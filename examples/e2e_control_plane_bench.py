@@ -36,8 +36,10 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from horovod_tpu.run.env_util import install_sigterm_exit
+from horovod_tpu.tuning import enable_compile_cache
 
 install_sigterm_exit()  # watchdog SIGTERM -> clean device teardown
+enable_compile_cache()  # before the first backend touch
 
 
 def main():

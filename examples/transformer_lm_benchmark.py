@@ -25,8 +25,10 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from horovod_tpu.run.env_util import install_sigterm_exit
+from horovod_tpu.tuning import enable_compile_cache
 
 install_sigterm_exit()  # watchdog SIGTERM -> clean device teardown
+enable_compile_cache()  # before the first backend touch
 
 import numpy as np
 import jax
@@ -110,17 +112,12 @@ def main():
     step = make_jit_train_step(model, tx, loss_fn=lm_xent)
     batch_stats = {}  # TransformerLM is stateless
 
-    step_flops = None
-    try:
-        compiled = step.lower(
-            params, batch_stats, opt_state, tokens, targets).compile()
-        step = compiled
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        step_flops = float(ca.get("flops", 0.0)) or None
-    except Exception as e:
-        print(f"cost analysis unavailable: {e}", file=sys.stderr)
+    step = step.lower(
+        params, batch_stats, opt_state, tokens, targets).compile()
+    ca = step.cost_analysis()
+    if isinstance(ca, (list, tuple)):
+        ca = ca[0]
+    step_flops = float(ca["flops"])
 
     for _ in range(args.warmup):
         params, batch_stats, opt_state, loss = step(
@@ -137,7 +134,8 @@ def main():
         return loss
 
     losses, dt = timed_steps(run_one, args.steps)
-    assert all(np.isfinite(l) for l in losses), f"non-finite: {losses[-3:]}"
+    if not all(np.isfinite(l) for l in losses):
+        raise SystemExit(f"non-finite loss: {losses[-3:]}")
 
     tokens_per_sec = global_batch * args.seq_len * args.steps / dt
     device_kind = jax.devices()[0].device_kind
@@ -146,14 +144,15 @@ def main():
         "value": round(tokens_per_sec / n_chips, 1),
         "unit": "tokens/s/chip",
         "n_chips": n_chips,
+        "platform": jax.devices()[0].platform,
         "device_kind": device_kind,
         "flash": bool(args.flash),
         "rope": bool(args.rope),
     }
     from horovod_tpu.profiler import device_peak_flops
 
-    peak = device_peak_flops(device_kind)
-    if step_flops is not None and peak is not None:
+    peak = device_peak_flops(device_kind)  # None off TPU: no MFU on a CPU
+    if peak is not None:
         achieved = step_flops * args.steps / dt
         result["mfu"] = round(achieved / (n_chips * peak), 4)
         result["model_tflops_per_step"] = round(step_flops / 1e12, 3)

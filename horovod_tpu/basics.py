@@ -132,10 +132,6 @@ def init(
         if _state.initialized:
             return
 
-        from horovod_tpu import compat
-
-        compat.warn_if_unsupported()
-
         coord = coordinator_address or os.environ.get("HVD_COORDINATOR_ADDR")
         nproc = num_processes or _env_int("HVD_NUM_PROCESSES")
         pid = process_id if process_id is not None else _env_int("HVD_PROCESS_ID")

@@ -32,6 +32,7 @@ import os
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 #: elements per int8 quantization scale (one bf16 scale per block)
 INT8_BLOCK = 256
@@ -104,8 +105,14 @@ def quantize_blockwise(flat, block: int = INT8_BLOCK, *, use_pallas=None):
         return _pk.quantize_blockwise(flat, block)
     m = flat.reshape(-1, block)
     amax = jnp.max(jnp.abs(m), axis=1)
-    scales = (amax / 127.0).astype(jnp.bfloat16)
-    s = scales.astype(flat.dtype)[:, None]
+    # reduce_precision, not a bare astype pair: XLA may elide an
+    # f32->bf16->f32 convert pair (excess precision — it does on TPU) and
+    # divide by the unrounded scale, while the receiver multiplies by the
+    # bf16 one; a reduce_precision rounding is never elided
+    wire = lax.reduce_precision(
+        amax / 127.0, exponent_bits=8, mantissa_bits=7)
+    scales = wire.astype(jnp.bfloat16)
+    s = wire[:, None]
     safe = jnp.where(s > 0, s, jnp.ones_like(s))
     q = jnp.where(s > 0, m / safe, jnp.zeros_like(m))
     q = jnp.clip(jnp.round(q), -127, 127).astype(jnp.int8)

@@ -38,10 +38,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 stable name
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from horovod_tpu import basics
 from horovod_tpu.analysis import sanitizer as _sanitizer
@@ -280,14 +277,9 @@ def _div(x, n):
 def _smap(fn, mesh, in_specs, out_specs):
     """shard_map with the static replication check disabled: collectives like
     all_gather/ppermute produce values the checker cannot prove replicated."""
-    try:
-        return _shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    except TypeError:  # pragma: no cover - older jax spelling
-        return _shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
+    return _shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 # --------------------------------------------------------------------------
 # compiled eager kernels (cached per mesh/shape/dtype/op)

@@ -231,6 +231,12 @@ def _flash_fwd_pallas(q, k, v, *, causal: bool, sm_scale: float,
     h_kv = k.shape[2]
     g = h // h_kv
     bq, bk = _block_sizes(t_q, t_k, block_q, block_k)
+    for name, blk, t in (("q", bq, t_q), ("k", bk, t_k)):
+        if blk % 8 and blk != t:
+            raise ValueError(
+                f"flash attention: sequence length {t} leaves a {name} "
+                f"block of {blk} rows, below the 8-row TPU tile; pad the "
+                f"sequence to a multiple of 8 (128 for full-size blocks)")
 
     # [B*H, T, D] layout: one grid row per (batch, head). K/V keep their
     # H_kv rows; GQA maps each query head's grid row onto its kv head in

@@ -29,11 +29,7 @@ epilogue now runs on-chip:
 - :func:`fused_adam_update` — Adam moment update + bias correction +
   parameter step in one kernel over the per-bucket ``[N, shard_k]``
   buffers of ``optim._zero_update`` (via :func:`horovod_tpu.optim.
-  fused_adam`). The optional ``requant_block`` epilogue additionally
-  emits the blockwise-int8 wire image of the update shard in the same
-  pass — the hook for a future quantized update-gather leg; today the
-  gather stays f32 (the collective schedule is pinned invariant), so
-  only the tests exercise it.
+  fused_adam`).
 
 Collectives are NEVER issued from a kernel: Pallas replaces the
 elementwise HLO *around* ``all_to_all``/``all_gather``/``ppermute``,
@@ -65,7 +61,6 @@ import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
 __all__ = [
@@ -86,16 +81,23 @@ __all__ = [
 #: (documented in docs/performance.md's Pallas knob table)
 PALLAS_ENV = "HOROVOD_PALLAS"
 
-#: elements per grid step for flat-vector kernels: one (8, 128) f32 VMEM
-#: tile — small enough that a whole (N, chunk) dequant-accumulate block
-#: stays resident beside its scales, large enough to amortize the grid
-_CHUNK = 1024
-
-#: sublane rows per grid step of the blockwise quantize (8 × block
-#: elements per tile, the f32 tile height)
-_QROWS = 8
-
+#: Mosaic tiling. Every block's last two dims must be a multiple of the
+#: dtype's native (sublane, 128-lane) tile — f32 (8, 128), bf16 (16, 128),
+#: int8 (32, 128) — or equal the array's own dims. Blocks that mix dtypes
+#: therefore align their row count to the int8 tile height.
 _LANES = 128
+_ROW_ALIGN = 32
+
+#: most block rows per grid step of the (rows, block) int8-wire kernels
+_MAX_ROWS = 512
+
+#: flat vectors (Adasum operands, Adam shards) ride as (rows, _VEC_COLS)
+#: with up to _VEC_ROWS rows per grid step
+_VEC_COLS = 1024
+_VEC_ROWS = 256
+
+#: VMEM budget for one resident (N, rows, block) int8 sender stack
+_STACK_BYTES = 2 << 20
 
 
 def _mode() -> str:
@@ -156,32 +158,60 @@ def _pl():
     return pl
 
 
-def _pad_rows(m, rows: int):
-    """Zero-pad the leading axis of a 2-D array to a multiple of ``rows``."""
-    pad = (-m.shape[0]) % rows
-    if pad:
-        m = jnp.concatenate(
-            [m, jnp.zeros((pad,) + m.shape[1:], m.dtype)])
-    return m
+def _coef_rows(*coefs):
+    """Traced scalar coefficients as one lane-dense ``(k, _VEC_COLS)``
+    f32 operand, row i filled with ``coefs[i]``: the kernel reads
+    ``c_ref[i:i + 1, :]`` and sublane-broadcasts it over its rows — no
+    scalar loads, and under ``jax.vmap`` a batched coefficient is just
+    one more leading block dim."""
+    c = jnp.stack(coefs).astype(jnp.float32)
+    return jnp.broadcast_to(c[:, None], (len(coefs), _VEC_COLS))
 
 
-def _pad_tail(flat, multiple: int):
-    pad = (-flat.shape[0]) % multiple
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-    return flat
+def _coef_spec(k: int):
+    return _pl().BlockSpec((k, _VEC_COLS), lambda i: (0, 0))
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _pad_axis(m, axis: int, multiple: int):
+    """Zero-pad one axis of an array up to a multiple of ``multiple``."""
+    pad = (-m.shape[axis]) % multiple
+    if not pad:
+        return m
+    widths = [(0, 0)] * m.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(m, widths)
+
+
+def _sublanes(dtype) -> int:
+    """Native tile height of ``dtype``: 8 rows of 32-bit words, packed
+    dtypes stack 2 (16-bit) or 4 (8-bit) rows per word."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _as_rows(flat):
+    """A flat vector as a zero-padded ``(rows, _VEC_COLS)`` matrix plus
+    the rows-per-grid-step that divides it."""
+    rows = _round_up(-(-flat.shape[0] // _VEC_COLS), _sublanes(flat.dtype))
+    tile = min(_VEC_ROWS, rows)
+    rows = _round_up(rows, tile)
+    m = _pad_axis(flat, 0, rows * _VEC_COLS).reshape(rows, _VEC_COLS)
+    return m, tile
 
 
 # --------------------------------------------------------------------------
 # blockwise int8 quantize (+ fused wire roundtrip)
 
 
-def _quantize_kernel(x_ref, q_ref, s_ref, *, roundtrip, d_ref=None):
-    """One VMEM pass over (rows, block): max-abs → bf16 scale → int8
-    cast, mirroring ``compression.quantize_blockwise`` expression for
-    expression so the interpret-mode output is BIT-identical to the HLO
-    path (pinned by tests/test_pallas.py)."""
-    m = x_ref[...]
+def _quantize_rows(m, q_ref, s_ref):
+    """Max-abs → bf16 scale → int8 cast over (rows, block), mirroring
+    ``compression.quantize_blockwise`` expression for expression so the
+    interpret-mode output is BIT-identical to the HLO path (pinned by
+    tests/test_pallas.py). Returns the f32 scale column and the int8
+    rows for a fused roundtrip."""
     amax = jnp.max(jnp.abs(m), axis=1, keepdims=True)
     sc = (amax / 127.0).astype(jnp.bfloat16)
     s_ref[...] = sc
@@ -190,44 +220,52 @@ def _quantize_kernel(x_ref, q_ref, s_ref, *, roundtrip, d_ref=None):
     q = jnp.where(sf > 0, m / safe, jnp.zeros_like(m))
     qi = jnp.clip(jnp.round(q), -127, 127).astype(jnp.int8)
     q_ref[...] = qi
-    if roundtrip:
-        d_ref[...] = qi.astype(m.dtype) * sf
+    return sf, qi
+
+
+def _quantize_kernel(x_ref, q_ref, s_ref):
+    _quantize_rows(x_ref[...], q_ref, s_ref)
+
+
+def _quantize_roundtrip_kernel(x_ref, q_ref, s_ref, d_ref):
+    sf, qi = _quantize_rows(x_ref[...], q_ref, s_ref)
+    d_ref[...] = qi.astype(d_ref.dtype) * sf
+
+
+def _row_tile(nb: int, n: int = 1, block: int = 1) -> int:
+    """Block rows per grid step: a multiple of the int8 tile height,
+    capped so an ``(n, rows, block)`` int8 sender stack fits its VMEM
+    budget."""
+    cap = max(_STACK_BYTES // max(n * block, 1), _ROW_ALIGN)
+    cap = min(_MAX_ROWS, cap // _ROW_ALIGN * _ROW_ALIGN)
+    return min(cap, _round_up(nb, _ROW_ALIGN))
 
 
 def _quantize_call(flat, block: int, roundtrip: bool):
     pl = _pl()
-    L = flat.shape[0]
-    nb = L // block
-    m = _pad_rows(flat.reshape(nb, block), _QROWS)
+    nb = flat.shape[0] // block
+    tile = _row_tile(nb)
+    m = _pad_axis(flat.reshape(nb, block), 0, tile)
     nbp = m.shape[0]
+    rows = pl.BlockSpec((tile, block), lambda i: (i, 0))
     out_shape = [
         jax.ShapeDtypeStruct((nbp, block), jnp.int8),
         jax.ShapeDtypeStruct((nbp, 1), jnp.bfloat16),
     ]
-    out_specs = [
-        pl.BlockSpec((_QROWS, block), lambda i: (i, 0)),
-        pl.BlockSpec((_QROWS, 1), lambda i: (i, 0)),
-    ]
+    out_specs = [rows, pl.BlockSpec((tile, 1), lambda i: (i, 0))]
     if roundtrip:
         out_shape.append(jax.ShapeDtypeStruct((nbp, block), flat.dtype))
-        out_specs.append(pl.BlockSpec((_QROWS, block), lambda i: (i, 0)))
-    kernel = (
-        (lambda x, q, s, d: _quantize_kernel(x, q, s, roundtrip=True,
-                                             d_ref=d))
-        if roundtrip else
-        (lambda x, q, s: _quantize_kernel(x, q, s, roundtrip=False))
-    )
+        out_specs.append(rows)
     out = pl.pallas_call(
-        kernel,
-        grid=(nbp // _QROWS,),
-        in_specs=[pl.BlockSpec((_QROWS, block), lambda i: (i, 0))],
+        _quantize_roundtrip_kernel if roundtrip else _quantize_kernel,
+        grid=(nbp // tile,),
+        in_specs=[rows],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret(),
     )(m)
-    q, s = out[0], out[1]
-    q = q[:nb].reshape(-1)
-    s = s[:nb].reshape(-1)
+    q = out[0][:nb].reshape(-1)
+    s = out[1][:nb].reshape(-1)
     if roundtrip:
         return q, s, out[2][:nb].reshape(-1)
     return q, s
@@ -252,57 +290,52 @@ def quantize_roundtrip(flat, block: int):
 
 # --------------------------------------------------------------------------
 # post-all_to_all epilogues: dequant-accumulate(-requantize)
+#
+# The wire image ``qr [N, sp]`` + ``scr [N, sp/block]`` rides as
+# ``(N, nb, block)`` int8 beside an ``(N, nb, 1)`` scale column: one
+# quantization block per sublane row, its scale broadcast along the
+# lanes — no in-kernel reshape, every block a whole number of tiles.
 
 
-def _chunk_cols(sp: int, block: int) -> int:
-    """Per-grid-step column count: a multiple of ``block`` capped near
-    :data:`_CHUNK` (the whole (N, chunk) int8 block + scales must sit in
-    VMEM beside the f32 accumulator)."""
-    cap = max(_CHUNK // block, 1)
+def _wire_stack(qr, scr, block: int):
+    n, sp = qr.shape
     nb = sp // block
-    return min(nb, cap) * block
+    tile = _row_tile(nb, n, block)
+    q3 = _pad_axis(qr.reshape(n, nb, block), 1, tile)
+    s3 = _pad_axis(scr.reshape(n, nb, 1), 1, tile)
+    return q3, s3, nb, tile
 
 
-def _deq_acc_kernel(q_ref, s_ref, o_ref, *, block):
-    q = q_ref[...]                                    # (n, chunk) int8
-    s = s_ref[...]                                    # (n, cpb) bf16
-    n, chunk = q.shape
-    d = q.astype(o_ref.dtype).reshape(n, chunk // block, block) \
-        * s.astype(o_ref.dtype)[:, :, None]
-    o_ref[...] = jnp.sum(d, axis=0).reshape(1, chunk)
+def _stack_specs(n: int, tile: int, block: int):
+    pl = _pl()
+    return [
+        pl.BlockSpec((n, tile, block), lambda j: (0, j, 0)),
+        pl.BlockSpec((n, tile, 1), lambda j: (0, j, 0)),
+    ]
 
 
-def _requant_rows(acc, q_ref, s_ref):
-    """Blockwise requantize of the accumulated (cpb, block) rows —
-    the same expressions as :func:`_quantize_kernel`."""
-    amax = jnp.max(jnp.abs(acc), axis=1, keepdims=True)
-    sc = (amax / 127.0).astype(jnp.bfloat16)
-    s_ref[...] = sc
-    sf = sc.astype(acc.dtype)
-    safe = jnp.where(sf > 0, sf, jnp.ones_like(sf))
-    q = jnp.where(sf > 0, acc / safe, jnp.zeros_like(acc))
-    q_ref[...] = jnp.clip(jnp.round(q), -127, 127).astype(jnp.int8)
+def _deq_sum(q_ref, s_ref, dtype):
+    """Dequantize the N sender rows and sum them in ``dtype`` — the same
+    ``(q * scale).sum(axis=0)`` the HLO path runs, sender by sender."""
+    d = q_ref[...].astype(dtype) * s_ref[...].astype(dtype)
+    return jnp.sum(d, axis=0)
 
 
-def _deq_acc_requant_kernel(q_ref, s_ref, q2_ref, s2_ref, *, block,
-                            divisor, dtype):
-    q = q_ref[...]
-    s = s_ref[...]
-    n, chunk = q.shape
-    d = q.astype(dtype).reshape(n, chunk // block, block) \
-        * s.astype(dtype)[:, :, None]
-    acc = jnp.sum(d, axis=0)                           # (cpb, block)
+def _deq_acc_kernel(q_ref, s_ref, o_ref):
+    o_ref[...] = _deq_sum(q_ref, s_ref, o_ref.dtype)
+
+
+def _deq_acc_requant_kernel(q_ref, s_ref, q2_ref, s2_ref, *, divisor,
+                            dtype):
+    acc = _deq_sum(q_ref, s_ref, dtype)
     if divisor is not None:
         acc = acc / jnp.asarray(divisor, dtype=acc.dtype)
-    _requant_rows(acc, q2_ref, s2_ref)
+    _quantize_rows(acc, q2_ref, s2_ref)
 
 
-def _pad_cols(m, cols: int):
-    pad = (-m.shape[1]) % cols
-    if pad:
-        m = jnp.concatenate(
-            [m, jnp.zeros((m.shape[0], pad), m.dtype)], axis=1)
-    return m
+def _deq_rows_kernel(q_ref, s_ref, o_ref):
+    o_ref[...] = q_ref[...].astype(o_ref.dtype) \
+        * s_ref[...].astype(o_ref.dtype)
 
 
 def dequant_accumulate(qr, scr, dtype, block: int):
@@ -315,24 +348,17 @@ def dequant_accumulate(qr, scr, dtype, block: int):
     bit-identical)."""
     pl = _pl()
     n, sp = qr.shape
-    chunk = _chunk_cols(sp, block)
-    qp = _pad_cols(qr, chunk)
-    sp_p = qp.shape[1]
-    scp = _pad_cols(scr, chunk // block)
-    cpb = chunk // block
+    q3, s3, nb, tile = _wire_stack(qr, scr, block)
+    nbp = q3.shape[1]
     out = pl.pallas_call(
-        functools.partial(_deq_acc_kernel, block=block),
-        grid=(sp_p // chunk,),
-        in_specs=[
-            pl.BlockSpec((n, chunk), lambda j: (0, j)),
-            pl.BlockSpec((n, cpb), lambda j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((1, chunk), lambda j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((sp_p // chunk, chunk),
-                                       jnp.dtype(dtype)),
+        _deq_acc_kernel,
+        grid=(nbp // tile,),
+        in_specs=_stack_specs(n, tile, block),
+        out_specs=pl.BlockSpec((tile, block), lambda j: (j, 0)),
+        out_shape=jax.ShapeDtypeStruct((nbp, block), jnp.dtype(dtype)),
         interpret=interpret(),
-    )(qp, scp)
-    return out.reshape(-1)[:sp]
+    )(q3, s3)
+    return out[:nb].reshape(-1)
 
 
 def dequant_accumulate_requantize(qr, scr, dtype, block: int,
@@ -346,42 +372,25 @@ def dequant_accumulate_requantize(qr, scr, dtype, block: int,
     multiple of ``block`` (the allreduce pads to ``N·block``)."""
     pl = _pl()
     n, sp = qr.shape
-    chunk = _chunk_cols(sp, block)
-    qp = _pad_cols(qr, chunk)
-    sp_p = qp.shape[1]
-    scp = _pad_cols(scr, chunk // block)
-    cpb = chunk // block
+    q3, s3, nb, tile = _wire_stack(qr, scr, block)
+    nbp = q3.shape[1]
     q2, s2 = pl.pallas_call(
         functools.partial(
-            _deq_acc_requant_kernel, block=block, divisor=divisor,
+            _deq_acc_requant_kernel, divisor=divisor,
             dtype=jnp.dtype(dtype)),
-        grid=(sp_p // chunk,),
-        in_specs=[
-            pl.BlockSpec((n, chunk), lambda j: (0, j)),
-            pl.BlockSpec((n, cpb), lambda j: (0, j)),
-        ],
+        grid=(nbp // tile,),
+        in_specs=_stack_specs(n, tile, block),
         out_specs=[
-            pl.BlockSpec((cpb, block), lambda j: (j, 0)),
-            pl.BlockSpec((cpb, 1), lambda j: (j, 0)),
+            pl.BlockSpec((tile, block), lambda j: (j, 0)),
+            pl.BlockSpec((tile, 1), lambda j: (j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((sp_p // block, block), jnp.int8),
-            jax.ShapeDtypeStruct((sp_p // block, 1), jnp.bfloat16),
+            jax.ShapeDtypeStruct((nbp, block), jnp.int8),
+            jax.ShapeDtypeStruct((nbp, 1), jnp.bfloat16),
         ],
         interpret=interpret(),
-    )(qp, scp)
-    nb = sp // block
+    )(q3, s3)
     return q2[:nb].reshape(-1), s2[:nb].reshape(-1)
-
-
-def _deq_rows_kernel(q_ref, s_ref, o_ref, *, block):
-    q = q_ref[...]                                    # (n, chunk) int8
-    s = s_ref[...]                                    # (n, cpb) bf16
-    n, chunk = q.shape
-    o_ref[...] = (
-        q.astype(o_ref.dtype).reshape(n, chunk // block, block)
-        * s.astype(o_ref.dtype)[:, :, None]
-    ).reshape(n, chunk)
 
 
 def dequantize_rows(qr, scr, dtype, block: int):
@@ -390,29 +399,23 @@ def dequantize_rows(qr, scr, dtype, block: int):
     ``dtype`` — NO accumulation (every row is a different rank's
     parameter shard; contrast :func:`dequant_accumulate`, the
     reduce-scatter epilogue that sums the senders). One VMEM pass per
-    column chunk, bit-identical to the discrete HLO
+    row tile, bit-identical to the discrete HLO
     ``compression.dequantize_rows`` (interpret mode pins it). The ZeRO-3
     int8 parameter gather (``collective.quantized_all_gather``) runs this
     right after its ``all_gather`` pair."""
     pl = _pl()
     n, sp = qr.shape
-    chunk = _chunk_cols(sp, block)
-    qp = _pad_cols(qr, chunk)
-    sp_p = qp.shape[1]
-    scp = _pad_cols(scr, chunk // block)
-    cpb = chunk // block
+    q3, s3, nb, tile = _wire_stack(qr, scr, block)
+    nbp = q3.shape[1]
     out = pl.pallas_call(
-        functools.partial(_deq_rows_kernel, block=block),
-        grid=(sp_p // chunk,),
-        in_specs=[
-            pl.BlockSpec((n, chunk), lambda j: (0, j)),
-            pl.BlockSpec((n, cpb), lambda j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((n, chunk), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((n, sp_p), jnp.dtype(dtype)),
+        _deq_rows_kernel,
+        grid=(nbp // tile,),
+        in_specs=_stack_specs(n, tile, block),
+        out_specs=pl.BlockSpec((n, tile, block), lambda j: (0, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, nbp, block), jnp.dtype(dtype)),
         interpret=interpret(),
-    )(qp, scp)
-    return out[:, :sp]
+    )(q3, s3)
+    return out[:, :nb].reshape(n, sp)
 
 
 # --------------------------------------------------------------------------
@@ -420,112 +423,117 @@ def dequantize_rows(qr, scr, dtype, block: int):
 
 
 def _pair_reduce_kernel(a_ref, b_ref, o_ref):
-    """Per-chunk lane-wise partials of ``a·b``, ``|a|²``, ``|b|²`` out
-    of ONE read of both operands, accumulated across the grid into one
-    (8, 128) block (rows 0..2 carry the three reductions)."""
+    """Partials of ``a·b``, ``|a|²``, ``|b|²`` out of ONE read of both
+    operands: each tile-high slab of the block adds into three resident
+    (slab, _VEC_COLS) accumulators (whole-vreg adds, no cross-lane
+    work); the wrapper folds the accumulators to scalars."""
     pl = _pl()
-    i = pl.program_id(0)
 
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    a = a_ref[...].astype(jnp.float32).reshape(-1, _LANES)
-    b = b_ref[...].astype(jnp.float32).reshape(-1, _LANES)
-    upd = jnp.concatenate([
-        jnp.sum(a * b, axis=0)[None],
-        jnp.sum(a * a, axis=0)[None],
-        jnp.sum(b * b, axis=0)[None],
-        jnp.zeros((5, _LANES), jnp.float32),
-    ], axis=0)
-    o_ref[...] = o_ref[...] + upd
+    h = o_ref.shape[1]
+
+    def slab(i, acc):
+        r = pl.multiple_of(i * h, h)
+        a = a_ref[pl.ds(r, h), :].astype(jnp.float32)
+        b = b_ref[pl.ds(r, h), :].astype(jnp.float32)
+        return acc[0] + a * b, acc[1] + a * a, acc[2] + b * b
+
+    zero = jnp.zeros((h, _VEC_COLS), jnp.float32)
+    ab, aa, bb = lax.fori_loop(
+        0, a_ref.shape[0] // h, slab, (zero, zero, zero))
+    o_ref[0] += ab
+    o_ref[1] += aa
+    o_ref[2] += bb
 
 
-def _blend_kernel(a_ref, b_ref, ca_ref, cb_ref, o_ref):
-    ca = ca_ref[0, 0]
-    cb = cb_ref[0, 0]
-    o_ref[...] = (ca * a_ref[...].astype(jnp.float32)
-                  + cb * b_ref[...].astype(jnp.float32))
+def _blend_kernel(c_ref, a_ref, b_ref, o_ref):
+    o_ref[...] = (c_ref[0:1, :] * a_ref[...].astype(jnp.float32)
+                  + c_ref[1:2, :] * b_ref[...].astype(jnp.float32))
 
 
-def _as_chunks(flat, chunk: int):
-    return _pad_tail(flat, chunk).reshape(-1, chunk)
+def _adasum_coefficients(dot, na, nb):
+    ca = jnp.where(na == 0, 0.0, 1.0 - dot / (2.0 * jnp.maximum(na, 1e-30)))
+    cb = jnp.where(nb == 0, 0.0, 1.0 - dot / (2.0 * jnp.maximum(nb, 1e-30)))
+    return ca, cb
 
 
 def adasum_pair_combine(a, b):
     """One Adasum pairwise combine (``ops/adasum.py::_pair_combine``)
     as two fused VMEM passes: pass 1 reads ``a``/``b`` ONCE for all
     three scalar reductions (the discrete path reads each operand three
-    times), pass 2 applies the scaled blend. The chunked partial
+    times), pass 2 applies the scaled blend. The tiled partial
     reduction changes the f32 summation order vs ``jnp.vdot``, so
     equivalence is pinned to tolerance, not bits."""
     pl = _pl()
     shape, dtype = a.shape, a.dtype
-    af = a.reshape(-1)
-    bf = b.reshape(-1)
-    L = af.shape[0]
-    a2 = _as_chunks(af, _CHUNK)
-    b2 = _as_chunks(bf, _CHUNK)
-    nc = a2.shape[0]
+    L = a.size
+    a2, tile = _as_rows(a.reshape(-1))
+    b2, _ = _as_rows(b.reshape(-1))
+    rows = a2.shape[0]
+    vec = pl.BlockSpec((tile, _VEC_COLS), lambda i: (i, 0))
+    acc = (3, _sublanes(dtype), _VEC_COLS)
     part = pl.pallas_call(
         _pair_reduce_kernel,
-        grid=(nc,),
-        in_specs=[
-            pl.BlockSpec((1, _CHUNK), lambda i: (i, 0)),
-            pl.BlockSpec((1, _CHUNK), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((8, _LANES), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((8, _LANES), jnp.float32),
+        grid=(rows // tile,),
+        in_specs=[vec, vec],
+        out_specs=pl.BlockSpec(acc, lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(acc, jnp.float32),
         interpret=interpret(),
     )(a2, b2)
-    dot = jnp.sum(part[0])
-    na = jnp.sum(part[1])
-    nb = jnp.sum(part[2])
-    ca = jnp.where(na == 0, 0.0, 1.0 - dot / (2.0 * jnp.maximum(na, 1e-30)))
-    cb = jnp.where(nb == 0, 0.0, 1.0 - dot / (2.0 * jnp.maximum(nb, 1e-30)))
+    dot, na, nb = jnp.sum(part, axis=(1, 2))
+    coef = _coef_rows(*_adasum_coefficients(dot, na, nb))
     out = pl.pallas_call(
         _blend_kernel,
-        grid=(nc,),
-        in_specs=[
-            pl.BlockSpec((1, _CHUNK), lambda i: (i, 0)),
-            pl.BlockSpec((1, _CHUNK), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, _CHUNK), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nc, _CHUNK), jnp.float32),
+        grid=(rows // tile,),
+        in_specs=[_coef_spec(2), vec, vec],
+        out_specs=vec,
+        out_shape=jax.ShapeDtypeStruct((rows, _VEC_COLS), jnp.float32),
         interpret=interpret(),
-    )(a2, b2, ca.reshape(1, 1), cb.reshape(1, 1))
+    )(coef, a2, b2)
     return out.reshape(-1)[:L].reshape(shape).astype(dtype)
 
 
 def _seg_reduce_kernel(a_ref, b_ref, seg_ref, o_ref):
-    """Segmented variant of :func:`_pair_reduce_kernel`: the three
-    products contract against an in-register one-hot segment matrix on
-    the MXU, yielding per-SEGMENT partials — all tensors of a fused
-    Adasum group reduced in one read of the group buffer."""
+    """Segmented variant of :func:`_pair_reduce_kernel`: each row's
+    three products contract against an in-register one-hot segment
+    matrix on the MXU, yielding per-SEGMENT partials — all tensors of a
+    fused Adasum group reduced in one read of the group buffer. Each
+    product row is sublane-broadcast to a full 8-row MXU operand, so the
+    (3, 8, nsp) accumulator holds every partial eight times over (the
+    wrapper reads row 0)."""
     pl = _pl()
-    i = pl.program_id(0)
 
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    a = a_ref[...].astype(jnp.float32)                 # (1, chunk)
-    b = b_ref[...].astype(jnp.float32)
-    seg = seg_ref[...]                                 # (1, chunk) int32
-    nsp = o_ref.shape[1]
-    onehot = (
-        lax.broadcasted_iota(jnp.int32, (nsp, a.shape[1]), 0) == seg
-    ).astype(jnp.float32)
-    prods = jnp.concatenate([
-        a * b, a * a, b * b,
-        jnp.zeros((5, a.shape[1]), jnp.float32),
-    ], axis=0)                                         # (8, chunk)
-    part = lax.dot_general(
-        prods, onehot, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)            # (8, nsp)
-    o_ref[...] = o_ref[...] + part
+    nsp = o_ref.shape[2]
+    cols = a_ref.shape[1]
+    seg_iota = lax.broadcasted_iota(jnp.int32, (nsp, cols), 0)
+    ab = aa = bb = jnp.zeros((8, nsp), jnp.float32)
+    for r in range(a_ref.shape[0]):
+        a = a_ref[r:r + 1, :]
+        b = b_ref[r:r + 1, :]
+        onehot = (seg_iota == seg_ref[r:r + 1, :]).astype(jnp.float32)
+
+        def contract(x):
+            # HIGHEST: the MXU's default single bf16 pass would round
+            # the f32 products to 8 mantissa bits before summing them
+            return lax.dot_general(
+                jnp.broadcast_to(x, (8, cols)), onehot,
+                (((1,), (1,)), ((), ())),
+                precision=lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+
+        ab = ab + contract(a * b)
+        aa = aa + contract(a * a)
+        bb = bb + contract(b * b)
+    o_ref[0] += ab
+    o_ref[1] += aa
+    o_ref[2] += bb
 
 
 def _seg_blend_kernel(a_ref, b_ref, ca_ref, cb_ref, o_ref):
@@ -542,51 +550,38 @@ def adasum_segment_combine(a, b, seg_ids, n_segments: int):
     kernel wrappers only."""
     pl = _pl()
     L = a.shape[0]
-    a2 = _as_chunks(a, _CHUNK)
-    b2 = _as_chunks(b, _CHUNK)
+    a2, tile = _as_rows(a)
+    b2, _ = _as_rows(b)
+    rows = a2.shape[0]
     # ghost id n_segments marks the zero-pad tail; it matches no one-hot
     # row (nsp > n_segments) or contributes only to a sliced-off row
-    seg_p = jnp.concatenate([
-        seg_ids.astype(jnp.int32),
-        jnp.full(((-L) % _CHUNK,), n_segments, jnp.int32),
-    ]) if L % _CHUNK else seg_ids.astype(jnp.int32)
-    s2 = seg_p.reshape(-1, _CHUNK)
-    nc = a2.shape[0]
-    nsp = -(-max(n_segments + 1, 2) // _LANES) * _LANES
+    seg_p = jnp.pad(seg_ids.astype(jnp.int32), (0, rows * _VEC_COLS - L),
+                    constant_values=n_segments)
+    s2 = seg_p.reshape(rows, _VEC_COLS)
+    nsp = _round_up(n_segments + 1, _LANES)
+    slab = pl.BlockSpec((8, _VEC_COLS), lambda i: (i, 0))
     part = pl.pallas_call(
         _seg_reduce_kernel,
-        grid=(nc,),
-        in_specs=[
-            pl.BlockSpec((1, _CHUNK), lambda i: (i, 0)),
-            pl.BlockSpec((1, _CHUNK), lambda i: (i, 0)),
-            pl.BlockSpec((1, _CHUNK), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((8, nsp), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((8, nsp), jnp.float32),
+        grid=(rows // 8,),
+        in_specs=[slab, slab, slab],
+        out_specs=pl.BlockSpec((3, 8, nsp), lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((3, 8, nsp), jnp.float32),
         interpret=interpret(),
     )(a2, b2, s2)
-    dot = part[0, :n_segments]
-    na = part[1, :n_segments]
-    nb = part[2, :n_segments]
-    ca = jnp.where(na == 0, 0.0, 1.0 - dot / (2.0 * jnp.maximum(na, 1e-30)))
-    cb = jnp.where(nb == 0, 0.0, 1.0 - dot / (2.0 * jnp.maximum(nb, 1e-30)))
+    ca, cb = _adasum_coefficients(*part[:, 0, :n_segments])
     # per-element coefficients: one gather (the same gather the discrete
-    # path's ca[seg_ids] performs), fed chunk-wise into the blend pass
-    ca_e = jnp.concatenate([ca, jnp.zeros((1,), jnp.float32)])[seg_p]
-    cb_e = jnp.concatenate([cb, jnp.zeros((1,), jnp.float32)])[seg_p]
+    # path's ca[seg_ids] performs), fed tile-wise into the blend pass
+    ca_e = jnp.concatenate([ca, jnp.zeros((1,), jnp.float32)])[s2]
+    cb_e = jnp.concatenate([cb, jnp.zeros((1,), jnp.float32)])[s2]
+    vec = pl.BlockSpec((tile, _VEC_COLS), lambda i: (i, 0))
     out = pl.pallas_call(
         _seg_blend_kernel,
-        grid=(nc,),
-        in_specs=[
-            pl.BlockSpec((1, _CHUNK), lambda i: (i, 0)),
-            pl.BlockSpec((1, _CHUNK), lambda i: (i, 0)),
-            pl.BlockSpec((1, _CHUNK), lambda i: (i, 0)),
-            pl.BlockSpec((1, _CHUNK), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, _CHUNK), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nc, _CHUNK), jnp.float32),
+        grid=(rows // tile,),
+        in_specs=[vec, vec, vec, vec],
+        out_specs=vec,
+        out_shape=jax.ShapeDtypeStruct((rows, _VEC_COLS), jnp.float32),
         interpret=interpret(),
-    )(a2, b2, ca_e.reshape(-1, _CHUNK), cb_e.reshape(-1, _CHUNK))
+    )(a2, b2, ca_e, cb_e)
     return out.reshape(-1)[:L]
 
 
@@ -594,99 +589,52 @@ def adasum_segment_combine(a, b, seg_ids, n_segments: int):
 # fused Adam shard update (ZeRO-1 per-bucket [N, shard_k] buffers)
 
 
-def _adam_kernel(g_ref, mu_ref, nu_ref, c_ref, u_ref, mu2_ref, nu2_ref,
-                 *, b1, b2, eps, eps_root, neg_lr, requant, block,
-                 q_ref=None, s_ref=None):
+def _adam_kernel(c_ref, g_ref, mu_ref, nu_ref, u_ref, mu2_ref, nu2_ref,
+                 *, b1, b2, eps, eps_root, neg_lr):
     """Adam moment update + bias correction + parameter step in one VMEM
     pass, expression-for-expression the optax ``scale_by_adam`` +
     ``scale(-lr)`` chain so interpret mode is bit-identical to the
     discrete path. ``c_ref`` carries the two traced bias-correction
-    scalars (they depend on the step count). With ``requant`` the update
-    chunk is additionally blockwise-int8 quantized in the same pass —
-    the wire image of the update shard when compression is on."""
+    scalars (they depend on the step count)."""
     g = g_ref[...]
     mu = mu_ref[...]
     nu = nu_ref[...]
-    b1c = c_ref[0, 0]
-    b2c = c_ref[0, 1]
+    b1c = c_ref[0:1, :].astype(g.dtype)
+    b2c = c_ref[1:2, :].astype(g.dtype)
     mu2 = (1 - b1) * g + b1 * mu
     nu2 = (1 - b2) * (g * g) + b2 * nu
     mu2_ref[...] = mu2
     nu2_ref[...] = nu2
-    u = neg_lr * ((mu2 / b1c) / (jnp.sqrt(nu2 / b2c + eps_root) + eps))
-    u_ref[...] = u
-    if requant:
-        _requant_rows(u.reshape(-1, block), q_ref, s_ref)
+    u_ref[...] = neg_lr * (
+        (mu2 / b1c) / (jnp.sqrt(nu2 / b2c + eps_root) + eps))
 
 
 def fused_adam_update(g, mu, nu, b1c, b2c, *, lr, b1, b2, eps,
-                      eps_root=0.0, requant_block=None):
+                      eps_root=0.0):
     """One fused Adam step over a flat shard: returns ``(update, mu',
     nu')`` — bit-identical to optax's ``scale_by_adam`` →
-    ``scale(-lr)`` chain — and, with ``requant_block``, additionally
-    ``(q, scales)``: the blockwise-int8 wire image of the update shard
-    emitted by the same pass. No production path consumes the epilogue
-    yet — the ZeRO-1 update gather stays f32 so the pinned collective
-    schedule cannot move; it is the (tested) hook for a future int8
-    gather leg. ``b1c``/``b2c`` are the traced bias corrections
-    ``1 - b**count`` (they ride a tiny (1, 2) buffer into the kernel).
+    ``scale(-lr)`` chain. ``b1c``/``b2c`` are the traced bias
+    corrections ``1 - b**count`` (they ride a two-row coefficient
+    operand into the kernel).
 
-    Works on any 1-D shard (zero-padded to the chunk internally) and
+    Works on any 1-D shard (zero-padded to whole tiles internally) and
     under ``jax.vmap`` — the form ``optim._zero_update`` applies over
     the per-bucket ``[N, shard_k]`` state buffers."""
     pl = _pl()
     L = g.shape[0]
-    chunk = _CHUNK if requant_block is None else \
-        max(_CHUNK // requant_block, 1) * requant_block
-    g2 = _as_chunks(g, chunk)
-    mu2 = _as_chunks(mu, chunk)
-    nu2 = _as_chunks(nu, chunk)
-    nc = g2.shape[0]
-    c = jnp.stack([b1c, b2c]).astype(g.dtype).reshape(1, 2)
-    out_specs = [
-        pl.BlockSpec((1, chunk), lambda i: (i, 0)),
-        pl.BlockSpec((1, chunk), lambda i: (i, 0)),
-        pl.BlockSpec((1, chunk), lambda i: (i, 0)),
-    ]
-    out_shape = [jax.ShapeDtypeStruct((nc, chunk), g.dtype)] * 3
-    if requant_block is not None:
-        cpb = chunk // requant_block
-        out_specs += [
-            pl.BlockSpec((cpb, requant_block), lambda i: (i, 0)),
-            pl.BlockSpec((cpb, 1), lambda i: (i, 0)),
-        ]
-        out_shape += [
-            jax.ShapeDtypeStruct((nc * cpb, requant_block), jnp.int8),
-            jax.ShapeDtypeStruct((nc * cpb, 1), jnp.bfloat16),
-        ]
-
-    def kernel(g_r, mu_r, nu_r, c_r, u_r, m2_r, n2_r, *extra):
-        _adam_kernel(
-            g_r, mu_r, nu_r, c_r, u_r, m2_r, n2_r,
-            b1=b1, b2=b2, eps=eps, eps_root=eps_root, neg_lr=-lr,
-            requant=requant_block is not None,
-            block=requant_block or 0,
-            q_ref=extra[0] if extra else None,
-            s_ref=extra[1] if extra else None,
-        )
-
+    g2, tile = _as_rows(g)
+    mu2, _ = _as_rows(mu)
+    nu2, _ = _as_rows(nu)
+    rows = g2.shape[0]
+    vec = pl.BlockSpec((tile, _VEC_COLS), lambda i: (i, 0))
     out = pl.pallas_call(
-        kernel,
-        grid=(nc,),
-        in_specs=[
-            pl.BlockSpec((1, chunk), lambda i: (i, 0)),
-            pl.BlockSpec((1, chunk), lambda i: (i, 0)),
-            pl.BlockSpec((1, chunk), lambda i: (i, 0)),
-            pl.BlockSpec((1, 2), lambda i: (0, 0)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
+        functools.partial(
+            _adam_kernel, b1=b1, b2=b2, eps=eps, eps_root=eps_root,
+            neg_lr=-lr),
+        grid=(rows // tile,),
+        in_specs=[_coef_spec(2), vec, vec, vec],
+        out_specs=[vec, vec, vec],
+        out_shape=[jax.ShapeDtypeStruct((rows, _VEC_COLS), g.dtype)] * 3,
         interpret=interpret(),
-    )(g2, mu2, nu2, c)
-    u, mo, no = (o.reshape(-1)[:L] for o in out[:3])
-    if requant_block is None:
-        return u, mo, no
-    lq = -(-L // requant_block) * requant_block
-    q = out[3].reshape(-1)[:lq]
-    s = out[4].reshape(-1)[:lq // requant_block]
-    return u, mo, no, (q, s)
+    )(_coef_rows(b1c, b2c), g2, mu2, nu2)
+    return tuple(o.reshape(-1)[:L] for o in out)

@@ -19,7 +19,7 @@ package is that layer for the TPU-native stack:
   :func:`~horovod_tpu.resilience.loop.run`: SIGTERM/SIGINT drain in-flight
   collectives, write an emergency checkpoint, and exit with the resumable
   exit code (:data:`RESUMABLE_EXIT_CODE`, 75 = ``EX_TEMPFAIL``) that
-  launchers and ``tools/tpu_window_watcher.py`` read as "preempted, retry".
+  launchers read as "preempted, retry".
 - :mod:`~horovod_tpu.resilience.chaos` — the env-gated
   (``HOROVOD_CHAOS=...``) fault-injection harness that makes all of the
   above deterministically testable on CPU in tier-1.

@@ -620,8 +620,6 @@ class ElasticRun:
                 help="wall time of one membership change (rollback + mesh "
                      "re-formation + reshard + epoch barrier)",
             ).observe(dt)
-        # NOTE: tools/tpu_window_watcher.py matches this exact prefix to
-        # classify a mid-rung resize as healthy progress, not a wedge.
         logger.warning(
             "elastic: resized to world size %d (generation %d, lost=%s "
             "joined=%s) in %.3fs",

@@ -16,9 +16,8 @@ checkpoint; :func:`run` converts that into a classified, resumable outcome:
    ``{"step": N, "state": ...}``) and raises :class:`Preempted` — a
    ``SystemExit`` subclass whose code is :data:`RESUMABLE_EXIT_CODE` (75 =
    BSD ``EX_TEMPFAIL``), so an unguarded training script exits with the
-   code launchers (``run/runner.py`` bounded restarts) and
-   ``tools/tpu_window_watcher.py`` read as "preempted, retry" rather than
-   "failed".
+   code launchers (``run/runner.py`` bounded restarts) read as
+   "preempted, retry" rather than "failed".
 4. On the next launch, :func:`run` (or :func:`resume_state`) restores the
    newest *valid* checkpoint and continues from the recorded step.
 

@@ -18,6 +18,7 @@ Two step styles, same user-visible semantics:
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Callable, Optional
 
@@ -34,7 +35,9 @@ from horovod_tpu.observability import metrics as _metrics
 from horovod_tpu.observability import regression as _regression
 from horovod_tpu.observability import slo as _slo
 from horovod_tpu.observability import straggler as _straggler
-from horovod_tpu.ops.collective import Average, allreduce, _smap
+from horovod_tpu.ops.collective import (
+    Average, allreduce, _mesh_axis_size, _smap,
+)
 from horovod_tpu.ops import overlap as _overlap
 from horovod_tpu.compression import Compression
 from horovod_tpu.resilience import health as _health
@@ -94,7 +97,16 @@ class InstrumentedStep:
         if self._peak_total is None:
             from horovod_tpu import profiler
 
-            peak = profiler.device_peak_flops()
+            try:
+                peak = profiler.device_peak_flops()
+            except ValueError as e:
+                # a gap in the telemetry table must not stop a training
+                # step; the entry points (bench.py, chip_smoke.py) raise
+                import logging
+
+                logging.getLogger("horovod_tpu").warning(
+                    "%s_mfu will not be reported: %s", self._name, e)
+                peak = None
             try:
                 n = basics.size()
             except RuntimeError:
@@ -223,6 +235,33 @@ def make_loader_step(step_fn: Callable, loader) -> Callable:
     return stepped
 
 
+def _attention_per_batch_shard(model):
+    """``model`` with its ``attention_fn`` (if it has one) run per batch
+    shard: a ``shard_map`` over the data axis, every other mesh axis left
+    to the partitioner. :func:`make_jit_train_step` owns the layout —
+    activations sharded ``P(data)`` on the batch — and a ``pallas_call``
+    (``flash_attention`` on TPU) is opaque to the SPMD partitioner, which
+    would otherwise all-gather q/k/v and run the whole batch on every
+    chip. For an attention made of plain HLO the wrap states the layout
+    the partitioner picks anyway. The mesh is read at trace time, so a
+    step retraced after an elastic resize follows the live world."""
+    fn = getattr(model, "attention_fn", None)
+    if fn is None:
+        return model
+
+    def attention(q, k, v, **kw):
+        mesh, ax = basics.mesh(), basics.data_axis()
+        if _mesh_axis_size(mesh, ax) == 1:
+            return fn(q, k, v, **kw)
+        return jax.shard_map(
+            functools.partial(fn, **kw), mesh=mesh, in_specs=(P(ax),) * 3,
+            out_specs=P(ax), check_vma=False,
+            axis_names=set(ax) if isinstance(ax, tuple) else {ax},
+        )(q, k, v)
+
+    return model.clone(attention_fn=attention)
+
+
 def make_jit_train_step(
     model,
     tx: optax.GradientTransformation,
@@ -235,7 +274,10 @@ def make_jit_train_step(
 ):
     """Global-jit DP train step. Inputs: (params, batch_stats, opt_state,
     images, labels) with images/labels sharded P(data) and the rest replicated.
-    Returns (params, batch_stats, opt_state, loss).
+    Returns (params, batch_stats, opt_state, loss). A model with an
+    ``attention_fn`` has it run per batch shard
+    (:func:`_attention_per_batch_shard`), so a Pallas attention kernel sees
+    its chip's rows and not the gathered batch.
 
     A numerics-guarded ``tx`` (``DistributedOptimizer(numerics_guard=True)``)
     is detected automatically: the loss is multiplied by the guard's
@@ -258,6 +300,7 @@ def make_jit_train_step(
 
         _tuning.apply_xla_flags()
     guarded = _numerics.is_guarded(tx)
+    model = _attention_per_batch_shard(model)
 
     def step(params, batch_stats, opt_state, images, labels):
         scale = _numerics.current_scale(opt_state) if guarded else None

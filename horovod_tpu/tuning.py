@@ -1,4 +1,4 @@
-"""XLA flag tuning for comm/compute overlap.
+"""XLA flag tuning for comm/compute overlap, and compile-cache placement.
 
 The bucketed gradient sync (:mod:`horovod_tpu.ops.overlap`) makes each
 bucket's collective *schedulable* inside the backward pass — whether it
@@ -7,17 +7,19 @@ work: **async collective fusion** (collectives split into start/done
 pairs that run on the DMA engines while the TensorCore keeps computing)
 and the **latency-hiding scheduler** (hoists the starts as early as
 their operands allow and sinks the dones as late as their consumers
-allow). Both are controlled by ``XLA_FLAGS``, which XLA reads ONCE at
-backend initialization — so the knobs must land in the environment
-before the first ``jax`` device touch.
+allow). Both are compiler flags the runtime reads ONCE at backend
+initialization — so the knobs must land in the environment before the
+first ``jax`` device touch.
 
 :func:`apply_xla_flags` appends the preset idempotently and never
 clobbers a flag the user already set (their value wins, even when it
 disagrees with the preset). ``HOROVOD_XLA_FLAGS_PRESET=<preset>`` makes
-``hvd.init`` apply it automatically before backend init. TPU-prefixed
-flags are a hard parse error on non-TPU jaxlibs (``Unknown flags in
-XLA_FLAGS`` is *fatal*), so application is gated on the resolved target
-platform — on a CPU host the call is a recorded no-op, never a crash.
+``hvd.init`` apply it automatically before backend init. A flag lands in
+the variable its runtime parses — ``--xla_tpu_*`` in
+``LIBTPU_INIT_ARGS``, which libtpu reads; in ``XLA_FLAGS`` the same flag
+is ``Unknown flags in XLA_FLAGS``, *fatal* at backend init on a CPU
+jaxlib and on a TPU host alike — and application is gated on the
+resolved target platform: on a CPU host the call is a recorded no-op.
 """
 
 from __future__ import annotations
@@ -35,6 +37,7 @@ __all__ = [
     "apply_xla_flags",
     "maybe_apply_from_env",
     "backend_initialized",
+    "enable_compile_cache",
 ]
 
 log = logging.getLogger("horovod_tpu")
@@ -56,6 +59,10 @@ _OVERLAP_FLAGS = (
      "tpu"),
     ("--xla_tpu_enable_latency_hiding_scheduler=true", "tpu"),
 )
+
+#: the environment variable each platform's runtime parses its flags from
+#: (anything else: XLA_FLAGS)
+_FLAGS_VAR = {"tpu": "LIBTPU_INIT_ARGS"}
 
 PRESETS = {
     "overlap": _OVERLAP_FLAGS,
@@ -88,6 +95,23 @@ def backend_initialized() -> bool:
         return False
 
 
+def enable_compile_cache() -> Optional[str]:
+    """Place JAX's persistent compilation cache; call before the first
+    backend touch. Where ``JAX_COMPILATION_CACHE_DIR`` is set jax reads it
+    itself and nothing is set here (returns None); otherwise the cache
+    lives at ``<checkout>/.jax_cache`` — a fixed path, because the path
+    is part of the cache key and a directory that moves never hits."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    import jax
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def _target_platform(env) -> str:
     """The platform the next backend init will target: the explicit
     ``JAX_PLATFORMS``/``JAX_PLATFORM_NAME`` pin when present, else
@@ -110,7 +134,8 @@ def apply_xla_flags(preset: Optional[str] = None, *, env=None,
                     platform: Optional[str] = None,
                     warn_if_late: bool = True
                     ) -> Tuple[List[str], List[str]]:
-    """Append the preset's flags to ``XLA_FLAGS`` idempotently.
+    """Append the preset's flags to the variable their runtime parses
+    (``LIBTPU_INIT_ARGS`` for TPU flags, else ``XLA_FLAGS``), idempotently.
 
     Returns ``(added, skipped)``: flags appended now, and flags withheld
     because the user already set that flag name (their value wins) or
@@ -128,8 +153,9 @@ def apply_xla_flags(preset: Optional[str] = None, *, env=None,
             f"{sorted(PRESETS)}"
         )
     platform = (platform or _target_platform(env)).lower()
-    current = env.get("XLA_FLAGS", "")
-    present = {_flag_name(t) for t in current.split() if t}
+    flag_vars = {"XLA_FLAGS", *_FLAGS_VAR.values()}
+    present = {_flag_name(t) for var in flag_vars
+               for t in env.get(var, "").split()}
     added: List[str] = []
     skipped: List[str] = []
     for flag, flag_platform in PRESETS[preset]:
@@ -140,13 +166,15 @@ def apply_xla_flags(preset: Optional[str] = None, *, env=None,
             # a fatal parse error, not a no-op — withhold it
         else:
             added.append(flag)
+            var = _FLAGS_VAR.get(flag_platform, "XLA_FLAGS")
+            env[var] = f"{env.get(var, '')} {flag}".strip()
     if added:
-        env["XLA_FLAGS"] = " ".join(([current] if current else []) + added)
         if warn_if_late and env is os.environ and backend_initialized():
             warnings.warn(
                 "horovod_tpu.tuning.apply_xla_flags ran after a jax "
-                "backend initialized; XLA_FLAGS is read once at backend "
-                "init, so the overlap flags only affect subprocesses. "
+                "backend initialized; compiler flags are read once at "
+                "backend init, so the overlap flags only affect "
+                "subprocesses. "
                 "Set HOROVOD_XLA_FLAGS_PRESET=overlap (or call "
                 "apply_xla_flags) before the first device touch.",
                 RuntimeWarning,

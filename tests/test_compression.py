@@ -12,6 +12,7 @@ Acceptance pins on the 8-device CPU mesh:
    ``consolidate_opt_state`` reshard with EF-residual mass preserved.
 """
 
+import functools
 import os
 
 import numpy as np
@@ -144,6 +145,27 @@ def test_int8_roundtrip_error_bound(hvd):
     # all-zero input quantizes to exactly zero (no 0/0 in the scale)
     z = np.asarray(int8_roundtrip(jnp.zeros(2048, jnp.float32)))
     np.testing.assert_array_equal(z, 0.0)
+
+
+def test_hlo_quantizer_divides_by_the_wire_scale():
+    """The receiver only ever sees the bf16 scale, so the sender must
+    divide by exactly that value. An ``astype`` pair is not enough: XLA
+    may elide f32->bf16->f32 (it does on TPU, where 4 % of codes then
+    came from the unrounded scale) — the rounding is a reduce_precision,
+    which no pass removes — and the codes match a NumPy oracle of the
+    documented semantics."""
+    x = np.random.RandomState(0).randn(64 * INT8_BLOCK).astype(np.float32)
+    hlo = functools.partial(quantize_blockwise, use_pallas=False)
+    assert "reduce_precision" in str(jax.make_jaxpr(hlo)(x))
+    q, scales = jax.jit(hlo)(x)
+    m = x.reshape(-1, INT8_BLOCK)
+    wire = np.asarray(jnp.asarray(np.abs(m).max(1) / np.float32(127.0))
+                      .astype(jnp.bfloat16).astype(jnp.float32))
+    np.testing.assert_array_equal(
+        np.asarray(scales.astype(jnp.float32)), wire)
+    np.testing.assert_array_equal(
+        np.asarray(q).reshape(m.shape),
+        np.clip(np.round(m / wire[:, None]), -127, 127).astype(np.int8))
 
 
 def test_int8_compress_decompress_shapes(hvd):

@@ -762,7 +762,7 @@ def test_bench_input_ab_rung():
     ).strip()
     out = subprocess.run(
         [sys.executable, os.path.join(_REPO, "bench.py"),
-         "--input-ab", "--iters", "10", "--no-probe"],
+         "--input-ab", "--iters", "10"],
         capture_output=True, text=True, timeout=600, env=env,
     )
     assert out.returncode == 0, out.stderr[-2000:]

@@ -6,7 +6,7 @@ The acceptance pin: under ``HOROVOD_CHAOS=rank_hang_at_step=K`` on the
 hung rank and the exact collective signature ``(step, gen, seq)``; a
 variant that SIGKILLs the hung process still diagnoses from the surviving
 ranks' records. Plus unit coverage of the ring, the torn-tail-tolerant
-sidecar, the verdict taxonomy, and the env-knob doc guard."""
+sidecar, the verdict classes, and the env-knob doc guard."""
 
 import json
 import os
@@ -128,7 +128,7 @@ def test_sidecar_compaction_bounds_the_file(tmp_path, monkeypatch):
     assert flight.analyze_dir(str(tmp_path))["verdict"] == "progressing"
 
 
-# ------------------------------------------------------- verdict taxonomy
+# -------------------------------------------------------- verdict classes
 
 
 def _stream(keys, *, end_last=True, op="allreduce", ops=None):

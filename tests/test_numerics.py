@@ -1734,7 +1734,7 @@ def test_bench_numerics_ab_rung():
     ).strip()
     out = subprocess.run(
         [sys.executable, os.path.join(_REPO, "bench.py"),
-         "--numerics-ab", "--iters", "10", "--no-probe"],
+         "--numerics-ab", "--iters", "10"],
         capture_output=True, text=True, timeout=600, env=env,
     )
     assert out.returncode == 0, out.stderr[-2000:]

@@ -655,17 +655,22 @@ def test_tuning_applies_preset_idempotently_on_tpu_target():
     env = {"JAX_PLATFORMS": "tpu"}
     added, skipped = tuning.apply_xla_flags("overlap", env=env)
     assert added and not skipped
-    assert all(f in env["XLA_FLAGS"] for f in added)
+    # libtpu parses its flags from LIBTPU_INIT_ARGS; the same flag in
+    # XLA_FLAGS aborts backend init ("Unknown flags in XLA_FLAGS")
+    assert env["LIBTPU_INIT_ARGS"].split() == added
+    assert "XLA_FLAGS" not in env
     again, skipped2 = tuning.apply_xla_flags("overlap", env=env)
     assert not again and len(skipped2) == len(added)
 
 
-def test_tuning_never_clobbers_user_set_entries():
+@pytest.mark.parametrize("var", ["LIBTPU_INIT_ARGS", "XLA_FLAGS"])
+def test_tuning_never_clobbers_user_set_entries(var):
     user = "--xla_tpu_enable_latency_hiding_scheduler=false"
-    env = {"JAX_PLATFORMS": "tpu", "XLA_FLAGS": user}
+    env = {"JAX_PLATFORMS": "tpu", var: user}
     added, skipped = tuning.apply_xla_flags("overlap", env=env)
-    assert user in env["XLA_FLAGS"]
-    assert env["XLA_FLAGS"].count("xla_tpu_enable_latency_hiding_scheduler") == 1
+    assert user in env[var]
+    everything = env.get("XLA_FLAGS", "") + " " + env["LIBTPU_INIT_ARGS"]
+    assert everything.count("xla_tpu_enable_latency_hiding_scheduler") == 1
     assert any("latency_hiding" in f for f in skipped)
     assert all("latency_hiding" not in f for f in added)
 
@@ -676,7 +681,7 @@ def test_tuning_withholds_tpu_flags_on_cpu_target():
     env = {"JAX_PLATFORMS": "cpu"}
     added, skipped = tuning.apply_xla_flags("overlap", env=env)
     assert not added and skipped
-    assert "XLA_FLAGS" not in env
+    assert "XLA_FLAGS" not in env and "LIBTPU_INIT_ARGS" not in env
 
 
 def test_tuning_env_knob_and_unknown_preset():
@@ -740,7 +745,7 @@ def test_bench_overlap_ab_rung():
     ).strip()
     out = subprocess.run(
         [sys.executable, os.path.join(_REPO, "bench.py"),
-         "--overlap-ab", "--iters", "6", "--no-probe"],
+         "--overlap-ab", "--iters", "6"],
         capture_output=True, text=True, timeout=600, env=env,
     )
     assert out.returncode == 0, out.stderr[-2000:]

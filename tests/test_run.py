@@ -236,7 +236,8 @@ def test_launch_job_mocked_failure_kills_job():
         return -signal.SIGTERM
 
     with mock.patch.object(runner.safe_exec, "execute", fake_execute):
-        codes = runner.launch_job(slots, ["python", "train.py"], {})
+        codes = runner.launch_job(
+            slots, ["python", "train.py"], {"JAX_PLATFORMS": "cpu"})
     assert sorted(calls) == [0, 1]
     assert codes[0] == 3
     assert codes[1] == -signal.SIGTERM

@@ -213,7 +213,8 @@ def test_lm_comm_fraction_modes(mode):
     proc = subprocess.run(
         [sys.executable, os.path.join(_REPO, "tools", "scaling_projection.py"),
          "--parallelism", mode, "--dim", "64", "--depth", "1",
-         "--heads", "4", "--seq-len", "256", "--vocab", "512"],
+         "--heads", "4", "--seq-len", "256", "--vocab", "512",
+         "--mfu", "0.4"],
         capture_output=True, text=True, timeout=900, env=env,
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
@@ -232,7 +233,7 @@ def test_projection_end_to_end():
     proc = subprocess.run(
         [sys.executable, os.path.join(_REPO, "tools", "scaling_projection.py"),
          "--model", "resnet50", "--image-size", "64", "--batch-per-chip", "2",
-         "--chips", "8"],
+         "--chips", "8", "--mfu", "0.4"],
         capture_output=True, text=True, timeout=900, env=env,
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
@@ -259,7 +260,7 @@ def test_hier_projection_end_to_end():
     proc = subprocess.run(
         [sys.executable, os.path.join(_REPO, "tools", "scaling_projection.py"),
          "--parallelism", "hier", "--image-size", "64",
-         "--batch-per-chip", "2"],
+         "--batch-per-chip", "2", "--mfu", "0.4"],
         capture_output=True, text=True, timeout=900, env=env,
     )
     assert proc.returncode == 0, proc.stderr[-2000:]

@@ -982,7 +982,7 @@ def test_bench_publish_ab_rung():
     ).strip()
     out = subprocess.run(
         [sys.executable, os.path.join(_REPO, "bench.py"),
-         "--publish-ab", "--iters", "5", "--no-probe"],
+         "--publish-ab", "--iters", "5"],
         capture_output=True, text=True, timeout=600, env=env,
     )
     assert out.returncode == 0, out.stderr[-2000:]

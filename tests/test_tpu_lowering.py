@@ -1,0 +1,177 @@
+"""Cross-lower every Pallas kernel for the TPU platform, on the CPU.
+
+Tier-1 runs the kernels with ``interpret=True``, which never meets the
+Pallas TPU lowering's tiling rules: six of eight kernels once passed
+every CPU test while ``HOROVOD_PALLAS=auto`` armed them on a chip where
+they could not even be traced. ``jit(f).trace(...).lower(
+lowering_platforms=("tpu",))`` applies those rules without a chip, so
+the next refusal shows here. Mosaic itself only runs on the chip —
+``chip_smoke.py``'s kernel phase covers that half.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+from horovod_tpu.compression import INT8_BLOCK
+from horovod_tpu.ops import pallas_kernels as pk
+from horovod_tpu.ops.flash_attention import flash_attention
+
+S = jax.ShapeDtypeStruct
+L = 1 << 20
+N = 4
+F32 = functools.partial(S, dtype=jnp.float32)
+
+
+def _lower_for_tpu(fn, *shapes):
+    text = jax.jit(fn).trace(*shapes).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.fixture()
+def compiled_kernels(monkeypatch):
+    """Kernels armed and NOT interpreted, as on a TPU backend."""
+    monkeypatch.setenv("HOROVOD_PALLAS", "1")
+    monkeypatch.setattr(pk, "interpret", lambda: False)
+
+
+_WIRE = (S((N, L // N), jnp.int8), S((N, L // N // INT8_BLOCK), jnp.bfloat16))
+
+_KERNELS = {
+    "quantize_blockwise": (
+        lambda x: pk.quantize_blockwise(x, INT8_BLOCK), F32((L,))),
+    "quantize_roundtrip": (
+        lambda x: pk.quantize_roundtrip(x, INT8_BLOCK), F32((L,))),
+    "quantize_one_block": (
+        lambda x: pk.quantize_blockwise(x, INT8_BLOCK), F32((INT8_BLOCK,))),
+    "dequant_accumulate": (
+        lambda q, s: pk.dequant_accumulate(q, s, jnp.float32, INT8_BLOCK),
+        *_WIRE),
+    "dequant_accumulate_requantize": (
+        lambda q, s: pk.dequant_accumulate_requantize(
+            q, s, jnp.float32, INT8_BLOCK, divisor=N), *_WIRE),
+    "dequantize_rows": (
+        lambda q, s: pk.dequantize_rows(q, s, jnp.float32, INT8_BLOCK),
+        *_WIRE),
+    "adasum_pair_combine": (pk.adasum_pair_combine, F32((L,)), F32((L,))),
+    "adasum_pair_combine_bf16_odd": (
+        pk.adasum_pair_combine, S((40, 30), jnp.bfloat16),
+        S((40, 30), jnp.bfloat16)),
+    "adasum_segment_combine": (
+        lambda a, b, s: pk.adasum_segment_combine(a, b, s, 5),
+        F32((L,)), F32((L,)), S((L,), jnp.int32)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_KERNELS))
+def test_kernel_lowers_for_tpu(compiled_kernels, name):
+    fn, *shapes = _KERNELS[name]
+    _lower_for_tpu(fn, *shapes)
+
+
+@pytest.mark.parametrize("shape", [(L,), (30,), (N, L // N)])
+def test_fused_adam_lowers_for_tpu(compiled_kernels, shape):
+    """Plain, tiny-leaf, and the vmapped ``[N, shard]`` form
+    ``optim._zero_update`` applies — whose step count, and therefore the
+    bias-correction operand, is batched too."""
+    from horovod_tpu.optim import fused_adam
+
+    fa, ref = fused_adam(1e-3), optax.adam(1e-3)
+    g = F32(shape)
+    if len(shape) == 2:
+        _lower_for_tpu(jax.vmap(fa.update), g,
+                       jax.eval_shape(jax.vmap(ref.init), g))
+    else:
+        _lower_for_tpu(fa.update, g, jax.eval_shape(ref.init, g))
+
+
+@pytest.mark.parametrize("heads,kv_heads,head_dim", [
+    (12, 12, 64),   # TransformerSmall
+    (4, 4, 128),
+    (8, 2, 128),    # GQA
+    (4, 1, 64),     # MQA
+])
+def test_flash_attention_lowers_for_tpu(heads, kv_heads, head_dim):
+    flash = functools.partial(flash_attention, causal=True, use_pallas=True)
+    q = S((2, 512, heads, head_dim), jnp.bfloat16)
+    kv = S((2, 512, kv_heads, head_dim), jnp.bfloat16)
+    _lower_for_tpu(flash, q, kv, kv)
+    # the backward recomputes from the kernel's (out, lse) residuals
+    _lower_for_tpu(
+        jax.grad(lambda q, k, v: flash(q, k, v).astype(jnp.float32).sum(),
+                 argnums=(0, 1, 2)), q, kv, kv)
+
+
+def test_jit_train_step_runs_flash_attention_per_batch_shard(hvd):
+    """A pallas_call is opaque to the SPMD partitioner. The global-jit
+    builder owns the batch-sharded layout, so it runs the model's
+    attention per batch shard: no all-gather around the kernel, and the
+    kernel's operands hold one chip's rows."""
+    import numpy as np
+
+    from horovod_tpu.models import TransformerTiny
+    from horovod_tpu.training import (
+        make_jit_train_step, replicate, shard_batch, token_xent)
+
+    n, seq = hvd.size(), 128
+    tiny = functools.partial(TransformerTiny, vocab=64, depth=1, heads=2,
+                             max_len=seq)
+    tokens = np.random.RandomState(0).randint(0, 64, (n, seq), np.int32)
+    tx = hvd.DistributedOptimizer(optax.sgd(0.1))
+    params = jax.jit(tiny().init)(jax.random.PRNGKey(0), tokens[:1])["params"]
+    losses = {}
+    for name, kw in (("flash", dict(attention_fn=functools.partial(
+            flash_attention, use_pallas=True, interpret=True))),
+                     ("dense", {})):
+        step = make_jit_train_step(tiny(dtype=jnp.float32, **kw), tx,
+                                   loss_fn=token_xent, instrument=False,
+                                   donate=False)
+        args = (replicate(params), {}, replicate(tx.init(params)),
+                shard_batch(tokens), shard_batch(np.roll(tokens, -1, 1)))
+        compiled = step.lower(*args).compile()
+        hlo = compiled.as_text()
+        assert "all-reduce" in hlo and "all-gather" not in hlo, name
+        losses[name] = float(compiled(*args)[3])
+    assert abs(losses["flash"] - losses["dense"]) < 1e-4 * losses["dense"]
+
+    # the same step lowered for TPU: the Mosaic call takes [1 x heads, T, D]
+    model = tiny(attention_fn=functools.partial(
+        flash_attention, use_pallas=True, interpret=False))
+    step = make_jit_train_step(model, tx, loss_fn=token_xent,
+                               instrument=False)
+    text = step.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    heads, hd = model.heads, model.dim // model.heads
+    assert calls and all(
+        f"tensor<{heads}x{seq}x{hd}xbf16>" in l for l in calls)
+
+
+def test_flash_attention_leaves_placement_to_its_caller(hvd):
+    """The kernel does not consult hvd state: q/k/v committed to ONE device
+    of the initialised 8-device mesh (single-chip eval on a multi-chip
+    host) run there, eagerly and under jit, whatever the batch divides."""
+    import numpy as np
+
+    flash = functools.partial(flash_attention, causal=True, use_pallas=True,
+                              interpret=True)
+    dev = jax.devices()[3]
+    x = jax.device_put(jnp.asarray(
+        np.random.RandomState(0).randn(hvd.size(), 128, 2, 64),
+        jnp.bfloat16), dev)
+    assert "shard_map" not in str(jax.make_jaxpr(flash)(x, x, x))
+    for f in (flash, jax.jit(flash)):
+        out = f(x, x, x)
+        assert out.shape == x.shape and out.devices() == {dev}
+
+
+def test_flash_attention_rejects_blocks_below_the_tile():
+    """T=204 halves the 128-row block down to 4 rows — an error here, not
+    a kernel Mosaic refuses."""
+    x = S((1, 204, 2, 64), jnp.bfloat16)
+    with pytest.raises(ValueError, match="below the 8-row TPU tile"):
+        jax.eval_shape(
+            functools.partial(flash_attention, use_pallas=True), x, x, x)

@@ -749,7 +749,6 @@ def _report_comm_fraction(args, compiled, mesh, *, default_group: int,
         "comm_bytes_per_step": sum(b for _, b, _ in comm_ops),
         "flops_per_chip_per_step": flops_per_chip,
         "mfu_assumed": args.mfu,
-        "mfu_source": getattr(args, "mfu_source", "cli"),
         "comm_ms": round(t_comm * 1e3, 3),
         "compute_ms": round(t_compute * 1e3, 3),
         "comm_fraction_serial": round(t_comm / (t_comm + t_compute), 4),
@@ -966,7 +965,6 @@ def _hier_comm_fraction(args) -> int:
         "params": n_params,
         "comm_bytes_by_fabric": by_fabric,
         "mfu_assumed": args.mfu,
-        "mfu_source": getattr(args, "mfu_source", "cli"),
         "comm_ms_at_compiled_mesh": round(t_comm * 1e3, 3),
         "compute_ms": round(t_compute * 1e3, 3),
         "multi_host_projection": proj,
@@ -978,55 +976,6 @@ def _hier_comm_fraction(args) -> int:
     }), flush=True)
     hvd.shutdown()
     return 0
-
-
-def _resolve_mfu(artifacts: str = None) -> tuple:
-    """Best MEASURED mfu_vs_peak banked by the round-long TPU window watcher
-    (tools/tpu_window_watcher.py rung ``mfu``), else the 0.4 literature
-    default. The fraction is an achieved-utilization estimate for the large
-    bf16 matmul — transferable across TPU generations as a roofline input
-    even when --hw differs from the chip that measured it (VERDICT r4: the
-    projection's 0.4 assumption was itself unmeasured)."""
-    import glob
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if artifacts:
-        pats = [os.path.join(artifacts, "mfu_*.json")]
-    else:
-        # live watcher dir (gitignored) plus the committed evidence snapshot,
-        # so a fresh checkout still gets the measured number
-        pats = [os.path.join(repo, ".tpu_watch", "mfu_*.json"),
-                os.path.join(repo, "docs", "evidence", "*", "mfu_*.json")]
-    import time as _time
-
-    sys.path.insert(0, os.path.join(_REPO_ROOT, "tools"))
-    from tpu_window_watcher import FRESHNESS_S, artifact_ok
-
-    best = None
-    now = _time.time()
-    for path in (p for pat in pats for p in glob.glob(pat)):
-        try:
-            # live-watcher artifacts from a previous round are stale; the
-            # committed evidence snapshot is trusted at any age. The
-            # acceptance policy itself (rc, value, hardware-not-CPU) is the
-            # watcher's shared artifact_ok — same predicate bench.py's
-            # merge applies, so the two cannot drift.
-            if (".tpu_watch" in path
-                    and now - os.path.getmtime(path) > FRESHNESS_S):
-                continue
-            with open(path) as f:
-                data = json.load(f)
-        except (ValueError, OSError):
-            continue
-        frac = data.get("mfu_vs_peak")
-        if not frac or not artifact_ok(data):
-            continue
-        if best is None or frac > best[0]:
-            best = (frac, f"measured:{os.path.basename(path)}"
-                          f" ({data.get('device_kind', '?')})")
-    if best is not None:
-        return best
-    return 0.4, "assumed-default"
 
 
 def main() -> int:
@@ -1056,22 +1005,13 @@ def main() -> int:
                    help="hier mode: per-host DCN NIC bandwidth in GB/s "
                         "(shared by the host's local chips); 25 GB/s ~ "
                         "200 Gbit ethernet")
-    p.add_argument("--mfu", type=float, default=None,
+    p.add_argument("--mfu", type=float, required=True,
                    help="achievable model-flops-utilization for t_compute "
                         "(peak*mfu); 100%% peak would overstate comm cost "
-                        "~2-3x vs real conv/matmul utilization. Default: "
-                        "the best measured mfu_vs_peak banked by "
-                        "tools/tpu_window_watcher.py in --artifacts (a real "
-                        "chip measurement), else 0.4")
-    p.add_argument("--artifacts", default=None,
-                   help="watcher artifact dir to read a MEASURED MFU from "
-                        "(default: <repo>/.tpu_watch)")
+                        "~2-3x vs real conv/matmul utilization. Take it "
+                        "from a chip measurement (PERF.md) and say which")
     p.add_argument("--chips", type=int, nargs="+", default=[8, 32, 256])
     args = p.parse_args()
-
-    args.mfu_source = "cli"
-    if args.mfu is None:
-        args.mfu, args.mfu_source = _resolve_mfu(args.artifacts)
 
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
@@ -1155,7 +1095,6 @@ def main() -> int:
         "comm_bytes_per_step": comm_bytes,
         "flops_per_chip_per_step": flops_per_chip,
         "mfu_assumed": args.mfu,
-        "mfu_source": getattr(args, "mfu_source", "cli"),
         "batch_per_chip": args.batch_per_chip,
         "image_size": size,
         "projection": proj,
