@@ -190,6 +190,34 @@ def _is_tracer(x) -> bool:
     return isinstance(x, jax.core.Tracer)
 
 
+def _sync_scope(part: str):
+    """``hvd.sync/<part>``: the scope of an explicit exchange inside a
+    compiled step (``grads``, ``stats``, ``loss``; ZeRO's ``params`` and
+    ``updates`` gathers). What runs under it is the ``sync`` phase of
+    :func:`horovod_tpu.profiler.scope_of`."""
+    return jax.named_scope(f"hvd.sync/{part}")
+
+
+def _jit_scoped(fn):
+    """The in-jit branch of a public collective runs under ``hvd.<op>``
+    (``/<name>`` where the caller gave ``name=``), so a user's own
+    ``hvd.allreduce`` under ``jit`` is named in the device trace. A scope
+    is HLO metadata: no jaxpr equation, nothing at run time. The eager
+    branch pays one ``isinstance``."""
+    scope = "hvd." + fn.__name__
+
+    @functools.wraps(fn)
+    def scoped(tensor, *args, **kwargs):
+        tensors = tensor if isinstance(tensor, (list, tuple)) else (tensor,)
+        if not any(map(_is_tracer, tensors)):
+            return fn(tensor, *args, **kwargs)
+        name = kwargs.get("name")
+        with jax.named_scope(f"{scope}/{name}" if name else scope):
+            return fn(tensor, *args, **kwargs)
+
+    return scoped
+
+
 def _hier_enabled() -> bool:
     from horovod_tpu.ops import hierarchical
 
@@ -1090,6 +1118,7 @@ def clear_eager_caches() -> None:
 # allreduce
 
 
+@_jit_scoped
 def allreduce(tensor, op: ReduceOp = Average, *, axis=None, name: Optional[str] = None,
               compression=None, prescale_factor: float = 1.0,
               postscale_factor: float = 1.0):
@@ -1220,6 +1249,7 @@ def allreduce_async(tensor, op: ReduceOp = Average, *, axis=None, name=None,
 allreduce_async_ = allreduce_async
 
 
+@_jit_scoped
 def grouped_allreduce(tensors: Sequence, op: ReduceOp = Average, *, axis=None,
                       name=None):
     """Fused allreduce of a list of tensors in one collective.
@@ -1301,6 +1331,7 @@ def grouped_allreduce_async(tensors, op: ReduceOp = Average, *, axis=None,
 # allgather
 
 
+@_jit_scoped
 def allgather(tensor, *, axis=None, name=None):
     """Concatenate per-rank tensors along dim 0 (reference
     ``MPIAllgather``/``NCCL`` path, ``mpi_operations.cc:83+``;
@@ -1342,6 +1373,7 @@ def allgather(tensor, *, axis=None, name=None):
     return out
 
 
+@_jit_scoped
 def grouped_allgather(tensors: Sequence, *, axis=None, name=None):
     """Fused allgather of a tensor list in one XLA launch (the reference
     fuses allgather responses too, ``controller.cc:700-755``; here the
@@ -1399,6 +1431,7 @@ def allgather_object(obj, *, name=None):
 # broadcast
 
 
+@_jit_scoped
 def broadcast(tensor, root_rank: int = 0, *, axis=None, name=None):
     """Broadcast root's value to all ranks (reference
     ``NCCLBroadcast``, ``nccl_operations.cc:366-396``;
@@ -1477,6 +1510,7 @@ def broadcast_object(obj, root_rank: int = 0, *, name=None):
 # horovod_tpu.parallel for sequence/expert parallelism)
 
 
+@_jit_scoped
 def alltoall(tensor, *, axis=None, name=None):
     """All-to-all: rank i sends chunk j of its tensor to rank j. Not in the
     0.19.2 reference (added upstream in 0.20); first-class here because
@@ -1566,6 +1600,7 @@ def _pad_rows(tensor, n: int, dim: int = 0):
     return jnp.pad(tensor, widths)
 
 
+@_jit_scoped
 def reducescatter(tensor, op: ReduceOp = Average, *, axis=None, name=None):
     """Reduce-scatter along dim 0 (upstream 0.21 feature; here it is also the
     building block of hierarchical allreduce, reference

@@ -279,6 +279,7 @@ def _flash_fwd_pallas(q, k, v, *, causal: bool, sm_scale: float,
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name="hvd_flash_fwd",
     )(qr, kr, vr)
     out = out.reshape(b, h, t_q, d).transpose(0, 2, 1, 3)
     lse = lse.reshape(b, h, t_q)
@@ -335,11 +336,13 @@ def _flash(q, k, v, causal, sm_scale, block_sizes):
     return _fwd_impl(q, k, v, causal, sm_scale, block_sizes)[0]
 
 
+@jax.named_scope("hvd.flash_fwd")
 def _flash_fwd(q, k, v, causal, sm_scale, block_sizes):
     out, lse = _fwd_impl(q, k, v, causal, sm_scale, block_sizes)
     return out, (q, k, v, out, lse)
 
 
+@jax.named_scope("hvd.flash_bwd")
 def _flash_bwd(causal, sm_scale, block_sizes, res, g):
     """O(T) extra-memory backward: scan K/V blocks, recomputing p from lse
     (saves no score matrix — the flash-attention trade). Residual K/V stay

@@ -338,6 +338,7 @@ def _record_buckets(mode: str, k: int) -> None:
     ).set(k)
 
 
+@jax.named_scope("hvd.sync/grads")  # the device trace's sync phase
 def bucketed_allreduce(grads, op=None, *, axis=None, compression=None,
                        bucket_bytes: Optional[int] = None,
                        plan: Optional[BucketPlan] = None,

@@ -263,6 +263,7 @@ def _quantize_call(flat, block: int, roundtrip: bool):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret(),
+        name="hvd_quantize_roundtrip" if roundtrip else "hvd_quantize",
     )(m)
     q = out[0][:nb].reshape(-1)
     s = out[1][:nb].reshape(-1)
@@ -357,6 +358,7 @@ def dequant_accumulate(qr, scr, dtype, block: int):
         out_specs=pl.BlockSpec((tile, block), lambda j: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((nbp, block), jnp.dtype(dtype)),
         interpret=interpret(),
+        name="hvd_dequant_accumulate",
     )(q3, s3)
     return out[:nb].reshape(-1)
 
@@ -389,6 +391,7 @@ def dequant_accumulate_requantize(qr, scr, dtype, block: int,
             jax.ShapeDtypeStruct((nbp, 1), jnp.bfloat16),
         ],
         interpret=interpret(),
+        name="hvd_dequant_accumulate_requantize",
     )(q3, s3)
     return q2[:nb].reshape(-1), s2[:nb].reshape(-1)
 
@@ -414,6 +417,7 @@ def dequantize_rows(qr, scr, dtype, block: int):
         out_specs=pl.BlockSpec((n, tile, block), lambda j: (0, j, 0)),
         out_shape=jax.ShapeDtypeStruct((n, nbp, block), jnp.dtype(dtype)),
         interpret=interpret(),
+        name="hvd_dequantize_rows",
     )(q3, s3)
     return out[:, :nb].reshape(n, sp)
 
@@ -482,6 +486,7 @@ def adasum_pair_combine(a, b):
         out_specs=pl.BlockSpec(acc, lambda i: (0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(acc, jnp.float32),
         interpret=interpret(),
+        name="hvd_adasum_pair_reduce",
     )(a2, b2)
     dot, na, nb = jnp.sum(part, axis=(1, 2))
     coef = _coef_rows(*_adasum_coefficients(dot, na, nb))
@@ -492,6 +497,7 @@ def adasum_pair_combine(a, b):
         out_specs=vec,
         out_shape=jax.ShapeDtypeStruct((rows, _VEC_COLS), jnp.float32),
         interpret=interpret(),
+        name="hvd_adasum_pair_blend",
     )(coef, a2, b2)
     return out.reshape(-1)[:L].reshape(shape).astype(dtype)
 
@@ -567,6 +573,7 @@ def adasum_segment_combine(a, b, seg_ids, n_segments: int):
         out_specs=pl.BlockSpec((3, 8, nsp), lambda i: (0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((3, 8, nsp), jnp.float32),
         interpret=interpret(),
+        name="hvd_adasum_segment_reduce",
     )(a2, b2, s2)
     ca, cb = _adasum_coefficients(*part[:, 0, :n_segments])
     # per-element coefficients: one gather (the same gather the discrete
@@ -581,6 +588,7 @@ def adasum_segment_combine(a, b, seg_ids, n_segments: int):
         out_specs=vec,
         out_shape=jax.ShapeDtypeStruct((rows, _VEC_COLS), jnp.float32),
         interpret=interpret(),
+        name="hvd_adasum_segment_blend",
     )(a2, b2, ca_e, cb_e)
     return out.reshape(-1)[:L]
 
@@ -636,5 +644,6 @@ def fused_adam_update(g, mu, nu, b1c, b2c, *, lr, b1, b2, eps,
         out_specs=[vec, vec, vec],
         out_shape=[jax.ShapeDtypeStruct((rows, _VEC_COLS), g.dtype)] * 3,
         interpret=interpret(),
+        name="hvd_fused_adam",
     )(_coef_rows(b1c, b2c), g2, mu2, nu2)
     return tuple(o.reshape(-1)[:L] for o in out)

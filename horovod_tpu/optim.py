@@ -131,6 +131,13 @@ def _orthonormalize(p, eps: float = 1e-8):
     return jnp.stack(cols, axis=1)
 
 
+def _sync_allreduce(x, op, *, axis, **kw):
+    """``allreduce`` of one gradient-exchange buffer, under the device
+    trace's ``hvd.sync/grads`` scope."""
+    with _C._sync_scope("grads"):
+        return allreduce(x, op, axis=axis, **kw)
+
+
 def _psgd_factor_sync(m2d, qmat, reduce_mean):
     """One PowerSGD round on a 2-D per-rank matrix: ``P = M @ Q`` (mean
     across ranks), orthonormalize, ``Q' = M^T @ P`` (mean across ranks).
@@ -570,16 +577,18 @@ def _zero_update(grads, state, params, *, optimizer, compression,
             if op == Average and predivide != 1.0:
                 send = send / predivide
             if qkernel:
-                shard = _C.quantized_psum_scatter(
-                    send, ax, block=qblock, pre=pre)
+                with _C._sync_scope("grads"):
+                    shard = _C.quantized_psum_scatter(
+                        send, ax, block=qblock, pre=pre)
                 ctx = None
             else:
                 comp, ctx = (
                     (_wire_rt(send), None) if qgroup
                     else compression.compress(send)
                 )
-                shard = lax.psum_scatter(
-                    comp, ax, scatter_dimension=0, tiled=True)
+                with _C._sync_scope("grads"):
+                    shard = lax.psum_scatter(
+                        comp, ax, scatter_dimension=0, tiled=True)
             if op == Average and predivide == 1.0:
                 shard = _C._div(shard, n)
             if not qgroup:
@@ -693,7 +702,9 @@ def _zero_update(grads, state, params, *, optimizer, compression,
             cats = [upd_shards[k][0] for k in keys]
             cat = cats[0] if len(cats) == 1 else jnp.concatenate(cats)
             S = cat.shape[0]
-            gat = lax.all_gather(cat, ax, axis=0, tiled=True).reshape(n, S)
+            with _C._sync_scope("updates"):
+                gat = lax.all_gather(
+                    cat, ax, axis=0, tiled=True).reshape(n, S)
             off = 0
             for k in keys:
                 s_k = groups[k].Lp // n
@@ -772,9 +783,7 @@ def _zero_update_powersgd(grads, state, params, *, optimizer, compression,
                 off += size
 
     def _reduce_mean_bound(x):
-        from horovod_tpu.ops.collective import allreduce
-
-        return allreduce(x, Average, axis=ax)
+        return _sync_allreduce(x, Average, axis=ax)
 
     # 2. per-leaf sync: factorized / int8 fallback / uncompressed
     reduced = [None] * len(leaves)
@@ -803,9 +812,8 @@ def _zero_update_powersgd(grads, state, params, *, optimizer, compression,
             if bound:
                 rt = int8_roundtrip(c, block)
                 res_leaves[i] = c - rt
-                from horovod_tpu.ops.collective import allreduce
-
-                reduced[i] = allreduce(c, op, axis=ax, compression=fallback)
+                reduced[i] = _sync_allreduce(
+                    c, op, axis=ax, compression=fallback)
             else:
                 rt = jax.vmap(lambda v: int8_roundtrip(v, block))(c)
                 res_leaves[i] = c - rt
@@ -814,9 +822,7 @@ def _zero_update_powersgd(grads, state, params, *, optimizer, compression,
         else:
             res_leaves[i] = jnp.zeros_like(c)
             if bound:
-                from horovod_tpu.ops.collective import allreduce
-
-                reduced[i] = allreduce(c, op, axis=ax)
+                reduced[i] = _sync_allreduce(c, op, axis=ax)
             else:
                 red = c.sum(axis=0) if op == Sum else _C._div(c.sum(axis=0), n)
                 reduced[i] = red.astype(dt)
@@ -863,7 +869,9 @@ def _zero_update_powersgd(grads, state, params, *, optimizer, compression,
     for key, entry in spec.items():
         L = entry[3]
         if bound:
-            full = lax.all_gather(upd_shards[key][0], ax, axis=0, tiled=True)
+            with _C._sync_scope("updates"):
+                full = lax.all_gather(
+                    upd_shards[key][0], ax, axis=0, tiled=True)
         else:
             full = upd_shards[key].reshape(-1)
         _zero_unpack(full[:L], entry, out_leaves)
@@ -1069,15 +1077,18 @@ def fsdp_gather_params(fp: FsdpParams, *, wire: Optional[str] = None):
         )
         if bound:
             local = fp.shards[key][0]                          # [s]
-            if qgroup and not isinstance(ax, tuple):
-                full = _C.quantized_all_gather(local, ax, block=INT8_BLOCK)
-            else:
-                if qgroup:
-                    # axis pair (hierarchical): the quantized kernel needs
-                    # a single named axis — ship the roundtripped values
-                    # through the routed gather (same math, modeled wire)
-                    local = _roundtrip_row(local)
-                full = _C.allgather(local, axis=ax)            # [n*s]
+            with _C._sync_scope("params"):
+                if qgroup and not isinstance(ax, tuple):
+                    full = _C.quantized_all_gather(
+                        local, ax, block=INT8_BLOCK)
+                else:
+                    if qgroup:
+                        # axis pair (hierarchical): the quantized kernel
+                        # needs a single named axis — ship the
+                        # roundtripped values through the routed gather
+                        # (same math, modeled wire)
+                        local = _roundtrip_row(local)
+                    full = _C.allgather(local, axis=ax)        # [n*s]
         else:
             rows = jnp.asarray(fp.shards[key])                 # [N, s]
             if qgroup:
@@ -1546,7 +1557,8 @@ def _powersgd_update(grads, state, params, *, optimizer, compression, op,
             if bound:
                 m2d = c.reshape(shape[0], -1)
                 approx, qn = _psgd_factor_sync(
-                    m2d, qmat, lambda x: allreduce(x, Average, axis=ax))
+                    m2d, qmat,
+                    lambda x: _sync_allreduce(x, Average, axis=ax))
                 new_res[i] = (m2d - approx).reshape(shape)
                 red = approx.reshape(shape)
             else:
@@ -1561,7 +1573,8 @@ def _powersgd_update(grads, state, params, *, optimizer, compression, op,
             if bound:
                 rt = int8_roundtrip(c, block)
                 new_res[i] = c - rt
-                reduced[i] = allreduce(c, op, axis=ax, compression=fallback)
+                reduced[i] = _sync_allreduce(
+                    c, op, axis=ax, compression=fallback)
             else:
                 if per_rank:
                     rt = jax.vmap(lambda v: int8_roundtrip(v, block))(c)
@@ -1574,7 +1587,7 @@ def _powersgd_update(grads, state, params, *, optimizer, compression, op,
         else:
             new_res[i] = jnp.zeros_like(c)
             if bound:
-                reduced[i] = allreduce(c, op, axis=ax)
+                reduced[i] = _sync_allreduce(c, op, axis=ax)
             elif per_rank:
                 red = c.sum(axis=0) if op == Sum else _C._div(c.sum(axis=0), n)
                 reduced[i] = red.astype(dt)
@@ -1799,6 +1812,7 @@ def DistributedOptimizer(
             "would mix them)"
         )
 
+    @_C._sync_scope("grads")
     def _allreduce_grads(grads):
         if op == Adasum and compression is Compression.none:
             return _fused_adasum_tree(grads, axis)
@@ -2015,7 +2029,7 @@ class DistributedGradientTape:
             grads = _fused_adasum_tree(grads, self._axis)
         else:
             grads = jax.tree_util.tree_map(
-                lambda g: allreduce(
+                lambda g: _sync_allreduce(
                     g, self._op, axis=self._axis,
                     compression=self._compression,
                 ),

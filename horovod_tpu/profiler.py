@@ -13,12 +13,45 @@ coordinator-only). The rebuild has two complementary layers:
   call captures device traces (HLO steps, collective time on ICI, HBM
   transfers) viewable in TensorBoard/Perfetto — the role chrome://tracing
   plays for the reference.
+
+The operator's workflow::
+
+    with hvd.profiler.timeline("/tmp/trace"):      # around a few steps
+        for _ in range(5):
+            state = step(*state, xs, ys)
+    # open /tmp/trace in xprof / Perfetto: operations group by hvd.* scope;
+    # in a script, join the trace's events to the program's own names:
+    table = hvd.profiler.scope_table()             # {module: {instr: ...}}
+    entry = table["jit_hvd1_step"]["fusion.7"]     # (op_name, HLO kind)
+    phase, kernel = hvd.profiler.scope_of(*entry)
+
+**Scope and span names are a stable interface** (``docs/observability.md``
+lists what reads each). Scopes are ``jax.named_scope`` names: HLO metadata,
+always there, no runtime cost. Spans are ``TraceMe``s on the profiler's
+clock, recorded only while a profiler session is on.
+
+Device scopes, as they read in an instruction's ``op_name``:
+
+- ``jvp(hvd.forward)`` — the differentiated loss function: the forward pass;
+  ``transpose(jvp(hvd.forward))`` — its transpose: the backward pass
+- ``hvd.sync/grads``, ``/stats``, ``/loss``, ``/params``, ``/updates`` — the
+  gradient, BatchNorm-statistics and loss exchange; ZeRO's gathers
+- ``hvd.optimizer`` — ``tx.update`` + ``optax.apply_updates``
+- ``hvd.allreduce``, ``hvd.allgather``, … (+ ``/<name>`` where the caller
+  gave ``name=``) — an in-jit ``hvd.<collective>``, a user's own included
+- ``hvd.flash_fwd`` / ``hvd.flash_bwd`` — flash attention's two halves
+- ``hvd_<kernel>`` — one ``pallas_call`` (also the Mosaic ``kernel_name``)
+
+Host spans: ``hvd.step`` (``InstrumentedStep.__call__``, a step marker
+carrying ``step_num``), ``hvd.step/dispatch`` (the wrapped step call inside
+it: the difference is what the per-step hooks cost), ``hvd.shard_batch``.
 """
 
 from __future__ import annotations
 
 import contextlib
 import os
+import re
 from typing import Optional
 
 import jax
@@ -63,10 +96,106 @@ def timeline(log_dir: str):
 
 
 def annotate(name: str):
-    """Named host-span annotation that shows up in the device trace
+    """The one host-span helper: a ``TraceMe`` on the profiler's clock (the
+    device planes' clock), recorded only while a profiler session runs at
+    host tracer level >= 1 and costing a flag read otherwise. The program's
+    own spans (``hvd.step/dispatch``, ``hvd.shard_batch``) go through it
     (analog of the reference's per-tensor ACTIVITY spans,
     ``common/common.h:31-59``)."""
     return jax.profiler.TraceAnnotation(name)
+
+
+# --------------------------------------------------------------------------
+# the scope table: a device trace event names an HLO instruction and nothing
+# of the program (its stats are offsets and durations, no ``op_name``), so the
+# program's scopes are joined in through the compiled module's metadata
+
+#: HLO kinds that move data between chips, whatever scope they inherited
+_COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
+                     "collective-permute", "all-to-all",
+                     "collective-broadcast")
+_HLO_LINE = re.compile(r"^\s*(?:ROOT )?%?([^\s=]+) = ")
+_HLO_KIND = re.compile(r" = .+? ([a-z][\w\-]*)\(")
+_HLO_BRACES = re.compile(r"\{[^{}]*\}")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _parse_hlo(text: str) -> dict:
+    """``{instruction name: (op_name, HLO kind)}`` of one module's text,
+    every computation's instructions (names are unique in a module)."""
+    table = {}
+    for line in text.splitlines():
+        m = _HLO_LINE.match(line)
+        if m is None:
+            continue
+        op = _OP_NAME.search(line)
+        # layouts and attribute groups hold parentheses of their own
+        # (``{1,0:T(8,128)}``): strip them before reading the kind
+        bare = _HLO_BRACES.sub("", _HLO_BRACES.sub("", line))
+        kind = _HLO_KIND.search(bare)
+        table[m.group(1)] = (op.group(1) if op else "",
+                             kind.group(1) if kind else "")
+    return table
+
+
+def scope_table() -> dict:
+    """``{module name: {instruction name: (op_name, HLO kind)}}`` for every
+    live executable of the backend, parsed from the compiled modules' text:
+    no handle on the jitted function and no second lowering. A trace
+    event's leading ``%name`` is the key; :func:`scope_of` reads the value.
+    A module name that repeats (two ``jit_step``s) keeps the later ones
+    under ``name#2``, ``name#3``, …"""
+    tables: dict = {}
+    for exe in jax.devices()[0].client.live_executables():
+        for mod in exe.hlo_modules():
+            key, n = mod.name, 1
+            while key in tables:
+                n += 1
+                key = f"{mod.name}#{n}"
+            tables[key] = _parse_hlo(mod.to_string())
+    return tables
+
+
+def _components(op_name: str):
+    """``op_name`` split at the ``/`` outside parentheses."""
+    parts, depth, cur = [], 0, []
+    for ch in op_name:
+        if ch == "/" and depth == 0:
+            parts.append("".join(cur))
+            cur = []
+            continue
+        depth += (ch == "(") - (ch == ")")
+        cur.append(ch)
+    parts.append("".join(cur))
+    return parts
+
+
+def scope_of(op_name: str, kind: str = ""):
+    """``(phase, kernel)`` of an instruction. ``phase`` is the first that
+    applies of ``"sync"`` (under ``hvd.sync``, or a collective by its HLO
+    kind: a bucketed exchange issued from inside the backward is sync, and
+    so is an ``all-reduce`` the partitioner inserted under some backward
+    operation's name), ``"optimizer"`` (``hvd.optimizer``), ``"backward"``
+    (``hvd.forward`` in or under a ``transpose(...)`` component: the
+    transposed pass, and what ``jax.checkpoint`` recomputes during it),
+    ``"forward"`` (any other ``hvd.forward``), else ``None``. ``kernel`` is
+    the innermost ``hvd.flash_*`` / ``hvd_<kernel>`` component, else
+    ``None``."""
+    parts = _components(op_name)
+    kernel = next((p for p in reversed(parts)
+                   if p.startswith(("hvd.flash_", "hvd_"))), None)
+    if kind.startswith(_COLLECTIVE_KINDS) or \
+            any("hvd.sync" in p for p in parts):
+        return "sync", kernel
+    if any("hvd.optimizer" in p for p in parts):
+        return "optimizer", kernel
+    at = next((i for i, p in enumerate(parts) if "hvd.forward" in p), None)
+    if at is None:
+        return None, kernel
+    # ``transpose(jvp(hvd.forward))``, or under ``jax.checkpoint``
+    # ``transpose(jvp(jvp()))/checkpoint/[rematted_computation/]hvd.forward``
+    backward = any("transpose(" in p for p in parts[:at + 1])
+    return ("backward" if backward else "forward"), kernel
 
 
 # Peak bf16 matmul throughput per chip, FLOP/s, keyed by substrings of

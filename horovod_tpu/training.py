@@ -29,6 +29,7 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from horovod_tpu import basics
+from horovod_tpu import profiler as _profiler
 from horovod_tpu.analysis import sanitizer as _sanitizer
 from horovod_tpu.observability import flight as _flight
 from horovod_tpu.observability import metrics as _metrics
@@ -36,12 +37,28 @@ from horovod_tpu.observability import regression as _regression
 from horovod_tpu.observability import slo as _slo
 from horovod_tpu.observability import straggler as _straggler
 from horovod_tpu.ops.collective import (
-    Average, allreduce, _mesh_axis_size, _smap,
+    Average, allreduce, _mesh_axis_size, _smap, _sync_scope,
 )
 from horovod_tpu.ops import overlap as _overlap
 from horovod_tpu.compression import Compression
 from horovod_tpu.resilience import health as _health
 from horovod_tpu.resilience import numerics as _numerics
+
+
+#: Prefix of the step builders' module names (``jit_hvd1_step``). JAX leaves
+#: metadata out of the persistent compile cache's key, so an edit that only
+#: moves or renames an ``hvd.*`` scope hits the cache and gets an executable
+#: with the OLD names back (a ResNet step compiled before the scopes existed
+#: was served, nameless, to the tree that has them; my chip run, PR 26). The
+#: module name IS in the key and does not depend on paths or line numbers:
+#: bump the digit with any change to the scopes these builders write.
+_SCOPES = "hvd1"
+
+
+def _named(step):
+    """``step`` under its module name, ``<_SCOPES>_<name>``."""
+    step.__name__ = f"{_SCOPES}_{step.__name__}"
+    return step
 
 
 def softmax_xent(logits, labels):
@@ -95,10 +112,8 @@ class InstrumentedStep:
 
     def _peak(self) -> Optional[float]:
         if self._peak_total is None:
-            from horovod_tpu import profiler
-
             try:
-                peak = profiler.device_peak_flops()
+                peak = _profiler.device_peak_flops()
             except ValueError as e:
                 # a gap in the telemetry table must not stop a training
                 # step; the entry points (bench.py, chip_smoke.py) raise
@@ -115,6 +130,14 @@ class InstrumentedStep:
         return self._peak_total or None
 
     def __call__(self, *args, **kwargs):
+        # host spans on the profiler's clock (recorded only while a
+        # profiler session is on): hvd.step - hvd.step/dispatch is what
+        # the hooks and the metrics block below cost per step
+        with jax.profiler.StepTraceAnnotation(
+                "hvd.step", step_num=self._step_idx):
+            return self._call(*args, **kwargs)
+
+    def _call(self, *args, **kwargs):
         # open this step's correlation scope BEFORE dispatch: eager
         # collectives issued by/around the step share (step, gen, seq)
         # keys across ranks (fleet trace correlation + straggler
@@ -131,7 +154,8 @@ class InstrumentedStep:
         # and rank-0 cross-checked here (no-op unless enabled)
         _numerics.set_step(self._step_idx)
         self._step_idx += 1
-        out = self._fn(*args, **kwargs)
+        with _profiler.annotate("hvd.step/dispatch"):
+            out = self._fn(*args, **kwargs)
         # standalone fingerprint path: without the elastic wrapper nobody
         # calls note_step, and the record published at the next boundary
         # would be a default — read the verdict from the returned state
@@ -305,6 +329,7 @@ def make_jit_train_step(
     def step(params, batch_stats, opt_state, images, labels):
         scale = _numerics.current_scale(opt_state) if guarded else None
 
+        @jax.named_scope("hvd.forward")
         def loss_and_logits(p):
             variables = {"params": p}
             if batch_stats:
@@ -329,16 +354,20 @@ def make_jit_train_step(
         )
         if scale is not None:
             loss = loss / scale
-        if guarded:
-            updates, opt_state = tx.update(
-                grads, opt_state, params, loss=loss)
-        else:
-            updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        # no exchange to wrap here: the wrapper's (DistributedOptimizer)
+        # sits under hvd.optimizer/hvd.sync, the partitioner's all-reduces
+        # are sync by their HLO kind (profiler.scope_of)
+        with jax.named_scope("hvd.optimizer"):
+            if guarded:
+                updates, opt_state = tx.update(
+                    grads, opt_state, params, loss=loss)
+            else:
+                updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, new_stats, opt_state, loss
 
     donate_argnums = (0, 1, 2) if donate else ()
-    jitted = jax.jit(step, donate_argnums=donate_argnums)
+    jitted = jax.jit(_named(step), donate_argnums=donate_argnums)
     # args: (params, batch_stats, opt_state, images, labels) -> the global
     # batch is images.shape[0]
     return instrument_step(jitted, batch_arg=3) if instrument else jitted
@@ -439,6 +468,7 @@ def make_shardmap_train_step(
         from horovod_tpu import optim as _optim
 
         def fsdp_step(params, batch_stats, opt_state, images, labels):
+            @jax.named_scope("hvd.forward")
             def loss_and_stats(fp):
                 p = _optim.fsdp_gather_params(fp)
                 variables = {"params": p}
@@ -460,18 +490,22 @@ def make_shardmap_train_step(
             # ZeRO-3 memory deal (the gather wire runs twice for it)
             (loss, new_stats), gshards = jax.value_and_grad(
                 jax.checkpoint(loss_and_stats), has_aux=True)(params)
-            new_stats = jax.tree_util.tree_map(
-                lambda s: allreduce(s, Average, axis=ax), new_stats
-            )
-            loss = allreduce(loss, Average, axis=ax)
-            updates, new_opt_state = tx.update(gshards, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with _sync_scope("stats"):
+                new_stats = jax.tree_util.tree_map(
+                    lambda s: allreduce(s, Average, axis=ax), new_stats
+                )
+            with _sync_scope("loss"):
+                loss = allreduce(loss, Average, axis=ax)
+            with jax.named_scope("hvd.optimizer"):
+                updates, new_opt_state = tx.update(
+                    gshards, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return params, new_stats, new_opt_state, loss
 
         rep = P()
         sharded = P(ax)
         smapped = _smap(
-            fsdp_step,
+            _named(fsdp_step),
             mesh,
             (P(ax), rep, P(ax), sharded, sharded),
             (P(ax), rep, P(ax), rep),
@@ -483,6 +517,7 @@ def make_shardmap_train_step(
     def shard_step(params, batch_stats, opt_state, images, labels):
         scale = _numerics.current_scale(opt_state) if guarded else None
 
+        @jax.named_scope("hvd.forward")
         def loss_and_stats(p):
             variables = {"params": p}
             if batch_stats:
@@ -526,24 +561,29 @@ def make_shardmap_train_step(
                     "allreduce", _axis_size(ax),
                     _tree_sync_wire_bytes(grads, compression),
                 )
-                grads = jax.tree_util.tree_map(
-                    lambda g: allreduce(
-                        g, reduce_op, axis=ax, compression=compression),
-                    grads,
-                )
+                with _sync_scope("grads"):
+                    grads = jax.tree_util.tree_map(
+                        lambda g: allreduce(
+                            g, reduce_op, axis=ax, compression=compression),
+                        grads,
+                    )
         # keep BN running stats replicated
-        new_stats = jax.tree_util.tree_map(
-            lambda s: allreduce(s, Average, axis=ax), new_stats
-        )
-        loss = allreduce(loss, Average, axis=ax)
-        if guarded:
-            # the guard consumes the (already rank-averaged) loss so a
-            # non-finite loss marks the step BAD alongside the grads
-            updates, new_opt_state = tx.update(
-                grads, opt_state, params, loss=loss)
-        else:
-            updates, new_opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with _sync_scope("stats"):
+            new_stats = jax.tree_util.tree_map(
+                lambda s: allreduce(s, Average, axis=ax), new_stats
+            )
+        with _sync_scope("loss"):
+            loss = allreduce(loss, Average, axis=ax)
+        with jax.named_scope("hvd.optimizer"):
+            if guarded:
+                # the guard consumes the (already rank-averaged) loss so a
+                # non-finite loss marks the step BAD alongside the grads
+                updates, new_opt_state = tx.update(
+                    grads, opt_state, params, loss=loss)
+            else:
+                updates, new_opt_state = tx.update(
+                    grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, new_stats, new_opt_state, loss
 
     rep = P()
@@ -554,7 +594,7 @@ def make_shardmap_train_step(
         # replicated; only the wrapped [N, shard] inner state rides P(ax)
         opt_spec = _numerics.shard_state_spec(P(ax))
     smapped = _smap(
-        shard_step,
+        _named(shard_step),
         mesh,
         (rep, rep, opt_spec, sharded, sharded),
         (rep, rep, opt_spec, rep),
@@ -698,7 +738,8 @@ def shard_batch(batch, *, axis: Optional[str] = None):
     sharding in every example script)."""
     mesh = basics.mesh()
     ax = axis or basics.data_axis()
-    return jax.device_put(batch, NamedSharding(mesh, P(ax)))
+    with _profiler.annotate("hvd.shard_batch"):
+        return jax.device_put(batch, NamedSharding(mesh, P(ax)))
 
 
 def replicate(tree):

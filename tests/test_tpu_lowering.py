@@ -73,6 +73,53 @@ def test_kernel_lowers_for_tpu(compiled_kernels, name):
     _lower_for_tpu(fn, *shapes)
 
 
+#: the ``hvd_<kernel>`` names each entry's Mosaic calls must carry, in
+#: issue order (``pallas_call(name=)``: the device trace's kernel scopes)
+_KERNEL_NAMES = {
+    "quantize_blockwise": ["hvd_quantize"],
+    "quantize_roundtrip": ["hvd_quantize_roundtrip"],
+    "quantize_one_block": ["hvd_quantize"],
+    "dequant_accumulate": ["hvd_dequant_accumulate"],
+    "dequant_accumulate_requantize": ["hvd_dequant_accumulate_requantize"],
+    "dequantize_rows": ["hvd_dequantize_rows"],
+    "adasum_pair_combine": [
+        "hvd_adasum_pair_reduce", "hvd_adasum_pair_blend"],
+    "adasum_pair_combine_bf16_odd": [
+        "hvd_adasum_pair_reduce", "hvd_adasum_pair_blend"],
+    "adasum_segment_combine": [
+        "hvd_adasum_segment_reduce", "hvd_adasum_segment_blend"],
+    "fused_adam": ["hvd_fused_adam"],
+    "flash_attention": ["hvd_flash_fwd"],
+}
+
+
+@pytest.mark.parametrize("name", sorted(_KERNEL_NAMES))
+def test_tpu_custom_call_carries_its_kernel_name(compiled_kernels, name):
+    """Every cross-lowered ``tpu_custom_call`` names its kernel
+    ``hvd_<kernel>``, as the Mosaic ``kernel_name`` and as the innermost
+    scope of its location: what ``profiler.scope_of`` reads as the
+    kernel."""
+    import re
+
+    if name == "fused_adam":
+        from horovod_tpu.optim import fused_adam
+
+        fn, shapes = fused_adam(1e-3).update, (F32((L,)), jax.eval_shape(
+            optax.adam(1e-3).init, F32((L,))))
+    elif name == "flash_attention":
+        fn = functools.partial(flash_attention, causal=True, use_pallas=True)
+        shapes = (S((2, 512, 4, 64), jnp.bfloat16),) * 3
+    else:
+        fn, *shapes = _KERNELS[name]
+    text = jax.jit(fn).trace(*shapes).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    want = _KERNEL_NAMES[name]
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == len(want)
+    assert re.findall(r'kernel_name = "([^"]*)"', text) == want
+    for kernel in want:
+        assert re.search(r'loc\("[^"]*%s/pallas_call"' % kernel, text), kernel
+
+
 @pytest.mark.parametrize("shape", [(L,), (30,), (N, L // N)])
 def test_fused_adam_lowers_for_tpu(compiled_kernels, shape):
     """Plain, tiny-leaf, and the vmapped ``[N, shard]`` form
