@@ -4,9 +4,9 @@ long-context stack (:mod:`horovod_tpu.parallel.ring_attention`).
 No counterpart exists in the reference — Horovod 0.19.2 shards only the batch
 axis (SURVEY.md §5.7) — so this module is TPU-native capability: an online-
 softmax attention whose working set stays in VMEM-sized tiles feeding the MXU,
-written as a Pallas kernel (grid ``[batch*heads, q_blocks, k_blocks]``,
-accumulators in VMEM scratch) with a mathematically identical ``lax.scan``
-implementation used off-TPU.
+written as a Pallas kernel (grid ``[batch*heads, q_blocks, k_blocks]``, each
+step a static schedule of sub-tiles, accumulators in VMEM scratch) with a
+mathematically identical ``lax.scan`` implementation used off-TPU.
 
 The backward pass is the standard flash backward: the forward saves only
 ``out`` and the log-sum-exp rows (O(T) extra memory, not the O(T²) score
@@ -18,11 +18,14 @@ and accumulates dq/dk/dv blockwise. The same block primitive
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from horovod_tpu.observability import metrics as _metrics
 
 NEG_INF = -1e30
 #: lse stand-in for fully-masked rows: exp(s - BIG) == 0 for any real score
@@ -156,16 +159,106 @@ def _delta(out, dout):
 # --------------------------------------------------------------------------
 # pallas kernel (TPU hot path) — emits out AND lse
 
+#: K/V block of the backward's scan (and of the off-TPU forward scan) when
+#: the caller names none. The forward kernel's tile is chosen apart from it
+#: (:func:`_fwd_tile`): the scan's f32 temporaries grow with this block.
+SCAN_BLOCK_K = 128
+#: the forward kernel's widest block (what one grid step holds of q and of
+#: k/v) and sub-tile (what one pair of products covers), in rows
+_FWD_BLOCK = 1024
+_FWD_SUBTILE = 512
+#: VMEM one grid step may fill (Mosaic's scoped default on a v5e is 16 MiB)
+_FWD_VMEM_BUDGET = 12 << 20
+_LANES = 128
+
+
+def _fwd_vmem_bytes(block, sub, head_dim: int, itemsize: int) -> int:
+    """Upper estimate of the VMEM one grid step of the forward kernel holds:
+    the double-buffered q, o, k, v and lse blocks, the softmax state, and
+    a sub-tile's live values (f32 scores and probabilities, the
+    probabilities in v's dtype, the int32 mask). Rows of fewer than 128
+    lanes are padded to 128."""
+    def pad(n):
+        return -(-n // _LANES) * _LANES
+
+    (bq, bk), (cq, ck) = block, sub
+    blocks = 2 * (2 * bq + 2 * bk) * pad(head_dim) * itemsize
+    lse = 2 * bq * _LANES * 4
+    state = bq * (2 * _LANES + pad(head_dim)) * 4
+    live = cq * pad(ck) * (4 + 4 + itemsize + 4)
+    return blocks + lse + state + live
+
+
+def _fit(t: int, cap: int) -> Optional[int]:
+    """The largest block of at most ``cap`` rows that divides ``t`` and the
+    TPU tiles: a multiple of 8 rows, or ``t`` itself. None if there is
+    none."""
+    if t <= cap:
+        return t
+    return next((b for b in range(cap - cap % 8, 7, -8) if t % b == 0), None)
+
+
+def _sub_tile(blk: int) -> int:
+    """Rows of a block's sub-tile: every sub-tile is unrolled into the
+    kernel, so a block that only splits into more than four a side is
+    computed whole."""
+    c = _fit(blk, _FWD_SUBTILE)
+    return c if c and blk // c <= 4 else blk
+
+
+def _fwd_tile(t_q: int, t_k: int, head_dim: int, dtype):
+    """The forward kernel's block ``(bq, bk)`` and sub-tile ``(cq, ck)``
+    from the shapes alone: the widest that divide the sequence and fit the
+    VMEM budget. A Pallas grid step costs about 0.4 us whatever it does and
+    ends the compiler's overlap of one sub-tile's products with another's
+    softmax, so few wide steps beat many narrow ones; the sub-tile bounds
+    the live [cq, ck] values and, under a causal mask, what is computed
+    beyond the diagonal. A side with no legal block (no multiple of 8
+    divides it) comes back as None."""
+    itemsize = jnp.dtype(dtype).itemsize
+    block = [_fit(t_q, _FWD_BLOCK), _fit(t_k, _FWD_BLOCK)]
+    if None in block:
+        return tuple(block), None
+    while True:
+        sub = tuple(_sub_tile(blk) for blk in block)
+        if _fwd_vmem_bytes(block, sub, head_dim, itemsize) <= _FWD_VMEM_BUDGET:
+            break
+        # shrink the wider side (both, if equal: square blocks cross the
+        # diagonal only on it) to its next legal block, if it has one
+        widest = max(block)
+        smaller = [_fit(t, blk - 1) if blk == widest else blk
+                   for t, blk in zip((t_q, t_k), block)]
+        if None in smaller:
+            break
+        block = smaller
+    return tuple(block), sub
+
+
+def _lane_cols(x, n: int):
+    """``x`` [rows, 128], every lane of a row the same value, as [rows, n]."""
+    if n <= _LANES:
+        return x[:, :n]
+    if n % _LANES == 0:
+        return jnp.tile(x, (1, n // _LANES))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       m_scratch, l_scratch, acc_scratch,
-                      *, sm_scale: float, causal: bool, block_q: int,
-                      block_k: int):
+                      *, sm_scale: float, causal: bool, block, sub,
+                      past_blocks: bool):
+    """One grid step: the q block ``[bq, D]`` against the k/v block
+    ``[bk, D]``, as a static schedule of ``[cq, ck]`` sub-tiles in one basic
+    block, so the compiler overlaps one sub-tile's products with another's
+    softmax. ``past_blocks``: some grid step lies wholly below the diagonal
+    (the q sequence spans more than one block)."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     n_k = pl.num_programs(2)
+    (bq, bk), (cq, ck) = block, sub
+    head_dim = q_ref.shape[-1]
 
     @pl.when(kj == 0)
     def _init():
@@ -173,56 +266,110 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_scratch[:] = jnp.zeros_like(l_scratch)
         acc_scratch[:] = jnp.zeros_like(acc_scratch)
 
-    # All online-softmax state is kept 2-D ([bq, 1] keepdims columns):
-    # Mosaic's TPU lowering wants >=2-D vectors, and (bq, 1) broadcasts
-    # cleanly against both s [bq, bk] and acc [bq, D].
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * sm_scale     # [bq, D]
-        k = k_ref[0].astype(jnp.float32)                # [bk, D]
-        v = v_ref[0].astype(jnp.float32)
+    # The online-softmax state m, l is lane-dense: [bq, 128] with every lane
+    # of a row the same value, so reads, writes and the broadcasts against
+    # s [cq, ck] and acc [cq, D] are whole-vreg operations.
+    # A power-of-two scale (1/8 at D 64) is exact on q in any dtype; any
+    # other is applied to the f32 scores.
+    scale_q = math.frexp(sm_scale)[0] == 0.5
+
+    def _products(i: int, j: int, diagonal):
+        """Sub-tile (i, j) of the block. ``diagonal``: None for no mask,
+        else row r sees column c where ``r - c >= diagonal``."""
+        rows, cols = pl.ds(i * cq, cq), pl.ds(j * ck, ck)
+        q, k, v = q_ref[0, rows, :], k_ref[0, cols, :], v_ref[0, cols, :]
+        if scale_q:
+            q = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
+        # operands in the dtype they arrive in (bf16 x bf16 products are
+        # exact in f32), accumulation in f32
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [bq, bk]
-        if causal:
-            q_ids = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_ids = kj * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_ids >= k_ids, s, NEG_INF)
-        m_prev = m_scratch[:]                            # [bq, 1]
+            preferred_element_type=jnp.float32)         # [cq, ck]
+        if not scale_q:
+            s = s * sm_scale
+        if diagonal is not None:
+            r = lax.broadcasted_iota(jnp.int32, (cq, ck), 0)
+            c = lax.broadcasted_iota(jnp.int32, (cq, ck), 1)
+            s = jnp.where(r - c >= diagonal, s, NEG_INF)
+        m_prev = m_scratch[rows, :]                      # [cq, 128]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)                  # [bq, 1]
-        l_new = l_scratch[:] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_scratch[:] = (
-            acc_scratch[:] * alpha
+        p = jnp.exp(s - _lane_cols(m_new, ck))
+        alpha = jnp.exp(m_prev - m_new)                  # [cq, 128]
+        l_scratch[rows, :] = (
+            l_scratch[rows, :] * alpha + p.sum(axis=-1, keepdims=True))
+        acc_scratch[rows, :] = (
+            acc_scratch[rows, :] * _lane_cols(alpha, head_dim)
             + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        )
-        m_scratch[:] = m_new
-        l_scratch[:] = l_new
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        m_scratch[rows, :] = m_new
 
-    if causal:
-        # whole block strictly in the future -> skip
-        @pl.when(kj * block_k <= qi * block_q + (block_q - 1))
-        def _():
-            _compute()
+    def _block(rel):
+        """The block whose first q row is ``rel`` rows past its first k
+        column: None for no mask at all, an int for a schedule known now
+        (only sub-tiles on or below the diagonal, masks only on it), a
+        traced value for one decided per sub-tile on the chip."""
+        for i in range(bq // cq):
+            for j in range(bk // ck):
+                if rel is None:
+                    _products(i, j, None)
+                    continue
+                # first row and column of the sub-tile, from the block's
+                # first column
+                r0, c0 = rel + i * cq, j * ck
+                needed = c0 <= r0 + (cq - 1)
+                if not isinstance(rel, int):
+                    pl.when(needed)(
+                        functools.partial(_products, i, j, c0 - r0))
+                elif needed:
+                    past = c0 + (ck - 1) <= r0
+                    _products(i, j, None if past else c0 - r0)
+
+    if not causal:
+        _block(None)
     else:
-        _compute()
+        rel = qi * bq - kj * bk
+        if past_blocks:
+            pl.when(rel >= bk - 1)(functools.partial(_block, None))
+        if bq == bk:
+            # square blocks cross the diagonal only on it
+            pl.when(rel == 0)(functools.partial(_block, 0))
+        else:
+            pl.when(jnp.logical_and(rel < bk - 1, rel > -bq))(
+                functools.partial(_block, rel))
 
     @pl.when(kj == n_k - 1)
     def _write():
-        m, l = m_scratch[:], l_scratch[:]                # [bq, 1]
+        m, l = m_scratch[:], l_scratch[:]                # [bq, 128]
         safe_l = jnp.where(l > 0, l, 1.0)
-        out = acc_scratch[:] / safe_l
-        o_ref[0] = jnp.where(l > 0, out, 0.0).astype(o_ref.dtype)
-        lse_ref[0] = jnp.where(
-            l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), LSE_MASKED)
+        out = acc_scratch[:] / _lane_cols(safe_l, head_dim)
+        o_ref[0] = jnp.where(
+            _lane_cols(l, head_dim) > 0, out, 0.0).astype(o_ref.dtype)
+        lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), LSE_MASKED)
+        lse_ref[0] = lse[:, :1]
+
+
+def _record_fwd_tile(bq: int, bk: int, grid) -> None:
+    """Trace-time gauges of the tile a run compiled its forward with."""
+    if not _metrics.enabled():
+        return
+    for dim, blk in (("q", bq), ("k", bk)):
+        _metrics.gauge(
+            "flash_fwd_tile",
+            help="rows of the flash forward kernel's q / k tile, chosen "
+                 "from the shapes at trace time",
+            dim=dim,
+        ).set(blk)
+    _metrics.gauge(
+        "flash_fwd_grid_steps",
+        help="grid steps of one flash forward call (batch*heads x q blocks "
+             "x k blocks)",
+    ).set(math.prod(grid))
 
 
 def _flash_fwd_pallas(q, k, v, *, causal: bool, sm_scale: float,
-                      block_q: int, block_k: int, interpret: bool):
+                      block_q: Optional[int], block_k: Optional[int],
+                      interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -230,13 +377,23 @@ def _flash_fwd_pallas(q, k, v, *, causal: bool, sm_scale: float,
     t_k = k.shape[1]
     h_kv = k.shape[2]
     g = h // h_kv
-    bq, bk = _block_sizes(t_q, t_k, block_q, block_k)
-    for name, blk, t in (("q", bq, t_q), ("k", bk, t_k)):
-        if blk % 8 and blk != t:
+    if block_q or block_k:
+        # an explicit block is honoured (down to what divides the sequence)
+        block = _block_sizes(t_q, t_k, block_q or _FWD_BLOCK,
+                             block_k or _FWD_BLOCK)
+        sub = tuple(_sub_tile(blk) for blk in block)
+    else:
+        block, sub = _fwd_tile(t_q, t_k, d, q.dtype)
+    for name, blk, t in (("q", block[0], t_q), ("k", block[1], t_k)):
+        if blk is None or (blk % 8 and blk != t):
             raise ValueError(
-                f"flash attention: sequence length {t} leaves a {name} "
-                f"block of {blk} rows, below the 8-row TPU tile; pad the "
-                f"sequence to a multiple of 8 (128 for full-size blocks)")
+                f"flash attention: no block of at least 8 rows divides the "
+                f"{name} sequence length {t} (got {blk}), below the 8-row "
+                f"TPU tile; pad the sequence to a multiple of 8 (128 for "
+                f"full-size blocks)")
+    (bq, bk), (cq, ck) = block, sub
+    grid = (b * h, t_q // bq, t_k // bk)
+    _record_fwd_tile(bq, bk, grid)
 
     # [B*H, T, D] layout: one grid row per (batch, head). K/V keep their
     # H_kv rows; GQA maps each query head's grid row onto its kv head in
@@ -245,21 +402,26 @@ def _flash_fwd_pallas(q, k, v, *, causal: bool, sm_scale: float,
     kr = k.transpose(0, 2, 1, 3).reshape(b * h_kv, t_k, d)
     vr = v.transpose(0, 2, 1, 3).reshape(b * h_kv, t_k, d)
 
-    def kv_row(bh):
+    def kv_index(bh, qi, kj):
         # grid row bh = batch*h + head  ->  kv row = batch*h_kv + head//g
-        return (bh // h) * h_kv + (bh % h) // g
+        row = (bh // h) * h_kv + (bh % h) // g
+        if causal:
+            # a wholly-future block keeps the index of the last block its
+            # q rows need: the pipeline sees no change and issues no copy
+            kj = jnp.minimum(kj, (qi * bq + (bq - 1)) // bk)
+        return row, kj, 0
 
     kernel = functools.partial(
         _flash_fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=bq, block_k=bk,
+        block=(bq, bk), sub=(cq, ck), past_blocks=t_q > bq,
     )
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b * h, t_q // bq, t_k // bk),
+        grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, qi, kj: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, kj: (kv_row(bh), kj, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, kj: (kv_row(bh), kj, 0)),
+            pl.BlockSpec((1, bk, d), kv_index),
+            pl.BlockSpec((1, bk, d), kv_index),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, qi, kj: (bh, qi, 0)),
@@ -274,8 +436,8 @@ def _flash_fwd_pallas(q, k, v, *, causal: bool, sm_scale: float,
             jax.ShapeDtypeStruct((b * h, t_q, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+            pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
@@ -327,7 +489,7 @@ def _fwd_impl(q, k, v, causal, sm_scale, block_sizes):
     m, l, acc = _attention_scan(
         q, rep_group(k, g), rep_group(v, g), causal=causal,
         sm_scale=sm_scale,
-        q_offset=0, kv_offset=0, block_k=block_k)
+        q_offset=0, kv_offset=0, block_k=block_k or SCAN_BLOCK_K)
     return _finalize(m, l, acc, q.dtype), lse_from_state(m, l)
 
 
@@ -349,7 +511,7 @@ def _flash_bwd(causal, sm_scale, block_sizes, res, g):
     H_kv-wide under GQA; each block is broadcast per step and its gradient
     group-summed back (repeat's transpose — adjacent-copy layout)."""
     q, k, v, out, lse = res
-    block_k = block_sizes[1]
+    block_k = block_sizes[1] or SCAN_BLOCK_K
     b, t_k, h_kv, d = k.shape
     h = q.shape[2]
     grp = h // h_kv
@@ -494,7 +656,8 @@ def repeat_kv_heads(q, k, v):
 
 def flash_attention(q, k, v, *, causal: bool = False,
                     sm_scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     use_pallas: Optional[bool] = None,
                     interpret: bool = False):
     """Memory-efficient attention. ``q``: [B, Tq, H, D]; ``k``/``v``:
@@ -508,6 +671,16 @@ def flash_attention(q, k, v, *, causal: bool = False,
     maps each query head's grid row onto its kv head (no H-wide K/V buffer
     exists), residuals save the H_kv-wide K/V, and the scan path's
     per-block broadcast fuses under jit.
+
+    ``block_q`` / ``block_k`` default to None: the forward kernel tiles
+    itself from ``(t_q, t_k, head_dim, dtype)`` (:func:`_fwd_tile`: the
+    widest blocks of at most 1024 rows that divide the sequences and fit
+    VMEM, computed as sub-tiles of at most 512 x 512; the gauges
+    ``flash_fwd_tile`` / ``flash_fwd_grid_steps`` say what a trace chose),
+    while the backward's scan and the off-TPU forward scan keep K/V blocks
+    of ``SCAN_BLOCK_K`` = 128 rows: their f32 temporaries grow with the
+    block. An explicit integer is honoured by all three, down to what
+    divides the sequence.
     """
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise ValueError("q/k/v must be [batch, seq, heads, head_dim]")

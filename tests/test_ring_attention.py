@@ -411,3 +411,160 @@ def test_flash_attention_pallas_gqa_interpret_matches_dense():
                 jnp.repeat(v, h // h_kv, axis=2), causal=causal)
             np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                        rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------------- the forward's shape-chosen tile
+
+
+from horovod_tpu.ops import flash_attention as fa
+
+
+def _legal(block, t):
+    return t % block == 0 and (block % 8 == 0 or block == t)
+
+
+#: (t_q, t_k, heads, kv_heads, dtype, causal) — one B, few heads: interpret
+#: mode walks every grid step in Python
+_TILE_CASES = [
+    (8, 8, 2, 2, "bfloat16", True),
+    (24, 24, 2, 2, "bfloat16", True),
+    (136, 136, 2, 2, "bfloat16", True),
+    (384, 384, 2, 2, "bfloat16", True),
+    (1024, 1024, 2, 2, "bfloat16", True),
+    (2048, 2048, 1, 1, "bfloat16", True),
+    (1024, 1024, 1, 1, "float32", True),
+    (1024, 1024, 1, 1, "bfloat16", False),
+    (2048, 2048, 1, 1, "float32", False),
+    (1024, 1024, 4, 2, "bfloat16", True),     # GQA
+    (1024, 1024, 4, 1, "float32", True),      # MQA
+    (384, 1024, 2, 2, "bfloat16", True),      # t_q != t_k: unequal blocks
+    (1024, 384, 2, 1, "float32", True),
+    (2048, 1024, 1, 1, "bfloat16", True),
+    (1000, 1000, 1, 1, "float32", True),      # 5 x 5 blocks of 200
+]
+
+
+@pytest.mark.parametrize("t_q,t_k,h,h_kv,dtype,causal", _TILE_CASES)
+def test_flash_forward_chosen_tile_matches_dense(t_q, t_k, h, h_kv, dtype,
+                                                 causal):
+    """No block named: the kernel tiles itself from the shapes, and still
+    agrees with dense float32 attention."""
+    d = 64
+    (bq, bk), (cq, ck) = fa._fwd_tile(t_q, t_k, d, dtype)
+    assert _legal(bq, t_q) and _legal(bk, t_k), (bq, bk)
+    assert _legal(cq, bq) and _legal(ck, bk), (cq, ck)
+    rng = np.random.RandomState(t_q + t_k + h)
+    q, k, v = (jnp.asarray(rng.randn(1, t, n, d), dtype)
+               for t, n in ((t_q, h), (t_k, h_kv), (t_k, h_kv)))
+    out = flash_attention(q, k, v, causal=causal, use_pallas=True,
+                          interpret=True)
+    assert out.dtype == q.dtype and out.shape == q.shape
+    f32 = lambda x, n=1: jnp.repeat(x.astype(jnp.float32), n, axis=2)
+    ref = dense_attention(f32(q), f32(k, h // h_kv), f32(v, h // h_kv),
+                          causal=causal)
+    tol = 2e-5 if dtype == "float32" else 2e-2   # bf16: the output's own ulp
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               rtol=tol, atol=tol)
+
+
+def test_fwd_tile_at_the_benchmark_shape_is_pinned():
+    """What PERF.md §6 (PR 27) records for GPT-2 medium's attention: one
+    1024 x 1024 block a (batch, head), computed as 512 x 512 sub-tiles."""
+    assert fa._fwd_tile(1024, 1024, 64, jnp.bfloat16) == (
+        (1024, 1024), (512, 512))
+    # longer sequences keep the block; wide float32 heads shrink it, square
+    assert fa._fwd_tile(32768, 32768, 128, jnp.bfloat16)[0] == (1024, 1024)
+    bq, bk = fa._fwd_tile(2048, 2048, 512, jnp.float32)[0]
+    assert bq == bk < 1024
+    # no multiple of 8 divides 1028 = 4 * 257
+    assert fa._fwd_tile(1028, 1028, 64, jnp.bfloat16) == ((None, None), None)
+
+
+def _eqns(jaxpr, name):
+    """Every equation of primitive ``name``, nested jaxprs included."""
+    found = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == name:
+            found.append(e)
+        for p in e.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _eqns(sub, name)
+    return found
+
+
+def _pallas_grid(fn, *args):
+    calls = _eqns(jax.make_jaxpr(fn)(*args).jaxpr, "pallas_call")
+    assert len(calls) == 1
+    return tuple(calls[0].params["grid_mapping"].grid)
+
+
+def test_flash_forward_tile_gauges_and_explicit_blocks(hvd):
+    """``flash_fwd_tile`` / ``flash_fwd_grid_steps`` say which tile a trace
+    compiled with; an explicit block is still honoured."""
+    x = jax.ShapeDtypeStruct((2, 1024, 4, 64), jnp.bfloat16)
+    flash = functools.partial(flash_attention, causal=True, use_pallas=True)
+    assert _pallas_grid(flash, x, x, x) == (8, 1, 1)
+    assert hvd.metrics.value("flash_fwd_tile", dim="q") == 1024
+    assert hvd.metrics.value("flash_fwd_tile", dim="k") == 1024
+    assert hvd.metrics.value("flash_fwd_grid_steps") == 8
+    explicit = functools.partial(flash, block_q=16, block_k=32)
+    assert _pallas_grid(explicit, x, x, x) == (8, 64, 32)
+    assert hvd.metrics.value("flash_fwd_tile", dim="q") == 16
+    assert hvd.metrics.value("flash_fwd_tile", dim="k") == 32
+    assert hvd.metrics.value("flash_fwd_grid_steps") == 8 * 64 * 32
+
+
+def test_fully_masked_rows_give_zeros_and_lse_masked():
+    """Rows no key reached (ring attention skips K/V shards wholly in the
+    causal future, so their state stays at its start: l == 0) come out as
+    zeros with ``LSE_MASKED``, not NaN, and a recomputed probability
+    against that lse vanishes. The Pallas kernel never meets such a row
+    (row i always sees key 0) but keeps the same guard at its write."""
+    b, h, t, d = 1, 2, 16, 8
+    q, k, v = qkv(b=b, t=t, h=h, d=d, seed=7)
+    m, l, acc = fa._attention_scan(
+        q[:, 8:], k, v, causal=True, sm_scale=d ** -0.5, q_offset=8,
+        kv_offset=0, block_k=8)
+    # rows 0..7 untouched, rows 8..15 attended
+    pad = lambda x, fill: jnp.concatenate(
+        [jnp.full(x.shape[:2] + (8,) + x.shape[3:], fill, x.dtype), x], axis=2)
+    m, l, acc = pad(m, fa.NEG_INF), pad(l, 0.0), pad(acc, 0.0)
+    out = np.asarray(fa._finalize(m, l, acc, q.dtype))
+    lse = np.asarray(fa.lse_from_state(m, l))
+    assert np.isfinite(out).all() and np.isfinite(lse).all()
+    assert (out[:, :8] == 0).all() and (lse[:, :, :8] == fa.LSE_MASKED).all()
+    np.testing.assert_allclose(
+        out[:, 8:], np.asarray(dense_attention(q, k, v, causal=True))[:, 8:],
+        rtol=2e-5, atol=2e-5)
+    assert np.exp(1e4 - lse[:, :, :8]).max() == 0.0
+
+
+def test_chosen_tile_leaves_the_backward_alone():
+    """With no block named the forward takes its own tile, the backward
+    still scans K/V in blocks of 128 (its f32 temporaries grow with the
+    block), and the gradients are those of the explicit 128 blocks."""
+    t, d = 1024, 64
+    rng = np.random.RandomState(3)
+    q, k, v = (jnp.asarray(rng.randn(1, t, 1, d), jnp.float32)
+               for _ in range(3))
+
+    def loss(q, k, v, **kw):
+        return (flash_attention(q, k, v, causal=True, use_pallas=True,
+                                interpret=True, **kw) ** 2).sum()
+
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    jaxpr = jax.make_jaxpr(grad)(q, k, v)
+    scans = _eqns(jaxpr.jaxpr, "scan")
+    assert [e.params["length"] for e in scans] == [t // fa.SCAN_BLOCK_K] == [8]
+    blocks = [v_.aval.shape for e in scans for v_ in e.invars
+              if v_.aval.shape[:1] == (8,) and len(v_.aval.shape) == 5]
+    assert blocks and all(s[2] == 128 for s in blocks), blocks
+    g_chosen = grad(q, k, v)
+    g_parent = jax.grad(
+        functools.partial(loss, block_q=128, block_k=128),
+        argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_chosen, g_parent):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)

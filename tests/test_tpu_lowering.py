@@ -136,17 +136,33 @@ def test_fused_adam_lowers_for_tpu(compiled_kernels, shape):
         _lower_for_tpu(fa.update, g, jax.eval_shape(ref.init, g))
 
 
-@pytest.mark.parametrize("heads,kv_heads,head_dim", [
-    (12, 12, 64),   # TransformerSmall
-    (4, 4, 128),
-    (8, 2, 128),    # GQA
-    (4, 1, 64),     # MQA
+@pytest.mark.parametrize("batch,seq,heads,kv_heads,head_dim", [
+    (2, 512, 12, 12, 64),    # TransformerSmall
+    (2, 512, 4, 4, 128),
+    (2, 512, 8, 2, 128),     # GQA
+    (2, 512, 4, 1, 64),      # MQA
+    (8, 1024, 16, 16, 64),   # the benchmark's GPT-2 medium cell
+    (1, 4096, 4, 4, 128),    # several blocks a row: all three block kinds
 ])
-def test_flash_attention_lowers_for_tpu(heads, kv_heads, head_dim):
+def test_flash_attention_lowers_for_tpu(batch, seq, heads, kv_heads,
+                                        head_dim):
+    """The shape-chosen tile passes the Pallas TPU lowering, and the one
+    custom call a forward makes is still ``hvd_flash_fwd`` with results
+    ``(bf16[B*H, T, D], f32[B*H, T, 1])``: what the benchmark's
+    ``flash_fwd_roofline.train`` finds it by."""
+    import re
+
     flash = functools.partial(flash_attention, causal=True, use_pallas=True)
-    q = S((2, 512, heads, head_dim), jnp.bfloat16)
-    kv = S((2, 512, kv_heads, head_dim), jnp.bfloat16)
-    _lower_for_tpu(flash, q, kv, kv)
+    q = S((batch, seq, heads, head_dim), jnp.bfloat16)
+    kv = S((batch, seq, kv_heads, head_dim), jnp.bfloat16)
+    text = jax.jit(flash).trace(q, kv, kv).lower(
+        lowering_platforms=("tpu",)).as_text()
+    calls = [l for l in text.splitlines() if "@tpu_custom_call" in l]
+    assert len(calls) == 1
+    assert re.findall(r'kernel_name = "([^"]*)"', text) == ["hvd_flash_fwd"]
+    rows = batch * heads
+    assert (f"-> (tensor<{rows}x{seq}x{head_dim}xbf16>, "
+            f"tensor<{rows}x{seq}x1xf32>)") in calls[0]
     # the backward recomputes from the kernel's (out, lse) residuals
     _lower_for_tpu(
         jax.grad(lambda q, k, v: flash(q, k, v).astype(jnp.float32).sum(),
@@ -216,9 +232,46 @@ def test_flash_attention_leaves_placement_to_its_caller(hvd):
 
 
 def test_flash_attention_rejects_blocks_below_the_tile():
-    """T=204 halves the 128-row block down to 4 rows — an error here, not
-    a kernel Mosaic refuses."""
+    """No multiple of 8 divides T = 1028 = 4 * 257, and the sequence is
+    too long for one block — an error here, not a kernel Mosaic refuses.
+    (T = 204 runs as one 204-row block.)"""
     x = S((1, 204, 2, 64), jnp.bfloat16)
+    jax.eval_shape(functools.partial(flash_attention, use_pallas=True),
+                   x, x, x)
+    x = S((1, 1028, 2, 64), jnp.bfloat16)
     with pytest.raises(ValueError, match="below the 8-row TPU tile"):
         jax.eval_shape(
             functools.partial(flash_attention, use_pallas=True), x, x, x)
+
+
+# ----------------------------------------- Mosaic itself, for a described chip
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip():
+    """A v5e chip that is described, not attached: the TPU compiler runs
+    Mosaic for it here, so a tile that overflows the scoped VMEM or a
+    slice off the tiling fails in tier-1, not on the chip."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("batch,seq,heads,kv_heads,head_dim,dtype", [
+    (8, 1024, 16, 16, 64, "bfloat16"),   # the benchmark's GPT-2 medium cell
+    (2, 2048, 4, 2, 128, "bfloat16"),    # chip_smoke's GQA head, 2 x 2 blocks
+    (1, 2048, 2, 2, 256, "float32"),     # the widest operands: a shrunk block
+])
+def test_flash_forward_compiles_for_v5e(one_v5e_chip, batch, seq, heads,
+                                        kv_heads, head_dim, dtype):
+    flash = functools.partial(flash_attention, causal=True, use_pallas=True)
+    q = S((batch, seq, heads, head_dim), dtype, sharding=one_v5e_chip)
+    kv = S((batch, seq, kv_heads, head_dim), dtype, sharding=one_v5e_chip)
+    compiled = jax.jit(flash).lower(q, kv, kv).compile()
+    assert "hvd_flash_fwd" in compiled.as_text()
