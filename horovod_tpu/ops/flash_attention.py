@@ -11,8 +11,11 @@ mathematically identical ``lax.scan`` implementation used off-TPU.
 The backward pass is the standard flash backward: the forward saves only
 ``out`` and the log-sum-exp rows (O(T) extra memory, not the O(T²) score
 matrix); the backward recomputes each block's probabilities from (q, k, lse)
-and accumulates dq/dk/dv blockwise. The same block primitive
-(:func:`_block_bwd`) powers ring attention's distributed backward.
+and accumulates dq/dk/dv blockwise. On the Pallas path it is Pallas too
+(:func:`_flash_bwd_pallas`: the tiles stay in VMEM; one fused call where the
+sequence is one block, a dk/dv call and a dq call where it is more);
+elsewhere a ``lax.scan`` over K/V blocks, whose block primitive
+(:func:`_block_bwd`) also powers ring attention's distributed backward.
 """
 
 from __future__ import annotations
@@ -159,9 +162,10 @@ def _delta(out, dout):
 # --------------------------------------------------------------------------
 # pallas kernel (TPU hot path) — emits out AND lse
 
-#: K/V block of the backward's scan (and of the off-TPU forward scan) when
-#: the caller names none. The forward kernel's tile is chosen apart from it
-#: (:func:`_fwd_tile`): the scan's f32 temporaries grow with this block.
+#: K/V block of the scans (the off-TPU forward, and the backward wherever
+#: the Pallas backward has no block) when the caller names none. The
+#: kernels' tiles are chosen apart from it (:func:`_fwd_tile`,
+#: :func:`_bwd_tile`): the scans' f32 temporaries grow with this block.
 SCAN_BLOCK_K = 128
 #: the forward kernel's widest block (what one grid step holds of q and of
 #: k/v) and sub-tile (what one pair of products covers), in rows
@@ -172,15 +176,18 @@ _FWD_VMEM_BUDGET = 12 << 20
 _LANES = 128
 
 
+def _lane_pad(n: int) -> int:
+    """``n`` lanes as VMEM holds them: whole vregs of 128."""
+    return -(-n // _LANES) * _LANES
+
+
 def _fwd_vmem_bytes(block, sub, head_dim: int, itemsize: int) -> int:
     """Upper estimate of the VMEM one grid step of the forward kernel holds:
     the double-buffered q, o, k, v and lse blocks, the softmax state, and
     a sub-tile's live values (f32 scores and probabilities, the
     probabilities in v's dtype, the int32 mask). Rows of fewer than 128
     lanes are padded to 128."""
-    def pad(n):
-        return -(-n // _LANES) * _LANES
-
+    pad = _lane_pad
     (bq, bk), (cq, ck) = block, sub
     blocks = 2 * (2 * bq + 2 * bk) * pad(head_dim) * itemsize
     lse = 2 * bq * _LANES * 4
@@ -449,6 +456,370 @@ def _flash_fwd_pallas(q, k, v, *, causal: bool, sm_scale: float,
 
 
 # --------------------------------------------------------------------------
+# pallas backward (TPU hot path): p recomputed from (q, k, lse) in VMEM
+
+#: VMEM one backward grid step may fill, and the scoped limit its calls ask
+#: Mosaic for (the default is 16 MiB of the v5e's 128; the cells' tile needs
+#: about 14, wide float32 heads more)
+_BWD_VMEM_BUDGET = 24 << 20
+_BWD_VMEM_LIMIT = 32 << 20
+
+
+def _bwd_vmem_bytes(blk: int, sub: int, head_dim: int, itemsize: int) -> int:
+    """Upper estimate of the VMEM one grid step of a backward kernel holds:
+    the double-buffered q, k, v, o, do blocks and dq, dk, dv blocks, the
+    lane-dense lse and delta and their transposes, three f32 accumulators, and
+    a sub-tile's live values (f32 scores, probabilities, dp and ds, the
+    probabilities and ds in the operands' dtype, the int32 mask)."""
+    pad = _lane_pad
+    blocks = 2 * 8 * blk * pad(head_dim) * itemsize
+    stats = (2 + 2) * blk * _LANES * 4
+    acc = 3 * blk * pad(head_dim) * 4
+    live = sub * pad(sub) * (4 * 4 + 2 * itemsize + 4)
+    return blocks + stats + acc + live
+
+
+def _bwd_tile(t_q: int, t_k: int, head_dim: int, dtype,
+              block_q: Optional[int] = None, block_k: Optional[int] = None):
+    """The Pallas backward's square block and sub-tile ``(blk, sub)`` in
+    rows, from the shapes alone as :func:`_fwd_tile` chooses the forward's:
+    the widest block of at most 1024 rows that divides the sequence and
+    fits the VMEM budget, computed as sub-tiles of at most 512 x 512. An
+    explicit block is honoured, down to what divides the sequence. None
+    where the kernels have no block and the scan serves: ``t_q != t_k``,
+    unequal explicit blocks, a sequence of several blocks that no multiple
+    of 128 divides."""
+    if t_q != t_k:
+        return None
+    if block_q or block_k:
+        bq, bk = _block_sizes(t_q, t_k, block_q or _FWD_BLOCK,
+                              block_k or _FWD_BLOCK)
+        blk = bq if bq == bk else None
+    else:
+        itemsize = jnp.dtype(dtype).itemsize
+        blk = _fit(t_q, _FWD_BLOCK)
+        while blk and _bwd_vmem_bytes(
+                blk, _sub_tile(blk), head_dim, itemsize) > _BWD_VMEM_BUDGET:
+            blk = _fit(t_q, blk - 1)
+    # lse reaches the kernels as rows [1, blk]: whole lanes, or all of them
+    if not blk or (blk % _LANES and blk != t_q):
+        return None
+    return blk, _sub_tile(blk)
+
+
+def _record_bwd_tile(blk: int, grid_steps: int) -> None:
+    """Trace-time gauges of the tile a run compiled its Pallas backward
+    with; a backward that scans sets none."""
+    if not _metrics.enabled():
+        return
+    for dim in ("q", "k"):
+        _metrics.gauge(
+            "flash_bwd_tile",
+            help="rows of the flash backward kernels' (square) q / k block, "
+                 "chosen from the shapes at trace time",
+            dim=dim,
+        ).set(blk)
+    _metrics.gauge(
+        "flash_bwd_grid_steps",
+        help="grid steps of one flash backward: batch*heads for the fused "
+             "call, the dk/dv call's plus the dq call's otherwise",
+    ).set(grid_steps)
+
+
+def _dot(a, b, contract_a: int, contract_b: int):
+    return jax.lax.dot_general(
+        a, b, (((contract_a,), (contract_b,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _bwd_block(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *,
+               sm_scale: float, sub: int, diagonal: bool,
+               want_dq: bool, want_dkv: bool):
+    """Gradient contributions of the square block the refs hold (q, o, do,
+    lse: its q rows; k, v: its k rows), as a static schedule of ``[sub,
+    sub]`` sub-tiles in one basic block. ``diagonal``: the block lies on
+    the causal diagonal, so only sub-tiles on or below it are computed and
+    only those on it are masked; else every sub-tile, unmasked.
+
+    Per sub-tile, the flash backward: s = q k^T and p = exp(s - lse) again,
+    dp = do v^T, ds = p (dp - delta) with delta = rowsum(do o), all in f32;
+    then dv += p^T do, dk += ds^T q, dq += ds k with p and ds rounded to
+    the operands' dtype and f32 accumulation. Returns ``(dq, dk, dv)``:
+    per sub-tile row (dq) or column (dk, dv) an f32 ``[sub, D]`` value with
+    ``sm_scale`` applied, or None where unwanted."""
+    from jax.experimental import pallas as pl
+
+    n = q_ref.shape[1] // sub
+    dtype = q_ref.dtype
+    # a power-of-two scale (1/8 at D 64) is exact on q in any dtype, and
+    # the scaled q gives dk its scale too; any other goes on the f32 values
+    scale_q = math.frexp(sm_scale)[0] == 0.5
+    # lse and delta are read by every sub-tile: lane-dense [rows, 128],
+    # once a block. lse arrives as a row [1, rows] (a [rows, 1] column is
+    # padded to 128 lanes in HBM, and as the forward's residual cost 67 MB a
+    # layer at [128, 1024]): broadcast over sublanes, then transposed
+    lse = jnp.broadcast_to(lse_ref[0], (_LANES, n * sub)).T
+    delta = jnp.broadcast_to(
+        jnp.sum(do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32),
+                axis=-1, keepdims=True), (n * sub, _LANES))
+    dq, dk, dv = [None] * n, [None] * n, [None] * n
+
+    def add(acc, at, x):
+        acc[at] = x if acc[at] is None else acc[at] + x
+
+    for i in range(n):
+        rows = pl.ds(i * sub, sub)
+        q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+        if scale_q:
+            q = (q.astype(jnp.float32) * sm_scale).astype(dtype)
+        for j in range(i + 1 if diagonal else n):
+            cols = pl.ds(j * sub, sub)
+            k, v = k_ref[0, cols, :], v_ref[0, cols, :]
+            s = _dot(q, k, 1, 1)                         # [sub, sub]
+            if not scale_q:
+                s = s * sm_scale
+            if diagonal and i == j:
+                r = lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+                c = lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+                s = jnp.where(r >= c, s, NEG_INF)
+            # a fully-masked row's lse is LSE_MASKED: p underflows to 0
+            p = jnp.exp(s - _lane_cols(lse[i * sub:(i + 1) * sub], sub))
+            dp = _dot(do, v, 1, 1)
+            ds = p * (dp - _lane_cols(delta[i * sub:(i + 1) * sub], sub))
+            ds = ds.astype(dtype)
+            if want_dkv:
+                add(dv, j, _dot(p.astype(dtype), do, 0, 0))
+                add(dk, j, _dot(ds, q, 0, 0))
+            if want_dq:
+                add(dq, i, _dot(ds, k, 1, 0))
+    if want_dq:
+        dq = [x * sm_scale for x in dq]
+    if want_dkv and not scale_q:
+        dk = [x * sm_scale for x in dk]
+    return dq, dk, dv
+
+
+def _store_tiles(ref, tiles, sub: int) -> None:
+    """Write a block's f32 sub-tile values to its output block."""
+    from jax.experimental import pallas as pl
+
+    for i, x in enumerate(tiles):
+        ref[0, pl.ds(i * sub, sub), :] = x.astype(ref.dtype)
+
+
+def _add_tiles(scratch, tiles, sub: int) -> None:
+    """Add a block's f32 sub-tile values to its accumulator."""
+    from jax.experimental import pallas as pl
+
+    for i, x in enumerate(tiles):
+        scratch[pl.ds(i * sub, sub), :] += x
+
+
+def hvd_flash_bwd(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                  dq_ref, dk_ref, dv_ref, *scratch,
+                  sm_scale: float, causal: bool, sub: int):
+    """The whole backward of one (batch, head) whose sequence is one block:
+    five products a sub-tile. Grid ``[batch*kv_heads, group]``: under GQA
+    the group's q heads take turns on the resident dk/dv block, summed in
+    f32 scratch."""
+    from jax.experimental import pallas as pl
+
+    dq, dk, dv = _bwd_block(
+        q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, sm_scale=sm_scale,
+        sub=sub, diagonal=causal, want_dq=True, want_dkv=True)
+    _store_tiles(dq_ref, dq, sub)
+    if not scratch:                   # one q head a kv head: no sum
+        _store_tiles(dk_ref, dk, sub)
+        _store_tiles(dv_ref, dv, sub)
+        return
+    dk_scratch, dv_scratch = scratch
+    gi = pl.program_id(1)
+
+    @pl.when(gi == 0)
+    def _init():
+        dk_scratch[:] = jnp.zeros_like(dk_scratch)
+        dv_scratch[:] = jnp.zeros_like(dv_scratch)
+
+    _add_tiles(dk_scratch, dk, sub)
+    _add_tiles(dv_scratch, dv, sub)
+
+    @pl.when(gi == pl.num_programs(1) - 1)
+    def _write():
+        dk_ref[0] = dk_scratch[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scratch[:].astype(dv_ref.dtype)
+
+
+def _bwd_blocks(causal: bool, qi, kj, block):
+    """Run ``block(diagonal)`` for the grid step's (q block, k block) pair:
+    under the causal mask the diagonal block by its triangle, a past block
+    whole, a future block not at all."""
+    from jax.experimental import pallas as pl
+
+    if not causal:
+        block(False)
+        return
+    pl.when(qi == kj)(functools.partial(block, True))
+    pl.when(qi > kj)(functools.partial(block, False))
+
+
+def hvd_flash_bwd_dkv(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                      dk_ref, dv_ref, dk_scratch, dv_scratch,
+                      *, sm_scale: float, causal: bool, sub: int):
+    """dk and dv of one k block, summed over the q blocks (and, under GQA,
+    the group's q heads) that see it. Grid ``[batch*kv_heads, k blocks,
+    group, q blocks]``, the last two the reduction."""
+    from jax.experimental import pallas as pl
+
+    kj, gi, qi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    last = jnp.logical_and(gi == pl.num_programs(2) - 1,
+                           qi == pl.num_programs(3) - 1)
+
+    @pl.when(jnp.logical_and(gi == 0, qi == 0))
+    def _init():
+        dk_scratch[:] = jnp.zeros_like(dk_scratch)
+        dv_scratch[:] = jnp.zeros_like(dv_scratch)
+
+    def block(diagonal: bool):
+        _, dk, dv = _bwd_block(
+            q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, sm_scale=sm_scale,
+            sub=sub, diagonal=diagonal, want_dq=False, want_dkv=True)
+        _add_tiles(dk_scratch, dk, sub)
+        _add_tiles(dv_scratch, dv, sub)
+
+    _bwd_blocks(causal, qi, kj, block)
+
+    @pl.when(last)
+    def _write():
+        dk_ref[0] = dk_scratch[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scratch[:].astype(dv_ref.dtype)
+
+
+def hvd_flash_bwd_dq(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                     dq_ref, dq_scratch,
+                     *, sm_scale: float, causal: bool, sub: int):
+    """dq of one q block, summed over the k blocks it sees. Grid
+    ``[batch*heads, q blocks, k blocks]``, the last the reduction."""
+    from jax.experimental import pallas as pl
+
+    qi, kj = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(kj == 0)
+    def _init():
+        dq_scratch[:] = jnp.zeros_like(dq_scratch)
+
+    def block(diagonal: bool):
+        dq, _, _ = _bwd_block(
+            q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, sm_scale=sm_scale,
+            sub=sub, diagonal=diagonal, want_dq=True, want_dkv=False)
+        _add_tiles(dq_scratch, dq, sub)
+
+    _bwd_blocks(causal, qi, kj, block)
+
+    @pl.when(kj == pl.num_programs(2) - 1)
+    def _write():
+        dq_ref[0] = dq_scratch[:].astype(dq_ref.dtype)
+
+
+def _flash_bwd_pallas(q, k, v, out, lse, dout, *, causal: bool,
+                      sm_scale: float, blk: int, sub: int, interpret: bool):
+    """dq, dk, dv by the Pallas kernels, in square blocks of ``blk`` rows.
+    One block a sequence: one fused call (:func:`hvd_flash_bwd`). More: dk
+    and dv accumulate over q blocks and dq over k blocks, which no one grid
+    order keeps resident, so two calls share the sub-tile body and each
+    recomputes p (seven products for five). The calls carry no ``name=``:
+    their device time then lies under the caller's ``hvd.flash_bwd`` scope
+    with the kernel *functions'* names as Mosaic kernel names."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, h, d = q.shape
+    h_kv = k.shape[2]
+    g = h // h_kv
+    n = t // blk
+    _record_bwd_tile(blk, b * h if n == 1 else 2 * b * h * n * n)
+
+    def rows_first(x):               # [B, T, H, D] -> [B*H, T, D]
+        return x.transpose(0, 2, 1, 3).reshape(-1, t, d)
+
+    def heads_first(x, heads):       # and back
+        return x.reshape(b, heads, t, d).transpose(0, 2, 1, 3)
+
+    # lse as rows [1, T]: a [T, 1] column is padded to 128 lanes in HBM
+    args = (rows_first(q), rows_first(k), rows_first(v), rows_first(out),
+            rows_first(dout), lse.reshape(b * h, 1, t))
+    kernel_kw = dict(sm_scale=sm_scale, causal=causal, sub=sub)
+    call_kw = dict(
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_BWD_VMEM_LIMIT))
+    q_shape = jax.ShapeDtypeStruct((b * h, t, d), q.dtype)
+    kv_shape = jax.ShapeDtypeStruct((b * h_kv, t, d), k.dtype)
+    f32 = functools.partial(pltpu.VMEM, dtype=jnp.float32)
+
+    if n == 1:
+        # grid row bkv = batch*h_kv + kv head; its group's q heads are the
+        # g rows from bkv*g
+        def q_index(bkv, gi):
+            return bkv * g + gi, 0, 0
+
+        q_spec = pl.BlockSpec((1, t, d), q_index)
+        kv_spec = pl.BlockSpec((1, t, d), lambda bkv, gi: (bkv, 0, 0))
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(hvd_flash_bwd, **kernel_kw),
+            grid=(b * h_kv, g),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec,
+                      pl.BlockSpec((1, 1, t), q_index)],
+            out_specs=[q_spec, kv_spec, kv_spec],
+            out_shape=[q_shape, kv_shape, kv_shape],
+            scratch_shapes=[f32((t, d))] * 2 if g > 1 else [],
+            **call_kw)(*args)
+    else:
+        # under the causal mask a wholly-future block keeps the index of the
+        # nearest block the step needs: the pipeline sees no change and
+        # issues no copy
+        def dkv_q_index(bkv, kj, gi, qi):
+            return bkv * g + gi, jnp.maximum(qi, kj) if causal else qi
+
+        q_spec = pl.BlockSpec(
+            (1, blk, d), lambda *at: (*dkv_q_index(*at), 0))
+
+        def dkv_lse_index(*at):
+            row, qi = dkv_q_index(*at)
+            return row, 0, qi
+
+        lse_spec = pl.BlockSpec((1, 1, blk), dkv_lse_index)
+        kv_spec = pl.BlockSpec(
+            (1, blk, d), lambda bkv, kj, gi, qi: (bkv, kj, 0))
+        dk, dv = pl.pallas_call(
+            functools.partial(hvd_flash_bwd_dkv, **kernel_kw),
+            grid=(b * h_kv, n, g, n),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec],
+            out_specs=[kv_spec, kv_spec],
+            out_shape=[kv_shape, kv_shape],
+            scratch_shapes=[f32((blk, d))] * 2,
+            **call_kw)(*args)
+
+        def dq_kv_index(bh, qi, kj):
+            # grid row bh = batch*h + head  ->  kv row, as the forward's
+            return ((bh // h) * h_kv + (bh % h) // g,
+                    jnp.minimum(kj, qi) if causal else kj, 0)
+
+        q_spec = pl.BlockSpec((1, blk, d), lambda bh, qi, kj: (bh, qi, 0))
+        kv_spec = pl.BlockSpec((1, blk, d), dq_kv_index)
+        (dq,) = pl.pallas_call(
+            functools.partial(hvd_flash_bwd_dq, **kernel_kw),
+            grid=(b * h, n, n),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec,
+                      pl.BlockSpec((1, 1, blk),
+                                   lambda bh, qi, kj: (bh, 0, qi))],
+            out_specs=[q_spec],
+            out_shape=[q_shape],
+            scratch_shapes=[f32((blk, d))],
+            **call_kw)(*args)
+    return heads_first(dq, h), heads_first(dk, h_kv), heads_first(dv, h_kv)
+
+
+# --------------------------------------------------------------------------
 # public op with flash (blockwise-recompute) backward
 
 
@@ -506,12 +877,29 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_sizes):
 
 @jax.named_scope("hvd.flash_bwd")
 def _flash_bwd(causal, sm_scale, block_sizes, res, g):
-    """O(T) extra-memory backward: scan K/V blocks, recomputing p from lse
-    (saves no score matrix — the flash-attention trade). Residual K/V stay
-    H_kv-wide under GQA; each block is broadcast per step and its gradient
-    group-summed back (repeat's transpose — adjacent-copy layout)."""
+    """O(T) extra-memory backward: p is recomputed from lse block by block
+    (saves no score matrix — the flash-attention trade). On the Pallas path
+    the kernels of :func:`_flash_bwd_pallas`, wherever :func:`_bwd_tile`
+    has a block for the shapes; else the scan."""
+    block_q, block_k, use_pallas, interpret = block_sizes
     q, k, v, out, lse = res
-    block_k = block_sizes[1] or SCAN_BLOCK_K
+    tile = use_pallas and _bwd_tile(
+        q.shape[1], k.shape[1], q.shape[3], q.dtype, block_q, block_k)
+    if tile:
+        return _flash_bwd_pallas(
+            q, k, v, out, lse, g, causal=causal, sm_scale=sm_scale,
+            blk=tile[0], sub=tile[1], interpret=interpret)
+    return _flash_bwd_scan(q, k, v, out, lse, g, causal=causal,
+                           sm_scale=sm_scale, block_k=block_k)
+
+
+def _flash_bwd_scan(q, k, v, out, lse, g, *, causal: bool, sm_scale: float,
+                    block_k: Optional[int]):
+    """The backward as a scan over K/V blocks of ``block_k`` (default
+    ``SCAN_BLOCK_K``) rows in f32. Residual K/V stay H_kv-wide under GQA;
+    each block is broadcast per step and its gradient group-summed back
+    (repeat's transpose — adjacent-copy layout)."""
+    block_k = block_k or SCAN_BLOCK_K
     b, t_k, h_kv, d = k.shape
     h = q.shape[2]
     grp = h // h_kv
@@ -665,22 +1053,25 @@ def flash_attention(q, k, v, *, causal: bool = False,
     (H_kv < H) broadcasts each K/V head over its query group; MQA is
     ``H_kv == 1``. Returns [B, Tq, H, D].
 
-    ``use_pallas`` defaults to True on TPU backends (the VMEM-tiled kernel)
-    and False elsewhere (the scan path). Both paths share the blockwise
-    lse-recompute backward. GQA is zero-copy end-to-end: the Pallas kernel
-    maps each query head's grid row onto its kv head (no H-wide K/V buffer
-    exists), residuals save the H_kv-wide K/V, and the scan path's
-    per-block broadcast fuses under jit.
+    ``use_pallas`` defaults to True on TPU backends (the VMEM-tiled
+    kernels, forward and backward) and False elsewhere (the scan path).
+    Both backwards recompute p blockwise from the saved lse. GQA is
+    zero-copy end-to-end: the Pallas kernels map each query head's grid row
+    onto its kv head (no H-wide K/V buffer exists; the backward sums a
+    group's dk/dv in VMEM), residuals save the H_kv-wide K/V, and the scan
+    path's per-block broadcast fuses under jit.
 
-    ``block_q`` / ``block_k`` default to None: the forward kernel tiles
-    itself from ``(t_q, t_k, head_dim, dtype)`` (:func:`_fwd_tile`: the
-    widest blocks of at most 1024 rows that divide the sequences and fit
-    VMEM, computed as sub-tiles of at most 512 x 512; the gauges
-    ``flash_fwd_tile`` / ``flash_fwd_grid_steps`` say what a trace chose),
-    while the backward's scan and the off-TPU forward scan keep K/V blocks
-    of ``SCAN_BLOCK_K`` = 128 rows: their f32 temporaries grow with the
-    block. An explicit integer is honoured by all three, down to what
-    divides the sequence.
+    ``block_q`` / ``block_k`` default to None: the kernels tile themselves
+    from ``(t_q, t_k, head_dim, dtype)`` (:func:`_fwd_tile`,
+    :func:`_bwd_tile`: the widest blocks of at most 1024 rows that divide
+    the sequences and fit VMEM, computed as sub-tiles of at most 512 x 512;
+    the gauges ``flash_fwd_tile`` / ``flash_fwd_grid_steps`` and
+    ``flash_bwd_tile`` / ``flash_bwd_grid_steps`` say what a trace chose),
+    while the scans (off-TPU, and the backward where the kernels have no
+    block: ``t_q != t_k``, unequal explicit blocks) keep K/V blocks of
+    ``SCAN_BLOCK_K`` = 128 rows: their f32 temporaries grow with the block.
+    An explicit integer is honoured by all of them, down to what divides
+    the sequence.
     """
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise ValueError("q/k/v must be [batch, seq, heads, head_dim]")

@@ -541,30 +541,206 @@ def test_fully_masked_rows_give_zeros_and_lse_masked():
     assert np.exp(1e4 - lse[:, :, :8]).max() == 0.0
 
 
-def test_chosen_tile_leaves_the_backward_alone():
-    """With no block named the forward takes its own tile, the backward
-    still scans K/V in blocks of 128 (its f32 temporaries grow with the
-    block), and the gradients are those of the explicit 128 blocks."""
+def _flash_grads(q, k, v, causal=True, weights=None, **kw):
+    """Gradients of a weighted sum of the Pallas path's output (interpret
+    mode), in float32."""
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=causal, use_pallas=True,
+                              interpret=True, **kw).astype(jnp.float32)
+        return (out * weights).sum() if weights is not None else (
+            out ** 2).sum()
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def test_pallas_backward_engages_and_matches_the_scan():
+    """With no block named the backward on the Pallas path is the fused
+    kernel, not the scan, and its gradients are those of the scan in
+    explicit blocks of 128 (the parent's backward)."""
     t, d = 1024, 64
     rng = np.random.RandomState(3)
     q, k, v = (jnp.asarray(rng.randn(1, t, 1, d), jnp.float32)
                for _ in range(3))
+    jaxpr = jax.make_jaxpr(_flash_grads)(q, k, v)
+    assert not _eqns(jaxpr.jaxpr, "scan")
+    grids = [tuple(e.params["grid_mapping"].grid)
+             for e in _eqns(jaxpr.jaxpr, "pallas_call")]
+    assert grids == [(1, 1, 1), (1, 1)], grids      # forward, fused backward
+    g_pallas = _flash_grads(q, k, v)
 
-    def loss(q, k, v, **kw):
-        return (flash_attention(q, k, v, causal=True, use_pallas=True,
-                                interpret=True, **kw) ** 2).sum()
+    def scan_loss(q, k, v):
+        return (flash_attention(q, k, v, causal=True, use_pallas=False,
+                                block_k=128) ** 2).sum()
+
+    scan = jax.grad(scan_loss, argnums=(0, 1, 2))
+    assert [e.params["length"] for e in _eqns(
+        jax.make_jaxpr(scan)(q, k, v).jaxpr, "scan")] == [8, 8]
+    for a, b in zip(g_pallas, scan(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
+
+
+#: (t, heads, kv_heads, head_dim, dtype, causal) -> the backward's blocks a
+#: side. One B, few heads: interpret mode walks every grid step in Python
+_BWD_CASES = [
+    (8, 2, 2, 64, "float32", True),
+    (24, 2, 2, 64, "bfloat16", True),
+    (136, 2, 2, 64, "bfloat16", False),
+    (384, 2, 2, 128, "float32", True),
+    (1024, 2, 2, 64, "bfloat16", True),        # the cells' tile, fused
+    (1024, 1, 1, 64, "float32", True),
+    (1024, 1, 1, 128, "bfloat16", False),
+    (1024, 4, 2, 64, "bfloat16", True),        # GQA: the group's sum
+    (1024, 4, 1, 64, "float32", True),         # MQA
+    (2048, 1, 1, 64, "float32", True),         # two blocks: dk/dv + dq calls
+    (2048, 2, 1, 128, "bfloat16", True),       # two blocks, MQA
+    (2048, 1, 1, 64, "bfloat16", False),
+    (1536, 1, 1, 64, "float32", True),         # two blocks of 768
+    (200, 1, 1, 64, "float32", True),          # one block, not whole lanes
+]
+
+
+@pytest.mark.parametrize("t,h,h_kv,d,dtype,causal", _BWD_CASES)
+def test_flash_backward_pallas_matches_dense(t, h, h_kv, d, dtype, causal):
+    """The Pallas backward at its shape-chosen tile against ``jax.grad`` of
+    dense float32 attention."""
+    blk, sub = fa._bwd_tile(t, t, d, dtype)
+    assert _legal(blk, t) and _legal(sub, blk), (blk, sub)
+    rng = np.random.RandomState(t + h + d)
+    q, k, v = (jnp.asarray(rng.randn(1, t, n, d), dtype)
+               for n in (h, h_kv, h_kv))
+    w = jnp.asarray(rng.randn(1, t, h, d), jnp.float32)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        _flash_grads, causal=causal, weights=w))(q, k, v)
+    assert not _eqns(jaxpr.jaxpr, "scan")
+    assert len(_eqns(jaxpr.jaxpr, "pallas_call")) == (2 if blk == t else 3)
+    got = _flash_grads(q, k, v, causal=causal, weights=w)
+    f32 = lambda x, n=1: jnp.repeat(x.astype(jnp.float32), n, axis=2)
+
+    def dense_loss(q, k, v):
+        return (dense_attention(q, f32(k, h // h_kv), f32(v, h // h_kv),
+                                causal=causal) * w).sum()
+
+    want = jax.grad(dense_loss, argnums=(0, 1, 2))(f32(q), f32(k), f32(v))
+    for name, a, b in zip("qkv", got, want):
+        assert a.dtype == jnp.dtype(dtype) and a.shape == b.shape, name
+        # bf16: operands, p and ds are rounded once each, the result once
+        tol = 2e-4 if dtype == "float32" else 3e-2
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b), rtol=tol,
+            atol=tol * float(jnp.abs(b).max()), err_msg="d" + name)
+
+
+def test_bwd_tile_at_the_benchmark_shape_is_pinned():
+    """What PERF.md §6 (PR 31) records for GPT-2 medium's attention: one
+    1024-row block a (batch, head) in one fused call, as 512 x 512
+    sub-tiles; the scan where the kernels have no block."""
+    assert fa._bwd_tile(1024, 1024, 64, jnp.bfloat16) == (1024, 512)
+    assert fa._bwd_tile(32768, 32768, 128, jnp.bfloat16) == (1024, 512)
+    assert fa._bwd_tile(2048, 2048, 128, jnp.float32) == (1024, 512)
+    blk, sub = fa._bwd_tile(2048, 2048, 512, jnp.float32)  # wide f32 heads
+    assert blk < 1024 and 2048 % blk == 0
+    assert fa._bwd_vmem_bytes(1024, 512, 64, 2) < fa._BWD_VMEM_BUDGET \
+        < fa._BWD_VMEM_LIMIT
+    # explicit blocks: square ones are honoured, unequal ones scan
+    assert fa._bwd_tile(1024, 1024, 64, jnp.bfloat16, 128, 128) == (128, 128)
+    assert fa._bwd_tile(1024, 1024, 64, jnp.bfloat16, 128, 256) is None
+    assert fa._bwd_tile(1024, 1024, 64, jnp.bfloat16, None, 128) is None
+    assert fa._bwd_tile(384, 1024, 64, jnp.bfloat16) is None
+    # several blocks take lse in rows of whole lanes: 2000 = 10 x 200 has
+    # no block that is a multiple of 128, 1028 = 4 * 257 none of 8
+    assert fa._bwd_tile(2000, 2000, 64, jnp.bfloat16) is None
+    assert fa._bwd_tile(1028, 1028, 64, jnp.bfloat16) is None
+    assert fa._bwd_tile(1024, 1024, 64, jnp.bfloat16, 16, 16) is None
+    assert fa._bwd_tile(200, 200, 64, jnp.bfloat16) == (200, 200)
+
+
+def test_flash_backward_gauges_say_which_backward_was_traced(hvd):
+    """``flash_bwd_tile`` / ``flash_bwd_grid_steps`` are set when the Pallas
+    backward is traced and absent when the scan is."""
+    hvd.metrics.REGISTRY.reset()
+    x = jax.ShapeDtypeStruct((2, 1024, 4, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, 1024, 2, 64), jnp.bfloat16)
+    jax.make_jaxpr(functools.partial(_flash_grads, block_q=128,
+                                     block_k=256))(x, kv, kv)
+    assert hvd.metrics.value("flash_fwd_tile", dim="k") == 256
+    assert hvd.metrics.value("flash_bwd_tile", dim="q") is None
+    assert hvd.metrics.value("flash_bwd_grid_steps") is None
+    jax.make_jaxpr(_flash_grads)(x, kv, kv)
+    assert hvd.metrics.value("flash_bwd_tile", dim="q") == 1024
+    assert hvd.metrics.value("flash_bwd_tile", dim="k") == 1024
+    assert hvd.metrics.value("flash_bwd_grid_steps") == 8
+    jax.make_jaxpr(functools.partial(_flash_grads, block_q=256,
+                                     block_k=256))(x, kv, kv)
+    assert hvd.metrics.value("flash_bwd_tile", dim="q") == 256
+    # dk/dv: 2*2 kv rows x 4 k blocks x 2 q heads x 4 q blocks; dq: 8 x 4 x 4
+    assert hvd.metrics.value("flash_bwd_grid_steps") == 128 + 128
+
+
+@pytest.mark.parametrize("case", ["use_pallas=False", "t_q != t_k",
+                                  "unequal blocks", "blocks of 16 rows",
+                                  "no legal block"])
+def test_flash_backward_fallbacks_still_scan(case):
+    """Where the kernels have no block the backward is the parent's scan,
+    with the parent's gradients."""
+    rng = np.random.RandomState(11)
+    t_q, t_k, kw = 64, 64, dict(use_pallas=True, interpret=True)
+    if case == "use_pallas=False":
+        kw = dict(use_pallas=False, block_k=16)
+    elif case == "t_q != t_k":
+        t_q = 32
+    elif case == "unequal blocks":
+        kw.update(block_q=16, block_k=32)
+    elif case == "blocks of 16 rows":    # several blocks, not whole lanes
+        kw.update(block_q=16, block_k=16)
+    elif case == "no legal block":
+        # the forward takes 5 x 5 blocks of 200 rows; lse reaches the
+        # backward's kernels in rows of whole lanes, and 200 is not
+        t_q = t_k = 1000
+    q, k, v = (jnp.asarray(rng.randn(1, t, 2, 16), jnp.float32)
+               for t in (t_q, t_k, t_k))
+    causal = t_q == t_k
+
+    def loss(q, k, v, fn=functools.partial(flash_attention, **kw)):
+        return (fn(q, k, v, causal=causal) ** 2).sum()
+
+    def dense_loss(q, k, v):
+        return (dense_attention(q, k, v, causal=causal) ** 2).sum()
 
     grad = jax.grad(loss, argnums=(0, 1, 2))
     jaxpr = jax.make_jaxpr(grad)(q, k, v)
-    scans = _eqns(jaxpr.jaxpr, "scan")
-    assert [e.params["length"] for e in scans] == [t // fa.SCAN_BLOCK_K] == [8]
-    blocks = [v_.aval.shape for e in scans for v_ in e.invars
-              if v_.aval.shape[:1] == (8,) and len(v_.aval.shape) == 5]
-    assert blocks and all(s[2] == 128 for s in blocks), blocks
-    g_chosen = grad(q, k, v)
-    g_parent = jax.grad(
-        functools.partial(loss, block_q=128, block_k=128),
-        argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g_chosen, g_parent):
+    got = grad(q, k, v)
+    want = jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v)
+    bwd_calls = [e for e in _eqns(jaxpr.jaxpr, "pallas_call")
+                 if len(e.outvars) != 2 or e.outvars[1].aval.shape[-1] != 1]
+    assert not bwd_calls and _eqns(jaxpr.jaxpr, "scan")
+    for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("blocks", [None, 128])
+def test_pallas_backward_of_fully_masked_rows_is_zero(blocks):
+    """Residuals as ring attention's skipped shards leave them (l == 0:
+    out 0, lse ``LSE_MASKED``): the kernels recompute p = 0 there, so the
+    rows' dq is exactly zero, nothing is non-finite, and dk / dv are the
+    scan's on the same residuals."""
+    b, t, h, d = 1, 256, 2, 16
+    q, k, v = qkv(b=b, t=t, h=h, d=d, seed=5)
+    g = jnp.asarray(np.random.RandomState(6).randn(b, t, h, d), jnp.float32)
+    sm_scale = d ** -0.5
+    out, res = fa._flash_fwd(q, k, v, True, sm_scale,
+                             (None, None, False, False))
+    masked = np.zeros((t,), bool)
+    masked[:8] = masked[150:160] = True
+    out = jnp.where(masked[None, :, None, None], 0.0, out)
+    lse = jnp.where(masked[None, None, :], fa.LSE_MASKED, res[4])
+    res = (q, k, v, out, lse)
+    bwd = functools.partial(fa._flash_bwd, True, sm_scale)
+    got = bwd((blocks, blocks, True, True), res, g)
+    want = bwd((blocks, blocks, False, False), res, g)
+    assert all(np.isfinite(np.asarray(x)).all() for x in got)
+    assert (np.asarray(got[0])[:, masked] == 0).all()
+    assert np.abs(np.asarray(got[0])[:, ~masked]).min() > 0
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-4, atol=2e-4)

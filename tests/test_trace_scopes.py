@@ -151,6 +151,12 @@ def test_scope_table_holds_the_live_step(hvd):
      ("forward", "hvd_flash_fwd")),
     (_BWD + "/hvd.flash_bwd/while/body/closed_call/mul", "fusion",
      ("backward", "hvd.flash_bwd")),
+    # the Pallas backward's calls carry no name of their own: kernel and
+    # glue are both keyed by the scope flash_bwd_roofline.train reads
+    (_BWD + "/hvd.flash_bwd/pallas_call", "custom-call",
+     ("backward", "hvd.flash_bwd")),
+    (_BWD + "/hvd.flash_bwd/transpose", "fusion",
+     ("backward", "hvd.flash_bwd")),
     ("jit(step)/hvd.optimizer/mul", "fusion", ("optimizer", None)),
     ("jit(step)/hvd.optimizer/hvd_fused_adam/pallas_call", "custom-call",
      ("optimizer", "hvd_fused_adam")),
