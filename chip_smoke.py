@@ -459,8 +459,8 @@ def phase_train(sz, hvd, model, log):
         make_shardmap_train_step(model, tx, loss_fn=token_xent),
         (params, replicate(tx.init(params))), batches, sz, hvd, log)
 
-    # builder 2: one global jit + DistributedOptimizer — what bench.py and
-    # examples/transformer_lm_benchmark.py run
+    # builder 2: one global jit + DistributedOptimizer — what the one-chip
+    # benchmark cells and examples/transformer_lm_benchmark.py run
     dtx = hvd.DistributedOptimizer(optax.adamw(lr))
     params = replicate(hvd.broadcast_parameters(init))
     params, global_jit = _train_builder(

@@ -45,7 +45,9 @@ struct GlobalState {
   // reference HorovodGlobalState (global_state.h:42-122)
   std::atomic<bool> initialized{false};
   std::atomic<bool> shutdown_requested{false};
-  std::atomic<bool> shutdown_complete{false};
+  // set once the background loop has left its last cycle, BEFORE it
+  // aborts what is pending: an enqueue that comes later aborts its own
+  std::atomic<bool> loop_exited{false};
   int rank = 0;
   int size = 1;
   double cycle_time_ms = 5.0;  // reference operations.cc:427
@@ -172,13 +174,10 @@ void RunLoopOnce(std::chrono::steady_clock::time_point& last_cycle) {
   }
 }
 
-void BackgroundThreadLoop() {
-  auto last_cycle = std::chrono::steady_clock::now();
-  while (!g.shutdown_requested.load()) {
-    RunLoopOnce(last_cycle);
-  }
-  // abort everything still pending with shutdown error
-  // (reference operations.cc:526-532)
+// Abort everything still pending with the shutdown error (reference
+// operations.cc:526-532). Called by the background loop as it exits and by
+// an enqueue that finds the loop gone: a handle is drained by one of them.
+void AbortPending() {
   auto handles = g.tensor_queue.DrainAllHandles();
   if (g.exec_cb != nullptr && !handles.empty()) {
     ResponseList l;
@@ -198,8 +197,16 @@ void BackgroundThreadLoop() {
     g.exec_cb(payload.data(), static_cast<int>(payload.size()),
               handles.data(), static_cast<int>(handles.size()));
   }
+}
+
+void BackgroundThreadLoop() {
+  auto last_cycle = std::chrono::steady_clock::now();
+  while (!g.shutdown_requested.load()) {
+    RunLoopOnce(last_cycle);
+  }
+  g.loop_exited.store(true);
+  AbortPending();
   g.timeline.Shutdown();
-  g.shutdown_complete.store(true);
 }
 
 }  // namespace
@@ -221,7 +228,7 @@ int hvd_core_init(int rank, int size, const char* coordinator_host,
   g.size = size;
   g.cycle_time_ms = cycle_time_ms > 0 ? cycle_time_ms : 5.0;
   g.shutdown_requested.store(false);
-  g.shutdown_complete.store(false);
+  g.loop_exited.store(false);
   // the .so (and its globals) outlives init/shutdown cycles in one
   // process: a previous session's tuned toggles must not leak into a
   // fresh session as "already applied"
@@ -331,7 +338,13 @@ int hvd_core_enqueue(const char* name, int request_type, int dtype,
   e.meta.tensor_shape = TensorShape(std::move(d));
   g.timeline.NegotiateStart(e.meta.tensor_name, request_type);
   Status s = g.tensor_queue.AddToTensorQueue(e);
-  return s.ok() ? 0 : 1;  // 1 = duplicate name
+  if (!s.ok()) return 1;  // duplicate name
+  // The loop can end by itself (a lost peer, the stall shutdown) while the
+  // caller still holds a live core: nothing would ever drain this entry and
+  // its waiter would hang to its own timeout. The flag is set before the
+  // loop's drain, so either that drain took the entry or this one does.
+  if (g.loop_exited.load()) AbortPending();
+  return 0;
 }
 
 int hvd_core_pending(void) {

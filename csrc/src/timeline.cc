@@ -11,6 +11,9 @@ void Timeline::Initialize(const std::string& path, int rank) {
   rank_ = rank;
   t0_ = std::chrono::steady_clock::now();
   std::fputs("[\n", file_);
+  // the .so outlives init/shutdown cycles in one process: a second
+  // session's first event must not open with the separator
+  first_event_ = true;
   shutdown_.store(false);
   writer_ = std::thread([this] { WriterLoop(); });
   initialized_.store(true);

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Synthetic benchmark for the torch frontend — analog of reference
 ``examples/pytorch_synthetic_benchmark.py`` (img/s with allreduced grads).
-The model is a small conv net (torch runs on host CPU here; the flagship
-TPU benchmark is the JAX ``bench.py`` at the repo root)."""
+The model is a small conv net (torch runs on host CPU here; what is
+measured on the TPU is ``benchmarks/run.py``'s cells)."""
 
 import argparse
 import time

@@ -5,9 +5,9 @@ BASELINE config 2): Keras application model, synthetic images,
 ``DistributedGradientTape`` + optional fp16 compression, img/s per iter.
 
 The TF2 path exercises the frontend end-to-end (gradient tape wrapping,
-compression, broadcast_variables); the flagship TPU number comes from the JAX
-``bench.py`` at the repo root, which drives the same collective layer from a
-jitted XLA training step.
+compression, broadcast_variables); the TPU number for the same workload is
+the ``resnet50_train_1chip`` cell of ``benchmarks/run.py``, which drives the
+same collective layer from a jitted XLA training step.
 """
 
 import argparse

@@ -1,10 +1,10 @@
 """Synthetic causal-LM training benchmark: tokens/s/chip + MFU.
 
-The image families' analog lives in ``bench.py``; this harness gives the
-transformer stack (the long-context/TPU-native side of the framework) the
-same hardware perf story: one DP train step over all visible chips, bf16
-compute, optional flash attention (Pallas) and GQA, cost-analysis-derived
-MFU. Prints ONE JSON line, same shape as ``bench.py``'s.
+A harness for the transformer stack at sizes of the caller's choosing: one
+DP train step over all visible chips, bf16 compute, optional flash
+attention (Pallas) and GQA, cost-analysis-derived MFU. Prints ONE JSON line
+naming its device. The numbers every PR is held to come from the GPT-2
+cells of ``benchmarks/run.py``, not from here.
 
     python examples/transformer_lm_benchmark.py --dim 2048 --depth 16
 

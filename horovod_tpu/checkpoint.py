@@ -279,7 +279,7 @@ def state_nbytes(state: Any) -> int:
     """Raw array bytes a full checkpoint of `state` persists (the ``.npz``
     member payload, before zip framing) — the denominator of the serving
     layer's delta-vs-full-checkpoint wire comparison
-    (``bench.py --publish-ab``, ``scaling_projection.publish_bytes``)."""
+    (``tools/scaling_projection.py::publish_bytes``)."""
     return sum(
         np.asarray(leaf).nbytes
         for leaf in jax.tree_util.tree_leaves(state)

@@ -3,7 +3,8 @@
 The reference ships models only as examples/benchmark harnesses
 (``examples/tensorflow2_synthetic_benchmark.py`` uses Keras ResNet-50,
 ``examples/tensorflow2_mnist.py`` a small CNN); these are their TPU-native
-(flax) equivalents, used by ``bench.py`` and the test suite.
+(flax) equivalents, used by ``benchmarks/``, the examples and the test
+suite.
 """
 
 from horovod_tpu.models.resnet import (  # noqa: F401

@@ -6,8 +6,8 @@ backend — pinned by ``tests/test_metrics.py``):
 - :mod:`~horovod_tpu.observability.metrics` — process-local registry of
   counters, gauges, and fixed-bucket histograms with labeled children.
   The instrumented layers (``core.py`` cycle callback, the eager ops in
-  ``ops/collective.py``, the training-step wrappers) feed it; ``bench.py``
-  and user code read it via ``hvd.metrics.snapshot()`` /
+  ``ops/collective.py``, the training-step wrappers) feed it; the
+  benchmark and user code read it via ``hvd.metrics.snapshot()`` /
   ``hvd.metrics.summary()``.
 - :mod:`~horovod_tpu.observability.exporters` — Prometheus text
   exposition + JSON snapshot, and the opt-in rank-0 HTTP endpoint

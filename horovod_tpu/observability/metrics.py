@@ -4,7 +4,7 @@ The reference Horovod exposes no queryable metrics at all — cycle times,
 fusion efficiency, and cache behavior are visible only through the chrome
 Timeline or one-off logging. This registry is the rebuild's first-class
 answer: instrumented layers call ``counter("allreduce_bytes").inc(n)`` and
-anything (tests, ``bench.py``, the ``MetricsCallback``, the Prometheus
+anything (tests, ``benchmarks/``, the ``MetricsCallback``, the Prometheus
 endpoint) reads a consistent snapshot.
 
 Design constraints, in order:

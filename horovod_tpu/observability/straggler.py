@@ -26,8 +26,8 @@ observability side:
 Topology note: in the single-controller SPMD case one process dispatches on
 behalf of every rank, so per-rank arrivals are *simulated* — identical
 timestamps, except a rank charged with ``HOROVOD_CHAOS=rank_slow=<rank>:<s>``
-arrives ``<s>`` late (the process really sleeps, so step time moves too —
-``bench.py --straggler-ab`` measures exactly that). Multi-process ranks each
+arrives ``<s>`` late (the process really sleeps, so step time moves
+too). Multi-process ranks each
 record only their OWN arrival; the rank-0
 :class:`~horovod_tpu.observability.aggregate.FleetAggregator` unions the
 rings by key before attribution.

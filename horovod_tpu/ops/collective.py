@@ -516,9 +516,8 @@ def _counted_lru_cache(builder):
 
 
 def _record_eager_op(op_name: str, tensors, axis=None) -> None:
-    """Count one dispatched eager collective and its payload bytes (the
-    per-op traffic accounting ``bench.py`` previously approximated ad
-    hoc), and assign the op its fleet correlation key — ``(step, elastic
+    """Count one dispatched eager collective and its payload bytes, and
+    assign the op its fleet correlation key — ``(step, elastic
     generation, per-op seq)`` via
     :func:`horovod_tpu.observability.straggler.collective_begin`, which
     also records per-rank arrival timestamps and applies any

@@ -200,7 +200,8 @@ def scope_of(op_name: str, kind: str = ""):
 
 # Peak bf16 matmul throughput per chip, FLOP/s, keyed by substrings of
 # ``jax.Device.device_kind`` (first match wins) — the denominator for MFU
-# reporting (used by ``bench.py`` and the benchmark examples). Sources:
+# reporting (used by ``InstrumentedStep`` and the benchmark examples;
+# ``benchmarks/peaks.json`` is the benchmark's own table). Sources:
 # published TPU specs. Kinds with no entry (TPU7x: whether a device is a
 # chip or half of one is not settled here) raise on a TPU backend.
 _PEAK_BF16_FLOPS = (

@@ -116,7 +116,8 @@ class InstrumentedStep:
                 peak = _profiler.device_peak_flops()
             except ValueError as e:
                 # a gap in the telemetry table must not stop a training
-                # step; the entry points (bench.py, chip_smoke.py) raise
+                # step; the entry points (benchmarks/run.py, chip_smoke.py)
+                # raise on a device kind with no peak
                 import logging
 
                 logging.getLogger("horovod_tpu").warning(
@@ -226,10 +227,11 @@ def instrument_step(fn, *, batch_arg: Optional[int] = None,
                     examples_per_step: Optional[int] = None,
                     flops_per_step: Optional[float] = None,
                     name: str = "train"):
-    """Public spelling of the step wrapper: ``bench.py`` wraps its
-    AOT-compiled executable with the measured per-step FLOPs so
-    ``train_mfu`` lands in the registry; the ``make_*_train_step``
-    builders apply it automatically (``instrument=False`` opts out)."""
+    """Public spelling of the step wrapper: a caller that compiles its own
+    step wraps it here, with the per-step FLOPs if ``train_mfu`` should
+    land in the registry (``examples/transformer_lm_benchmark.py``); the
+    ``make_*_train_step`` builders apply it automatically
+    (``instrument=False`` opts out)."""
     return InstrumentedStep(
         fn, batch_arg=batch_arg, examples_per_step=examples_per_step,
         flops_per_step=flops_per_step, name=name,

@@ -97,11 +97,12 @@ def test_instrumented_step_skips_mfu_loudly_for_unknown_kind(
 # ------------------------------------------------- entry points off the chip
 
 
-def test_bench_default_mode_exits_nonzero_off_chip():
-    proc = _run_off_chip("bench.py", "--iters", "10")
+def test_benchmark_cell_exits_nonzero_off_chip():
+    proc = _run_off_chip(os.path.join("benchmarks", "run.py"),
+                         "--workload", "resnet50_train_1chip")
     assert proc.returncode != 0
     assert "platform=cpu" in proc.stderr
-    assert "{" not in proc.stdout  # no result line, null or otherwise
+    assert "{" not in proc.stdout  # no metrics line, null or otherwise
 
 
 def test_chip_smoke_exits_nonzero_off_chip_before_any_phase():

@@ -105,28 +105,33 @@ def test_core_knobs(hvd_core):
 
 
 def test_core_timeline(monkeypatch, tmp_path):
-    import horovod_tpu as hvd
-
-    tl = tmp_path / "timeline.json"
-    monkeypatch.setenv("HOROVOD_TIMELINE", str(tl))
-    monkeypatch.setenv("HOROVOD_CYCLE_TIME", "2")
-    hvd.shutdown()
-    hvd.init(native_core=True)
-    n = hvd.size()
-    x = stacked(hvd, np.ones((n, 2), dtype=np.float32))
-    for i in range(3):
-        hvd.synchronize(
-            hvd.allreduce_async(x, op=hvd.Sum, name=f"tl.{i}")
-        )
-    hvd.shutdown()
-    content = tl.read_text()
-    assert "NEGOTIATE" in content
-    assert "ALLREDUCE" in content
-    assert "CYCLE_START" in content
+    """Two sessions in one process: the library's globals outlive
+    init/shutdown, and a second session's file once opened with the
+    separator of the first's (invalid JSON) — which session this test got
+    depended on the files its xdist worker had run before."""
     import json
 
-    events = json.loads(content)
-    assert isinstance(events, list) and len(events) > 5
+    import horovod_tpu as hvd
+
+    monkeypatch.setenv("HOROVOD_CYCLE_TIME", "2")
+    hvd.shutdown()
+    for session in range(2):
+        tl = tmp_path / f"timeline{session}.json"
+        monkeypatch.setenv("HOROVOD_TIMELINE", str(tl))
+        hvd.init(native_core=True)
+        n = hvd.size()
+        x = stacked(hvd, np.ones((n, 2), dtype=np.float32))
+        for i in range(3):
+            hvd.synchronize(
+                hvd.allreduce_async(x, op=hvd.Sum, name=f"tl.{i}")
+            )
+        hvd.shutdown()
+        content = tl.read_text()
+        assert "NEGOTIATE" in content
+        assert "ALLREDUCE" in content
+        assert "CYCLE_START" in content
+        events = json.loads(content)
+        assert isinstance(events, list) and len(events) > 5
 
 
 def test_core_prescale_postscale(hvd_core):

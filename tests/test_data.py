@@ -661,22 +661,7 @@ def test_sharded_loader_epoch_snapshot_at_iter(hvd):
         [np.asarray(t[1]).tolist() for t in b]
 
 
-# ------------------------------------------------------ model + hvd_top
-
-
-def test_input_step_time_model():
-    import sys
-
-    sys.path.insert(0, os.path.join(_REPO, "tools"))
-    from scaling_projection import input_step_time
-
-    m = input_step_time(0.004, 0.002, 2)
-    assert m["serial_s"] == pytest.approx(0.006)
-    assert m["overlapped_s"] == pytest.approx(0.004)
-    assert m["speedup"] == pytest.approx(1.5)
-    assert m["bound"] == "compute"
-    assert input_step_time(0.004, 0.002, 0)["speedup"] == 1.0
-    assert input_step_time(0.001, 0.005, 4)["bound"] == "input"
+# ---------------------------------------------------------------- hvd_top
 
 
 def test_hvd_top_input_pane_renders():
@@ -744,35 +729,6 @@ def test_data_env_knobs_documented():
         f"env knobs named in code but absent from the docs/data.md "
         f"knob table: {missing}"
     )
-
-
-@pytest.mark.slow
-def test_bench_input_ab_rung():
-    """bench.py --input-ab emits one JSON line: a measured ratio plus the
-    analytic input_step_time model (the model alone when no device)."""
-    import json as _json
-    import subprocess
-    import sys
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8"
-    ).strip()
-    out = subprocess.run(
-        [sys.executable, os.path.join(_REPO, "bench.py"),
-         "--input-ab", "--iters", "10"],
-        capture_output=True, text=True, timeout=600, env=env,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = [l for l in out.stdout.splitlines() if l.startswith("{")][-1]
-    d = _json.loads(line)
-    assert d["metric"] == "input_ab_step_ratio"
-    assert d["input_model"]["serial_s"] > d["input_model"]["overlapped_s"]
-    if not d.get("skipped"):
-        assert d["value"] > 1.0  # prefetch must win on a 2 ms load cost
-        assert d["serial_step_s"] > d["overlapped_step_s"]
 
 
 def test_data_chaos_charges_parse():

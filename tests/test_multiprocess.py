@@ -958,13 +958,22 @@ def _kv_failover_drill_worker():
     primary_pid = int(os.environ["HVD_TEST_EXT_KV_PID"])
     rank = int(os.environ["HOROVOD_RANK"])
     pol = RetryPolicy(
-        scope="kv", max_attempts=12, base_delay=0.1, max_delay=0.5,
+        scope="kv", max_attempts=120, base_delay=0.1, max_delay=0.5,
         multiplier=2.0, jitter=0.1, deadline=60.0,
     )
     client = KVStoreClient(endpoints=eps, retry_policy=pol)
     for step in range(6):
-        if rank == 0 and step == 3:
-            os.kill(primary_pid, signal.SIGKILL)  # the real kill drill
+        if step == 3:
+            # the two workers start unsynchronised, and one that ran all
+            # six steps before the kill would never meet the failover:
+            # rank 0 kills once rank 1 is mid-run, and rank 1 goes on once
+            # rank 0's step 3 exists, which only the promoted standby can
+            # have taken
+            if rank == 0:
+                client.wait_for("/drill/rank1/step2", timeout=120.0)
+                os.kill(primary_pid, signal.SIGKILL)  # the real kill drill
+            else:
+                client.wait_for("/drill/rank0/step3", timeout=120.0)
         client.put(f"/drill/rank{rank}/step{step}", str(step).encode())
         time.sleep(0.05)
     # re-read the whole publication record through the (now promoted)

@@ -523,21 +523,6 @@ def test_sync_hook_interleaves_collectives_in_backward(hvd):
     assert _leaves_equal(gh, gm)
 
 
-def test_sync_hook_interleaving_survives_compilation(hvd):
-    """The optimized-HLO pin: after XLA's own scheduling, >= 2 all-reduce
-    launches still sit before the last backward matmul — the
-    optimization_barrier token threading makes the order a data
-    dependency no scheduler may undo."""
-    smh, _smm, ws, x = _hooked_and_mono_steps(hvd)
-    txt = jax.jit(smh).lower(ws, x).compile().as_text()
-    events = []
-    for m in re.finditer(r"(all-reduce(?:-start)?|dot)\(", txt):
-        events.append(m.group(1))
-    last_dot = max(i for i, e in enumerate(events) if e == "dot")
-    before = sum(1 for e in events[:last_dot] if e.startswith("all-reduce"))
-    assert before >= 2, events
-
-
 def test_sync_hook_barrier_off_still_correct(hvd):
     mesh, ax = hvd.mesh(), hvd.data_axis()
     rng = np.random.RandomState(0)
@@ -693,72 +678,6 @@ def test_tuning_env_knob_and_unknown_preset():
     with pytest.raises(ValueError, match="unknown"):
         tuning.apply_xla_flags("warp-speed", env={})
     assert tuning.apply_xla_flags("none", env={}) == ([], [])
-
-
-# --------------------------------------------------------------------------
-# analytic model + bench rung
-
-
-def test_overlap_step_time_model():
-    import sys
-
-    sys.path.insert(0, os.path.join(_REPO, "tools"))
-    from scaling_projection import overlap_step_time
-
-    # K=1 degenerates to serial
-    assert overlap_step_time(1.0, 0.5, 1)["overlapped_s"] == 1.5
-    # balanced compute/comm, 8 buckets, no latency: max + min/K
-    m = overlap_step_time(1.0, 1.0, 8)
-    assert m["overlapped_s"] == pytest.approx(1.125)
-    assert m["speedup"] == pytest.approx(2.0 / 1.125)
-    # latency clamps at serial — overlap never loses in the model
-    w = overlap_step_time(1e-6, 1e-5, 64, latency_s=1e-5)
-    assert w["overlapped_s"] <= w["serial_s"]
-    assert overlap_step_time(2.0, 1.0, 4)["bound"] == "compute"
-    assert overlap_step_time(1.0, 2.0, 4)["bound"] == "comm"
-
-
-def test_overlap_ab_byte_model_parity():
-    import bench
-
-    m = bench._overlap_model(8, 256 * 1024, 64)
-    # bucketing moves the same gradient bytes as the monolithic packing
-    assert m["bucketed_bytes"] == m["grad_bytes"]
-    assert m["n_buckets"] >= 2
-    assert m["projection_v4"]["serial_s"] >= m["projection_v4"]["overlapped_s"]
-
-
-@pytest.mark.slow
-def test_bench_overlap_ab_rung():
-    """bench.py --overlap-ab emits ONE JSON line on the CPU mesh with
-    the measured ratio, byte parity across modes, and the analytic
-    model."""
-    import json as _json
-    import subprocess
-    import sys
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8"
-    ).strip()
-    out = subprocess.run(
-        [sys.executable, os.path.join(_REPO, "bench.py"),
-         "--overlap-ab", "--iters", "6"],
-        capture_output=True, text=True, timeout=600, env=env,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = [l for l in out.stdout.splitlines() if l.startswith("{")][-1]
-    d = _json.loads(line)
-    assert d["metric"] == "overlap_ab_step_ratio"
-    if not d.get("skipped"):
-        assert d["value"] > 0
-        assert d["grad_sync_buckets"]["bucketed"] >= 2
-        assert d["grad_sync_bytes_per_step"]["bucketed"] == pytest.approx(
-            d["grad_sync_bytes_per_step"]["monolithic"], rel=0.01)
-    assert d["overlap_model"]["bucketed_bytes"] == \
-        d["overlap_model"]["grad_bytes"]
 
 
 # --------------------------------------------------------------------------

@@ -678,40 +678,3 @@ def test_pallas_hot_path_byte_model():
     assert m_ar["discrete_bytes"] > m["discrete_bytes"]
     with pytest.raises(ValueError, match="epilogue"):
         pallas_hot_path_bytes([(8,)], 8, epilogue="bogus")
-
-
-@pytest.mark.slow
-def test_bench_pallas_ab_rung():
-    """bench.py --pallas-ab on the 8-device CPU mesh: ONE JSON line with
-    the measured (interpret-mode) ratio, both arms' billed wire bytes
-    matching each other and the ring model (the gauges price the wire at
-    trace time — compiled-wire invariance itself is pinned by the
-    fingerprint tests above), and the analytic HBM model."""
-    import subprocess
-    import sys
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8"
-    ).strip()
-    env.pop("HOROVOD_PALLAS", None)
-    out = subprocess.run(
-        [sys.executable, os.path.join(_REPO, "bench.py"),
-         "--pallas-ab", "--iters", "3"],
-        capture_output=True, text=True, timeout=600, env=env,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = [l for l in out.stdout.splitlines() if l.startswith("{")][-1]
-    d = json.loads(line)
-    assert d["metric"] == "pallas_ab_step_ratio"
-    if not d.get("skipped"):
-        assert d["value"] > 0
-        b = d["grad_sync_bytes_per_step"]
-        # measured byte parity across arms AND vs the ring model
-        assert b["fused"] == b["discrete"]
-        assert b["fused"] == pytest.approx(b["ring_model"])
-        assert d["interpret"] is True
-    assert d["pallas_model"]["fused_bytes"] < \
-        d["pallas_model"]["discrete_bytes"]

@@ -21,9 +21,6 @@ The acceptance pins:
 Tier-1: deterministic, no sleeps; ``serving`` marker.
 """
 
-import json
-import os
-
 import numpy as np
 import pytest
 
@@ -46,8 +43,6 @@ from horovod_tpu.serving.scheduler import (  # noqa: E402
     Request,
     prefix_digests,
 )
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 pytestmark = pytest.mark.serving
 
@@ -704,53 +699,6 @@ def test_e2e_canary_promote_with_caching_and_speculation(hvd, monkeypatch):
         assert fp_after == fp_before
     finally:
         server.close()
-
-
-@pytest.mark.slow
-def test_bench_prefix_ab_rung():
-    """bench.py --prefix-ab emits ONE JSON line whose measured prefill
-    token deltas match the analytic model EXACTLY."""
-    import subprocess
-    import sys
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    out = subprocess.run(
-        [sys.executable, os.path.join(_REPO, "bench.py"), "--prefix-ab"],
-        capture_output=True, text=True, env=env, timeout=600, cwd=_REPO)
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = [l for l in out.stdout.splitlines() if l.startswith("{")][-1]
-    d = json.loads(line)
-    assert d["metric"] == "prefix_ab_prefill_ratio"
-    assert d["parity"] == "token-identical"
-    m = d["prefill_model"]
-    assert d["measured_prefill_tokens"]["cold"] == m["cold_prefill_tokens"]
-    assert d["measured_prefill_tokens"]["cached"] \
-        == m["cached_prefill_tokens"]
-    assert m["saved_tokens"] > 0
-
-
-@pytest.mark.slow
-def test_bench_spec_ab_rung():
-    """bench.py --spec-ab emits ONE JSON line whose proposal/acceptance
-    counters match the analytic model EXACTLY (full-depth draft)."""
-    import subprocess
-    import sys
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    out = subprocess.run(
-        [sys.executable, os.path.join(_REPO, "bench.py"), "--spec-ab"],
-        capture_output=True, text=True, env=env, timeout=600, cwd=_REPO)
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = [l for l in out.stdout.splitlines() if l.startswith("{")][-1]
-    d = json.loads(line)
-    assert d["metric"] == "spec_ab_goodput_ratio"
-    assert d["parity"] == "token-identical"
-    m = d["spec_model"]
-    assert d["measured"]["proposed"] == m["proposed"]
-    assert d["measured"]["accepted"] == m["accepted"]
-    assert m["accepted"] == m["proposed"]  # full-depth draft
 
 
 def test_hvd_top_serving_pane_shows_hit_and_acceptance_rates():

@@ -965,35 +965,3 @@ def test_twenty_generation_soak_with_wal_restarts():
                 jax.tree_util.tree_leaves(pub.reconstruction())):
             np.testing.assert_array_equal(got, w)
         s.close()
-
-
-@pytest.mark.serving
-@pytest.mark.slow
-def test_bench_publish_ab_rung():
-    """bench.py --publish-ab emits one JSON line whose measured wire-byte
-    gauges equal the analytic byte model exactly."""
-    import subprocess
-    import sys
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
-    ).strip()
-    out = subprocess.run(
-        [sys.executable, os.path.join(_REPO, "bench.py"),
-         "--publish-ab", "--iters", "5"],
-        capture_output=True, text=True, timeout=600, env=env,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = [l for l in out.stdout.splitlines() if l.startswith("{")][-1]
-    d = json.loads(line)
-    assert d["metric"] == "publish_ab_step_ratio"
-    if d.get("skipped"):
-        assert d["byte_model"]["delta_ratio_vs_checkpoint"] < 0.3
-    else:
-        assert d["publish_wire_bytes"]["key"] == \
-            d["byte_model"]["keyframe_bytes"]
-        assert d["publish_wire_bytes"]["delta"] == \
-            d["byte_model"]["delta_bytes"]
-        assert d["generations"] == d["subscriber_generation"]

@@ -17,9 +17,6 @@ every tier-1 run by ``test_schedule.py``).
 Tier-1: deterministic, no sleeps > 0.2s; ``serving`` marker.
 """
 
-import json
-import os
-
 import numpy as np
 import pytest
 
@@ -40,8 +37,6 @@ from horovod_tpu.serving import (  # noqa: E402
     protocol,
 )
 from horovod_tpu.serving.engine import note_subscriber_health  # noqa: E402
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 pytestmark = pytest.mark.serving
 
@@ -748,44 +743,3 @@ def test_e2e_train_publish_serve_canary_rollback(hvd, monkeypatch):
         assert fp_after == fp_before
     finally:
         server.close()
-
-
-# ------------------------------------------------------------ bench + model
-
-
-def test_serving_goodput_model_properties():
-    from tools.scaling_projection import serving_goodput
-
-    # uniform, batch-aligned workload: no padding waste → ratio 1.0
-    out = serving_goodput([16, 16, 16, 16], 8, max_batch=4,
-                          prefill_chunk=16)
-    assert out["goodput_ratio"] == pytest.approx(1.0)
-    # ragged prompts: static pays the padding, continuous does not
-    ragged = serving_goodput([4, 16, 7, 12], 8, max_batch=4,
-                             prefill_chunk=4)
-    assert ragged["goodput_ratio"] > 1.0
-    assert ragged["continuous_slot_tokens"] < ragged["static_slot_tokens"]
-    # chunk rounding is charged to the continuous arm honestly
-    chunky = serving_goodput([1], 1, max_batch=1, prefill_chunk=16)
-    assert chunky["continuous_slot_tokens"] == 17
-
-
-@pytest.mark.slow
-def test_bench_serving_ab_rung():
-    """bench.py --serving-ab emits ONE JSON line with a measured ratio,
-    token-identical parity, and the analytic slot-token model."""
-    import subprocess
-    import sys
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    out = subprocess.run(
-        [sys.executable, os.path.join(_REPO, "bench.py"), "--serving-ab"],
-        capture_output=True, text=True, env=env, timeout=600, cwd=_REPO)
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = [l for l in out.stdout.splitlines() if l.startswith("{")][-1]
-    d = json.loads(line)
-    assert d["metric"] == "serving_ab_goodput_ratio"
-    assert d["parity"] == "token-identical"
-    assert d["goodput_model"]["goodput_ratio"] > 1.0
-    assert d["value"] is None or d["value"] > 0

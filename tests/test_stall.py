@@ -110,23 +110,38 @@ DEATH_WORKER = textwrap.dedent(
                       coordinator_port=port)
     x = np.ones((4,), np.float32)
     h = core.enqueue("warm", x, REQUEST_ALLREDUCE, op=1)
-    h.wait(timeout=20)
+    h.wait(timeout=120)
+    pending = sys.argv[3]  # rank 0 makes it once 'orphan' is enqueued
     if rank == 1:
+        # die only when the survivor's collective IS pending: a peer that
+        # died first was noticed within a cycle, the loop was gone before
+        # 'orphan' arrived, and nothing was left to abort it
+        deadline = time.monotonic() + 120
+        while not os.path.exists(pending) and time.monotonic() < deadline:
+            time.sleep(0.01)
         os._exit(7)  # die abruptly mid-job: no shutdown, no socket close
+
+    def aborted(name, handle):
+        try:
+            # a client-side TimeoutError must FAIL the test: only the
+            # core's own abort (RuntimeError from the shutdown error
+            # response) counts
+            handle.wait(timeout=120)
+            print(f"RANK0-UNEXPECTED-COMPLETION {name}", flush=True)
+        except TimeoutError as e:
+            # still a test failure (no ABORTED line) but diagnosable
+            print(f"RANK0-CLIENT-TIMEOUT {name}: {e}", flush=True)
+        except RuntimeError as e:
+            print(f"RANK0-ABORTED {name}: {type(e).__name__}: {e}",
+                  flush=True)
+
     hm = core.enqueue("orphan", x, REQUEST_ALLREDUCE, op=1)
-    try:
-        # timeout far above the 3s stall-shutdown setting but a client-side
-        # TimeoutError must FAIL the test: only the core's own abort
-        # (RuntimeError from the shutdown error response) counts. 45s of
-        # headroom: under full-suite machine load the abort has been
-        # observed to take >20s to propagate, which is slow, not broken.
-        hm.wait(timeout=45)
-        print("RANK0-UNEXPECTED-COMPLETION", flush=True)
-    except TimeoutError as e:
-        # still a test failure (no RANK0-ABORTED line) but diagnosable
-        print(f"RANK0-CLIENT-TIMEOUT: {e}", flush=True)
-    except RuntimeError as e:
-        print(f"RANK0-ABORTED: {type(e).__name__}: {e}", flush=True)
+    open(pending, "w").close()
+    aborted("orphan", hm)
+    # and the other order: the loop is gone now, so what is enqueued next
+    # must be aborted by the enqueue itself, not wait for a drain that
+    # never comes
+    aborted("late", core.enqueue("late", x, REQUEST_ALLREDUCE, op=1))
     core.shutdown()
     print("rank0: exited cleanly", flush=True)
     """
@@ -135,8 +150,9 @@ DEATH_WORKER = textwrap.dedent(
 
 def test_worker_death_aborts_survivor(tmp_path):
     """Abrupt peer death mid-job (reference failure semantics, SURVEY §5.3):
-    the survivor's pending collective must ABORT via the stall-shutdown
-    path — never hang until an external timeout kills the job."""
+    the survivor's pending collective must ABORT — never hang until an
+    external timeout kills the job — and so must one it enqueues after
+    the core's loop has shut itself down."""
     script = tmp_path / "death_worker.py"
     script.write_text(DEATH_WORKER)
     port = _free_port()
@@ -148,7 +164,8 @@ def test_worker_death_aborts_survivor(tmp_path):
     )
     procs = [
         subprocess.Popen(
-            [sys.executable, "-u", str(script), str(r), str(port)],
+            [sys.executable, "-u", str(script), str(r), str(port),
+             str(tmp_path / "orphan_pending")],
             stdout=subprocess.PIPE,
             stderr=subprocess.STDOUT,
             text=True,
@@ -159,13 +176,14 @@ def test_worker_death_aborts_survivor(tmp_path):
     outs = []
     for p in procs:
         try:
-            out, _ = p.communicate(timeout=150)
+            out, _ = p.communicate(timeout=400)
         except subprocess.TimeoutExpired:
             p.kill()
             out, _ = p.communicate()
         outs.append(out)
     assert procs[1].returncode == 7  # the deliberate death
-    assert "RANK0-ABORTED" in outs[0], outs[0]
+    assert "RANK0-ABORTED orphan" in outs[0], outs[0]
+    assert "RANK0-ABORTED late" in outs[0], outs[0]
     assert "rank0: exited cleanly" in outs[0], outs[0]
     assert procs[0].returncode == 0, outs[0]
 

@@ -10,6 +10,7 @@ the next refusal shows here. Mosaic itself only runs on the chip —
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -288,19 +289,26 @@ def test_flash_attention_rejects_blocks_below_the_tile():
 
 
 @pytest.fixture(scope="module")
-def one_v5e_chip():
-    """A v5e chip that is described, not attached: the TPU compiler runs
-    Mosaic for it here, so a tile that overflows the scoped VMEM or a
-    slice off the tiling fails in tier-1, not on the chip."""
+def v5e_2x2():
+    """The four-chip v5e host, described and not attached: the TPU's own
+    compiler runs for it here."""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip(v5e_2x2):
+    """One chip of it: Mosaic runs for that chip, so a tile that overflows
+    the scoped VMEM or a slice off the tiling fails in tier-1, not on the
+    chip."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 @pytest.mark.parametrize("batch,seq,heads,kv_heads,head_dim,dtype", [
@@ -342,3 +350,64 @@ def test_flash_backward_compiles_for_v5e(one_v5e_chip, batch, seq, heads,
     assert sum("/hvd.flash_bwd/pallas_call" in l for l in calls) == len(
         kernels)
     assert " while(" not in text
+
+
+# ------------------------------- the staged backward, after the TPU's compiler
+
+
+def _entry_schedule(text):
+    """The ENTRY computation's all-reduces (``all-reduce[-start|-done]``)
+    and matmuls (``matmul``: a dot or convolution, alone or inside the
+    fusion that holds it) in scheduled order."""
+    bodies = dict(re.findall(r"^%(\S+) \(.*?\{\n(.*?)^\}", text,
+                             re.M | re.S))
+    holds_matmul = {name for name, body in bodies.items()
+                    if re.search(r" (dot|convolution)\(", body)}
+    kinds = []
+    for line in text[text.index("\nENTRY "):].splitlines()[1:]:
+        if (m := re.search(r" (all-reduce(?:-start|-done)?)\(", line)):
+            kinds.append(m.group(1))
+        elif re.search(r" (dot|convolution)\(", line) or any(
+                c in holds_matmul
+                for c in re.findall(r"calls=%([\w.\-]+)", line)):
+            kinds.append("matmul")
+    return kinds
+
+
+def test_sync_hook_order_does_not_survive_the_tpu_compiler(hvd, v5e_2x2):
+    """``ops.overlap.sync_hook`` threads an ``optimization_barrier`` token so
+    each block's gradient all-reduce is issued inside the backward pass;
+    the jaxpr order and the equal gradients are pinned in
+    ``test_overlap.py``. This pins what the described 2x2's compiler makes
+    of it: the three all-reduces combined into ONE synchronous tuple
+    ``all-reduce`` (no ``-start`` / ``-done`` pair) scheduled after the
+    last matmul of the backward, as in the monolithic step, and no
+    ``opt-barrier`` left. The same at
+    widths 1024 and 2048 (48 MB of gradients; PERF.md section 6). When a
+    change of flags or of the hook makes this fail, the compiler has
+    started to keep the interleaving: that is ROADMAP S8's news, pin it."""
+    import types
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from test_overlap import _hooked_and_mono_steps
+
+    axis = hvd.data_axis()
+    mesh = Mesh(np.array(v5e_2x2.devices), (axis,))
+    described = types.SimpleNamespace(mesh=lambda: mesh,
+                                      data_axis=lambda: axis)
+    hooked, mono, ws, x = _hooked_and_mono_steps(described)
+    ws = [S(w.shape, w.dtype, sharding=NamedSharding(mesh, P()))
+          for w in ws]
+    x = S(x.shape, x.dtype, sharding=NamedSharding(mesh, P(axis)))
+    for step in (hooked, mono):
+        text = jax.jit(step).lower(ws, x).compile().as_text()
+        kinds = _entry_schedule(text)
+        assert kinds.count("matmul") >= 2 * len(ws), kinds
+        assert [k for k in kinds if k.startswith("all-reduce")] == [
+            "all-reduce"], kinds
+        assert "matmul" not in kinds[kinds.index("all-reduce"):], kinds
+        assert "opt-barrier" not in text
+        reduced = re.search(r"= \((.*?)\) all-reduce\(", text).group(1)
+        assert reduced.count("f32[") == len(ws), reduced
