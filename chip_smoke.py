@@ -495,6 +495,10 @@ def phase_train(sz, hvd, model, log):
     if peak:
         print(f"  peak_bytes_in_use={peak['peak_bytes_in_use']:,} "
               f"(per-chip batch {sz.batch})", flush=True)
+    # what the last traced loss (the evaluation's) keeps for a backward:
+    # the logits in the model's dtype and a float32 log-sum-exp a token
+    print(f"  token_xent_residual_mb="
+          f"{hvd.metrics.value('token_xent_residual_mb')}", flush=True)
     return jax.device_get(params)
 
 

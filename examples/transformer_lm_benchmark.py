@@ -37,7 +37,8 @@ import optax
 
 import horovod_tpu as hvd
 from horovod_tpu.models import TransformerLM
-from horovod_tpu.training import make_jit_train_step, replicate, shard_batch
+from horovod_tpu.training import (
+    make_jit_train_step, replicate, shard_batch, token_xent)
 
 
 def main():
@@ -104,12 +105,7 @@ def main():
     opt_state = replicate(tx.init(params))
     params = replicate(params)
 
-    def lm_xent(logits, tgts):
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        ll = jnp.take_along_axis(logp, tgts[..., None], axis=-1)
-        return -jnp.mean(ll)
-
-    step = make_jit_train_step(model, tx, loss_fn=lm_xent)
+    step = make_jit_train_step(model, tx, loss_fn=token_xent)
     batch_stats = {}  # TransformerLM is stateless
 
     step = step.lower(

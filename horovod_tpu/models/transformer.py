@@ -201,7 +201,11 @@ class TransformerBlock(nn.Module):
 class TransformerLM(nn.Module):
     """Causal LM. Input: int tokens [B, T] (a *local* sequence shard when run
     under sequence parallelism — pass ``positions`` with the global offsets so
-    position embeddings line up). Output: logits [B, T, vocab]."""
+    position embeddings line up). Output: logits [B, T, vocab] — in
+    ``dtype``, as the head computed them, from a training-shape call: the
+    loss does its arithmetic in float32 (``training.token_xent`` upcasts
+    inside its own fusions); float32 from a kv-cache call (``decode=True``:
+    ``generate()``, the serving engine)."""
 
     vocab: int = 32000
     dim: int = 512
@@ -269,7 +273,11 @@ class TransformerLM(nn.Module):
         x = nn.LayerNorm(dtype=self.dtype, name="ln_f")(x)
         logits = nn.Dense(self.vocab, use_bias=False, dtype=self.dtype,
                           name="lm_head")(x)
-        return logits.astype(jnp.float32)
+        # a kv-cache step's few rows go to a sampler or to numpy: float32.
+        # A training call's [B, T, vocab] stay as the head computed them,
+        # for the loss to upcast inside its own fusions (token_xent): an
+        # upcast here is a second, float32 copy of them in HBM
+        return logits.astype(jnp.float32) if self.decode else logits
 
 
 def TransformerTiny(**kw):

@@ -67,11 +67,51 @@ def softmax_xent(logits, labels):
     return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=1))
 
 
+@jax.custom_vjp
 def token_xent(logits, targets):
-    """Per-token cross entropy for causal LMs (logits ``[..., T, V]``,
-    int targets ``[..., T]``), log-softmax in fp32."""
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-    return -jnp.mean(jnp.take_along_axis(logp, targets[..., None], axis=-1))
+    """Per-token cross entropy for causal LMs: logits ``[..., T, V]`` of any
+    float dtype (``TransformerLM``'s training call hands over the head's
+    own, in the model's compute dtype), int targets ``[..., T]``.
+
+    The arithmetic is float32 whatever comes in. For the backward it keeps
+    the logits as they arrived and one float32 log-sum-exp a token —
+    nothing else vocabulary-wide, where autodiff through ``log_softmax``
+    keeps a float32 copy of all the log-probabilities (at GPT-2's
+    ``[8, 1024, 50257]``: 0.82 GB for 2.47). The gradient comes back in
+    the logits' dtype.
+    """
+    return _token_xent_fwd(logits, targets)[0]
+
+
+def _token_xent_fwd(logits, targets):
+    x = logits.astype(jnp.float32)
+    top = jnp.max(x, axis=-1)
+    log_sum = jnp.log(jnp.sum(jnp.exp(x - top[..., None]), axis=-1))
+    picked = jnp.take_along_axis(
+        logits, targets[..., None], axis=-1)[..., 0].astype(jnp.float32)
+    lse = top + log_sum
+    if _metrics.enabled():
+        _metrics.gauge(
+            "token_xent_residual_mb",
+            help="MB token_xent keeps for its backward (the logits as they "
+                 "arrived + a float32 log-sum-exp a token), set when the "
+                 "loss is traced",
+        ).set((logits.size * logits.dtype.itemsize + lse.size * 4) / 1e6)
+    # (picked - top) first: both are logits, so the loss does not round at
+    # the logits' magnitude
+    return jnp.mean(log_sum - (picked - top)), (logits, lse, targets)
+
+
+def _token_xent_bwd(residuals, g):
+    logits, lse, targets = residuals
+    x = logits.astype(jnp.float32)
+    onehot = jax.lax.broadcasted_iota(
+        targets.dtype, x.shape, x.ndim - 1) == targets[..., None]
+    dlogits = (jnp.exp(x - lse[..., None]) - onehot) * (g / lse.size)
+    return dlogits.astype(logits.dtype), None
+
+
+token_xent.defvjp(_token_xent_fwd, _token_xent_bwd)
 
 
 def init_model(model, rng, sample_input, train: bool = True):
@@ -1028,8 +1068,7 @@ def make_transformer_pp_train_step(
 
     def head_fn(hp, x):
         x = ln_f.apply({"params": hp["ln_f"]}, x)
-        logits = lm_head.apply({"params": hp["lm_head"]}, x)
-        return logits.astype(jnp.float32)
+        return lm_head.apply({"params": hp["lm_head"]}, x)
 
     def pp_step(params, opt_state, toks_m, tgts_m):
         local = jax.tree_util.tree_map(lambda p: p[0], params["stages"])

@@ -352,6 +352,53 @@ def test_flash_backward_compiles_for_v5e(one_v5e_chip, batch, seq, heads,
     assert " while(" not in text
 
 
+# ------------------------------------- what the token loss keeps, on the chip
+
+
+@pytest.mark.parametrize("dtype,wide_f32,temp_gb", [
+    ("bfloat16", 0, 1.0),   # the GPT-2 cells' head: 0.824 GB (2.472 before)
+    ("float32", 1, 1.7),    # an f32 model: the logits themselves, 1.648 GB
+])
+def test_token_xent_keeps_no_float32_copy_of_the_logits(one_v5e_chip, dtype,
+                                                        wide_f32, temp_gb):
+    """Final LayerNorm + the ``[1024, 50257]`` head + ``token_xent`` under
+    ``value_and_grad`` at the GPT-2 cells' ``[8, 1024]`` batch, compiled for
+    the chip: beside the logits in the head's own dtype and dW, nothing as
+    wide as the vocabulary is written to HBM. Through ``log_softmax`` the
+    compiled step wrote an ``f32[8,1024,50257]`` of log-probabilities
+    (1.65 GB, 3.8 ms a step; PERF.md section 6, PR 34): an upcast in the
+    model's tail or a loss that autodiff differentiates brings it back."""
+    import flax.linen as nn
+
+    from horovod_tpu.training import token_xent
+
+    norm = nn.LayerNorm(dtype=dtype)
+    head = nn.Dense(50257, use_bias=False, dtype=dtype)
+
+    def loss(x, norm_params, kernel, targets):
+        h = norm.apply({"params": norm_params}, x)
+        return token_xent(
+            head.apply({"params": {"kernel": kernel}}, h), targets)
+
+    def shape(dims, dt):
+        return S(dims, dt, sharding=one_v5e_chip)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        shape((8, 1024, 1024), dtype),
+        {"scale": shape((1024,), "float32"),
+         "bias": shape((1024,), "float32")},
+        shape((1024, 50257), "float32"), shape((8, 1024), "int32")).compile()
+    text = compiled.as_text()
+    # each ENTRY instruction's result type(s), tuple reads left out
+    results = [m.group(1) for m in re.finditer(
+        r"^\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) (?!get-tuple-element)[\w\-]+\(",
+        text[text.index("\nENTRY "):], re.M)]
+    assert sum("f32[8,1024,50257]" in r for r in results) == wide_f32, [
+        r for r in results if "50257]" in r]
+    assert sum("[8,1024,50257]" in r for r in results) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
+
+
 # ------------------------------- the staged backward, after the TPU's compiler
 
 

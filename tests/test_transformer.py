@@ -541,3 +541,136 @@ def test_transformer_pp_interleaved_matches_dense():
     """Interleaved (circular) schedule: S=2 devices x v=2 wrap levels over
     4 blocks — same dense-oracle equality as the GPipe path."""
     _pp_dense_parity(2, 2, vocab=128, depth=4, seed=13)
+
+
+# ------------------------------------------ token_xent and the logits' dtype
+
+
+def _log_softmax_xent(logits, targets):
+    """The loss as it was before token_xent kept its own residuals:
+    autodiff through a float32 log_softmax."""
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+    return -jnp.mean(jnp.take_along_axis(logp, targets[..., None], axis=-1))
+
+
+def _xent_case(shape, dtype):
+    """Logits of ``shape`` with one row whose largest logit is 3e4, and
+    targets that name that logit once."""
+    rng = np.random.RandomState(sum(shape))
+    x = (3 * rng.randn(*shape)).astype(np.float32)
+    x[..., 0, 5] = 3e4
+    t = rng.randint(0, shape[-1], shape[:-1]).astype(np.int32)
+    t[..., -1] = 5
+    return jnp.asarray(x, dtype), jnp.asarray(t)
+
+
+def _check_xent_value_and_grad(shape, dtype):
+    from horovod_tpu.training import token_xent
+
+    x, t = _xent_case(shape, dtype)
+    loss, grad = jax.jit(jax.value_and_grad(token_xent))(x, t)
+    want, want_grad = jax.jit(jax.value_and_grad(_log_softmax_xent))(x, t)
+    assert np.isfinite(float(loss))
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-6)
+    assert grad.dtype == x.dtype and grad.shape == x.shape
+    # both round one float32 dlogits to the logits' dtype: an ulp of it
+    np.testing.assert_allclose(
+        np.asarray(grad, np.float32), np.asarray(want_grad, np.float32),
+        rtol=2 ** -7 if dtype == jnp.bfloat16 else 1e-5, atol=1e-8)
+    assert hvd.metrics.value("token_xent_residual_mb") == pytest.approx(
+        (x.size * x.dtype.itemsize + t.size * 4) / 1e6)
+    # what the backward is handed: the logits as they came, a float32
+    # log-sum-exp a token, the targets. Nothing else as wide as the
+    # vocabulary
+    saved = jax.tree_util.tree_leaves(jax.vjp(token_xent, x, t)[1])
+    assert sorted((a.shape, str(a.dtype)) for a in saved) == sorted([
+        (x.shape, str(x.dtype)), (t.shape, "float32"), (t.shape, "int32")])
+
+
+def _check_xent_under_builder(builder, dtype):
+    """One step through a step builder: the loss and every updated leaf
+    equal the log_softmax form's. (In bfloat16 the two round the same
+    float32 dlogits, an ulp of float32 apart, to bfloat16: a few land on
+    the other side, and the bfloat16 backward carries that on.)"""
+    from horovod_tpu import training
+
+    model = TransformerTiny(vocab=1031, depth=1, max_len=16, dtype=dtype)
+    rng = np.random.RandomState(7)
+    tokens = rng.randint(0, 1031, (hvd.size(), 16)).astype(np.int32)
+    targets = np.roll(tokens, -1, axis=1)
+    params = model.init(jax.random.PRNGKey(0), tokens[:1])["params"]
+    tx = (hvd.DistributedOptimizer(optax.sgd(0.1))
+          if builder == "make_jit_train_step" else optax.sgd(0.1))
+    out = {}
+    for loss_fn in (training.token_xent, _log_softmax_xent):
+        step = getattr(training, builder)(
+            model, tx, loss_fn=loss_fn, instrument=False, donate=False)
+        new, _, _, loss = step(
+            replicate(params), {}, replicate(tx.init(params)),
+            training.shard_batch(tokens), training.shard_batch(targets))
+        out[loss_fn] = (jax.device_get(new), float(loss))
+    (got, loss), (want, want_loss) = out.values()
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
+                            jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(
+            a, b, err_msg=jax.tree_util.keystr(path),
+            **(dict(rtol=1e-5, atol=1e-7) if dtype == jnp.float32
+               else dict(rtol=2 ** -5, atol=4e-6)))
+
+
+def _check_training_call_returns_compute_dtype():
+    tokens = jnp.zeros((2, 8), jnp.int32)
+    for dtype in (jnp.bfloat16, jnp.float32):
+        model = TransformerTiny(depth=1, dtype=dtype)
+        params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+        for kw in ({}, {"train": False}):
+            assert model.apply({"params": params}, tokens, **kw).dtype == dtype
+
+
+def _check_decode_logits_are_the_heads_upcast():
+    """A kv-cache call (generate(), the serving engine's applies) returns
+    float32, bit for bit the upcast of what the bfloat16 head computed:
+    the samplers and the engine's numpy side see the values they saw when
+    every call upcast."""
+    import dataclasses
+
+    model = TransformerTiny(depth=1, max_len=16)
+    rng = np.random.RandomState(5)
+    tokens = jnp.asarray(rng.randint(0, 1024, (2, 8)).astype(np.int32))
+    params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+    dec = dataclasses.replace(model, decode=True, cache_len=16)
+    pos = jnp.broadcast_to(jnp.arange(8, dtype=jnp.int32), (2, 8))
+    cache = dec.init(jax.random.PRNGKey(0), tokens, positions=pos)["cache"]
+    logits, state = dec.apply(
+        {"params": params, "cache": cache}, tokens, positions=pos,
+        mutable=["cache", "intermediates"],
+        capture_intermediates=lambda m, _: m.name == "lm_head")
+    (head,) = state["intermediates"]["lm_head"]["__call__"]
+    assert head.dtype == jnp.bfloat16 and logits.dtype == jnp.float32
+    np.testing.assert_array_equal(
+        np.asarray(logits), np.asarray(head.astype(jnp.float32)))
+
+
+_XENT_CASES = {
+    **{f"value_and_grad-{'x'.join(map(str, shape))}-{dtype}": functools.partial(
+        _check_xent_value_and_grad, shape, getattr(jnp, dtype))
+       for shape in ((7, 1031), (2, 5, 1031), (3, 4, 256))
+       for dtype in ("bfloat16", "float32")},
+    **{f"{builder}-{dtype}": functools.partial(
+        _check_xent_under_builder, builder, getattr(jnp, dtype))
+       for builder in ("make_jit_train_step", "make_shardmap_train_step")
+       for dtype in ("bfloat16", "float32")},
+    "training_call_dtype": _check_training_call_returns_compute_dtype,
+    "decode_logits": _check_decode_logits_are_the_heads_upcast,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_XENT_CASES))
+def test_token_xent_and_the_logits_dtype(hvd, case):
+    """``token_xent`` equals the float32 log_softmax form in value and
+    gradient, for bfloat16 and float32 logits, ``[T, V]`` and ``[B, T, V]``,
+    a vocabulary off the 128-lane tile and a row at 3e4, alone under jit and
+    inside both step builders; the training call hands it the head's own
+    dtype, a kv-cache call still returns float32."""
+    _XENT_CASES[case]()
