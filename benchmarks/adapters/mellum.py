@@ -1,0 +1,123 @@
+"""``mellum``-family configurations through the program's public API:
+``models.TransformerLM`` built from a per-layer description (sliding and
+full attention layers of ``head_dim``-wide heads, rotary with YaRN on the
+full ones, routed SwiGLU experts, RMSNorm), bfloat16 compute over float32
+parameters, ``flash_attention`` (with its ``window``), ``training.token_xent``,
+``optax.adamw``. Only names and shapes are translated here: the weights are
+the benchmark's (``reference/mellum.make_weights``), handed over as they
+are, and the share of the deployment (which heads, experts and vocabulary
+rows are held) is the configuration's. Where the configuration asks for
+``router_selection`` ``forced_uniform`` the routed layers are handed the
+benchmark's scores to choose by (``reference/mellum.forced_scores``), as
+they are handed its weights.
+"""
+
+import functools
+
+_BLOCK = {"g1": ("ln1", "scale"), "wq": ("q_proj", "kernel"),
+          "wk": ("k_proj", "kernel"), "wv": ("v_proj", "kernel"),
+          "wo": ("proj", "kernel"), "g2": ("ln2", "scale"),
+          "wr": ("router",), "wg": ("experts_gate",), "wu": ("experts_up",),
+          "wd": ("experts_down",)}
+_TOP = {"embed": ("tok_embed", "embedding"), "gf": ("ln_f", "scale"),
+        "w_head": ("lm_head", "kernel")}
+
+
+def _path(name):
+    if "." in name:
+        layer, leaf = name.split(".")
+        return ("block" + layer[1:],) + _BLOCK[leaf]
+    return _TOP[name]
+
+
+def to_tree(weights):
+    """The benchmark's flat ``name -> array`` as the model's param tree."""
+    tree = {}
+    for name, value in weights.items():
+        node, path = tree, _path(name)
+        for key in path[:-1]:
+            node = node.setdefault(key, {})
+        node[path[-1]] = value
+    return tree
+
+
+def ref_names(tree, names):
+    """A tree shaped like the params, back under the benchmark's names."""
+    out = {}
+    for name in names:
+        node = tree
+        for key in _path(name):
+            node = node[key]
+        out[name] = node
+    return out
+
+
+def layers(cfg):
+    """The configuration's ``layer_types`` as the model's per-layer
+    description."""
+    from horovod_tpu import models
+
+    experts = functools.partial(
+        models.Experts, routed=cfg["num_experts_routed"],
+        top_k=cfg["num_experts_per_tok"], width=cfg["moe_intermediate_size"],
+        first=cfg["first_expert"], count=cfg["num_experts"])
+    selection = cfg.get("router_selection", "top_k")
+    if selection not in ("top_k", "forced_uniform"):
+        raise ValueError(f"router_selection {selection!r}")
+    out = []
+    for i, kind in enumerate(cfg["layer_types"]):
+        rope = cfg["rope_parameters"][kind]
+        yarn = None
+        if rope["rope_type"] == "yarn":
+            yarn = models.Yarn(
+                factor=rope["factor"],
+                original_max_len=rope["original_max_position_embeddings"],
+                beta_fast=rope["beta_fast"], beta_slow=rope["beta_slow"],
+                attention_factor=rope["attention_factor"])
+        out.append(models.Layer(
+            heads=cfg["num_attention_heads"], head_dim=cfg["head_dim"],
+            kv_heads=cfg["num_key_value_heads"],
+            rope_base=rope["rope_theta"], yarn=yarn,
+            window=(cfg["sliding_window"] if kind == "sliding_attention"
+                    else None),
+            ffn=experts(select=_forced(i) if selection == "forced_uniform"
+                        else None)))
+    return tuple(out)
+
+
+def _forced(layer):
+    """Layer ``layer``'s scores to choose experts by, for all the tokens of
+    a step (the model flattens its batch)."""
+    from benchmarks.reference.mellum import forced_scores
+
+    return lambda probs: forced_scores(layer, 0, *probs.shape)
+
+
+def build(cfg, workload):
+    import jax.numpy as jnp
+    import optax
+
+    from horovod_tpu import models
+    from horovod_tpu.ops.flash_attention import flash_attention
+    from horovod_tpu.training import token_xent
+
+    if len(cfg["layer_types"]) != cfg["num_layers"] or set(
+            cfg["mlp_layer_types"]) != {"sparse"}:
+        raise ValueError("a mellum configuration gives one layer_types entry "
+                         "a layer, every one with a sparse MLP")
+    model = models.TransformerLM(
+        vocab=cfg["vocab_size"], dim=cfg["hidden_size"],
+        depth=cfg["num_layers"], heads=cfg["num_attention_heads"],
+        layers=layers(cfg), norm="rmsnorm", norm_eps=cfg["rms_norm_eps"],
+        pos_embedding="rope", max_len=cfg["max_position_embeddings"],
+        dtype=getattr(jnp, cfg.get("compute_dtype", "bfloat16")),
+        attention_fn=flash_attention)
+    opt = workload["optimizer"]
+    tx = optax.adamw(opt["lr"], b1=opt["b1"], b2=opt["b2"], eps=opt["eps"],
+                     weight_decay=opt["weight_decay"])
+    # each routed block's counter of the last step (the assignments held
+    # here) rides in the state the builders hand on
+    stats = {f"block{i}": {"moe_rows": jnp.zeros((), jnp.float32)}
+             for i in range(cfg["num_layers"])}
+    return {"model": model, "tx": tx, "loss_fn": token_xent,
+            "to_tree": to_tree, "ref_names": ref_names, "batch_stats": stats}

@@ -20,9 +20,12 @@ from horovod_tpu.models.inception import InceptionV3  # noqa: F401
 from horovod_tpu.models.mnist import MnistCNN  # noqa: F401
 from horovod_tpu.models.mlp import MLP  # noqa: F401
 from horovod_tpu.models.transformer import (  # noqa: F401
+    Experts,
+    Layer,
     TransformerLM,
     TransformerTiny,
     TransformerSmall,
+    Yarn,
     generate,
     transformer_param_specs,
 )

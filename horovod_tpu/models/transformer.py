@@ -15,7 +15,9 @@ ring attention inside a ``shard_map`` over the ``seq`` axis.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Optional
+import dataclasses
+import math
+from typing import Any, Callable, Optional, Tuple, Union
 
 import flax.linen as nn
 import jax
@@ -23,10 +25,75 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def apply_rope(x, positions, *, base: float = 10000.0):
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN scaling of a layer's rotary frequencies (Peng et al. 2023, as
+    the published ``rope_type: yarn`` code computes it): frequency j is
+    blended between ``base**(-2j/D)`` and that over ``factor`` by a linear
+    ramp between the correction dims of ``beta_fast`` and ``beta_slow``
+    turns over ``original_max_len`` positions; cos and sin are scaled by
+    ``attention_factor``."""
+
+    factor: float
+    original_max_len: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def blend(self, freqs, head_dim: int, base: float):
+        def correction_dim(turns):
+            return head_dim * math.log(self.original_max_len / (
+                turns * 2 * math.pi)) / (2 * math.log(base))
+
+        low = max(math.floor(correction_dim(self.beta_fast)), 0)
+        high = min(math.ceil(correction_dim(self.beta_slow)), head_dim - 1)
+        ramp = np.clip((np.arange(head_dim // 2) - low)
+                       / max(high - low, 1e-3), 0.0, 1.0)
+        return freqs / self.factor * ramp + freqs * (1.0 - ramp)
+
+
+@dataclasses.dataclass(frozen=True)
+class Experts:
+    """A routed-expert FFN (:func:`horovod_tpu.parallel.moe.routed_experts`)
+    in place of a block's MLP: a float32 router ``routed`` wide, ``top_k``
+    experts a token, SwiGLU experts ``width`` wide; of them this model holds
+    ``count`` from ``first`` (default: all) and computes their part of the
+    layer. ``select`` (``probabilities [tokens, routed] -> scores``) chooses
+    a token's experts in the router's place, by the ``top_k`` of its
+    scores; the weights stay the router's."""
+
+    routed: int
+    top_k: int
+    width: int
+    first: int = 0
+    count: Optional[int] = None
+    select: Optional[Callable] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class Layer:
+    """One block of a :class:`TransformerLM` built from a per-layer
+    description (``TransformerLM(layers=...)``): its attention (``heads``
+    query heads on ``kv_heads`` K/V heads of ``head_dim``, separate
+    bias-free q/k/v projections; rotary with ``rope_base`` and optional
+    ``yarn``; causal, within ``window`` positions where set) and its FFN
+    (a GELU MLP ``mlp_ratio`` x dim wide, or :class:`Experts`)."""
+
+    heads: int
+    head_dim: int
+    kv_heads: Optional[int] = None
+    rope_base: float = 10000.0
+    yarn: Optional[Yarn] = None
+    window: Optional[int] = None
+    ffn: Union[int, Experts] = 4
+
+
+def apply_rope(x, positions, *, base: float = 10000.0,
+               yarn: Optional[Yarn] = None):
     """Rotary position embedding on ``[B, T, H, D]`` (D even), rotate-half
     (NeoX-style) convention: feature i pairs with feature i + D/2, rotated
-    by ``positions * base**(-2i/D)``.
+    by ``positions * base**(-2i/D)`` (frequencies and amplitude rescaled
+    where ``yarn`` is set).
 
     Positions are the *global* token indices, so under sequence parallelism
     each shard rotates with its own offsets and ring/Ulysses attention sees
@@ -35,10 +102,16 @@ def apply_rope(x, positions, *, base: float = 10000.0):
     over a learned absolute table)."""
     d = x.shape[-1]
     half = d // 2
-    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(yarn.blend(
+            float(base) ** (-np.arange(half) / half), d, base), jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B?, T, half]
     cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(
         jnp.float32)
     out = jnp.concatenate(
@@ -46,9 +119,11 @@ def apply_rope(x, positions, *, base: float = 10000.0):
     return out.astype(x.dtype)
 
 
-def default_attention(q, k, v, *, causal: bool = True, sm_scale=None):
+def default_attention(q, k, v, *, causal: bool = True, sm_scale=None,
+                      window: Optional[int] = None):
     """Dense attention fallback (plain jit / tiny shapes). GQA-aware like
-    the flash/ring implementations: K/V may carry fewer heads than Q."""
+    the flash/ring implementations: K/V may carry fewer heads than Q.
+    ``window`` (causal only): row i sees column j where ``i - j < window``."""
     if k.shape[2] != q.shape[2]:
         from horovod_tpu.ops.flash_attention import repeat_kv_heads
 
@@ -59,7 +134,11 @@ def default_attention(q, k, v, *, causal: bool = True, sm_scale=None):
     if causal:
         t_q, t_k = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((t_q, t_k), bool))
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((t_q, t_k), bool), -window)
         s = jnp.where(mask[None, None], s, -1e30)
+    elif window is not None:
+        raise ValueError("window needs causal=True")
     p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
@@ -90,13 +169,36 @@ class TransformerBlock(nn.Module):
     paged: bool = False
     page_size: int = 0
     num_pages: int = 0
+    # a block from a per-layer description (:class:`Layer`): a head size of
+    # its own (separate q/k/v projections), YaRN, a window, routed experts
+    head_dim: Optional[int] = None
+    yarn: Optional[Yarn] = None
+    window: Optional[int] = None
+    experts: Optional[Experts] = None
+    norm: str = "layernorm"  # or "rmsnorm"
+    norm_eps: float = 1e-6
+
+    def _norm(self, name):
+        return make_norm(self.norm, self.norm_eps, self.dtype, name)
 
     @nn.compact
     def __call__(self, x, positions=None, page_table=None):
-        head_dim = self.dim // self.heads
+        if self.decode and (self.window is not None
+                            or self.experts is not None):
+            raise NotImplementedError(
+                "kv-cache decoding (generate(), the serving engine) handles "
+                "full causal attention and MLP blocks only: this block has "
+                f"window={self.window}, experts={self.experts}")
+        head_dim = self.head_dim or self.dim // self.heads
         h_kv = self.kv_heads or self.heads
-        h = nn.LayerNorm(dtype=self.dtype, name="ln1")(x)
-        if h_kv == self.heads:
+        h = self._norm("ln1")(x)
+        if self.head_dim is not None:
+            q, k, v = (
+                nn.Dense(n * head_dim, use_bias=False, dtype=self.dtype,
+                         name=name)(h)
+                for name, n in (("q_proj", self.heads), ("k_proj", h_kv),
+                                ("v_proj", h_kv)))
+        elif h_kv == self.heads:
             qkv = nn.Dense(3 * self.dim, use_bias=False, dtype=self.dtype,
                            name="qkv")(h)
             q, k, v = jnp.split(qkv, 3, axis=-1)
@@ -120,8 +222,8 @@ class TransformerBlock(nn.Module):
                     "use_rope=True requires positions (global token "
                     "indices) — TransformerLM passes them automatically"
                 )
-            q = apply_rope(q, positions, base=self.rope_base)
-            k = apply_rope(k, positions, base=self.rope_base)
+            q = apply_rope(q, positions, base=self.rope_base, yarn=self.yarn)
+            k = apply_rope(k, positions, base=self.rope_base, yarn=self.yarn)
         if self.decode and self.paged:
             from horovod_tpu.ops.flash_attention import (
                 paged_decode_attention,
@@ -184,18 +286,55 @@ class TransformerBlock(nn.Module):
             cache_k.value = upd(cache_k.value, k.astype(self.dtype), start)
             cache_v.value = upd(cache_v.value, v.astype(self.dtype), start)
             att = _decode_attention(q, cache_k.value, cache_v.value, start)
+        elif self.window is not None:
+            att = self.attention_fn(q, k, v, causal=True, window=self.window)
         else:
             att = self.attention_fn(q, k, v, causal=True)
-        att = att.reshape(*att.shape[:2], self.dim)
+        att = att.reshape(*att.shape[:2], self.heads * head_dim)
         x = x + nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
                          name="proj")(att)
 
-        h = nn.LayerNorm(dtype=self.dtype, name="ln2")(x)
+        h = self._norm("ln2")(x)
+        if self.experts is not None:
+            return x + self._routed(h)
         h = nn.Dense(self.mlp_ratio * self.dim, dtype=self.dtype,
                      name="mlp_up")(h)
         h = nn.gelu(h)
         h = nn.Dense(self.dim, dtype=self.dtype, name="mlp_down")(h)
         return x + h
+
+    def _routed(self, h):
+        """The routed-expert FFN over the block's tokens, flattened: float32
+        parameters, the router's product in float32, the experts' in
+        ``dtype``."""
+        from horovod_tpu.parallel.moe import routed_experts
+
+        e = self.experts
+        count = e.routed if e.count is None else e.count
+        init = nn.initializers.normal(0.02)
+        router = self.param("router", init, (self.dim, e.routed))
+        gate = self.param("experts_gate", init, (count, self.dim, e.width))
+        up = self.param("experts_up", init, (count, self.dim, e.width))
+        down = self.param("experts_down", init, (count, e.width, self.dim))
+        y, rows = routed_experts(
+            h.reshape(-1, self.dim), router, gate, up, down, top_k=e.top_k,
+            first=e.first, select=e.select, dtype=self.dtype)
+        # the step's counter rides where BatchNorm's statistics do: a step
+        # builder hands it on, ``moe.record_rows`` reads it
+        if self.is_mutable_collection("batch_stats"):
+            self.variable("batch_stats", "moe_rows", jnp.zeros, (),
+                          jnp.float32).value = rows
+        return y.reshape(h.shape)
+
+
+def make_norm(kind: str, eps: float, dtype, name: str):
+    """A block's (or the model's last) normalisation: flax's LayerNorm, or
+    RMSNorm (``x rsqrt(mean(x^2) + eps) scale``, no bias, no mean)."""
+    if kind == "layernorm":
+        return nn.LayerNorm(epsilon=eps, dtype=dtype, name=name)
+    if kind == "rmsnorm":
+        return nn.RMSNorm(epsilon=eps, dtype=dtype, name=name)
+    raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', got {kind!r}")
 
 
 class TransformerLM(nn.Module):
@@ -223,20 +362,55 @@ class TransformerLM(nn.Module):
     paged: bool = False  # page-pool kv cache (serving engine)
     page_size: int = 0
     num_pages: int = 0
+    # blocks from a per-layer description, one :class:`Layer` a block
+    # (``depth`` of them, rotary positions); None: ``depth`` blocks alike
+    # from heads / kv_heads / mlp_ratio above, as ever
+    layers: Optional[Tuple[Layer, ...]] = None
+    norm: str = "layernorm"  # or "rmsnorm": every block's and the last
+    norm_eps: float = 1e-6
+
+    def block_config(self, i: int) -> dict:
+        """The fields of block ``i``: :class:`TransformerBlock` built from
+        them, under the name ``block{i}``, is the model's i-th block."""
+        common = dict(
+            dim=self.dim, dtype=self.dtype, attention_fn=self.attention_fn,
+            use_rope=self.pos_embedding == "rope", decode=self.decode,
+            cache_len=self.cache_len or self.max_len, paged=self.paged,
+            page_size=self.page_size, num_pages=self.num_pages,
+            norm=self.norm, norm_eps=self.norm_eps)
+        if self.layers is None:
+            return dict(common, heads=self.heads, mlp_ratio=self.mlp_ratio,
+                        kv_heads=self.kv_heads, rope_base=self.rope_base)
+        layer = self.layers[i]
+        routed = isinstance(layer.ffn, Experts)
+        return dict(
+            common, heads=layer.heads, mlp_ratio=0 if routed else layer.ffn,
+            kv_heads=layer.kv_heads, rope_base=layer.rope_base,
+            head_dim=layer.head_dim, yarn=layer.yarn, window=layer.window,
+            experts=layer.ffn if routed else None)
 
     @nn.compact
     def __call__(self, tokens, positions=None, train: bool = True,
                  page_table=None):
+        if self.layers is not None and (
+                len(self.layers) != self.depth
+                or self.pos_embedding != "rope"):
+            raise ValueError(
+                f"layers describes {len(self.layers)} blocks with rotary "
+                f"positions: pass depth={len(self.layers)} and "
+                f"pos_embedding='rope' (got depth={self.depth}, "
+                f"pos_embedding={self.pos_embedding!r})")
         if self.pos_embedding not in ("learned", "rope"):
             raise ValueError(
                 f"pos_embedding must be 'learned' or 'rope', "
                 f"got {self.pos_embedding!r}"
             )
-        if self.pos_embedding == "rope" and (self.dim // self.heads) % 2:
+        head_dims = ([self.dim // self.heads] if self.layers is None
+                     else [layer.head_dim for layer in self.layers])
+        if self.pos_embedding == "rope" and any(d % 2 for d in head_dims):
             raise ValueError(
-                f"rope needs an even head_dim, got "
-                f"{self.dim // self.heads} (dim={self.dim}, "
-                f"heads={self.heads})"
+                f"rope needs an even head_dim, got {head_dims} "
+                f"(dim={self.dim}, heads={self.heads})"
             )
         if self.decode and positions is None:
             raise ValueError(
@@ -259,18 +433,10 @@ class TransformerLM(nn.Module):
             # table — those rows' logits are never consumed
             x = x + jnp.take(pos_table, positions, axis=0).astype(self.dtype)
         for i in range(self.depth):
-            x = TransformerBlock(
-                self.dim, self.heads, self.mlp_ratio, self.dtype,
-                self.attention_fn, kv_heads=self.kv_heads,
-                use_rope=use_rope, rope_base=self.rope_base,
-                decode=self.decode,
-                cache_len=self.cache_len or self.max_len,
-                paged=self.paged, page_size=self.page_size,
-                num_pages=self.num_pages,
-                name=f"block{i}",
-            )(x, positions=positions if (use_rope or self.decode) else None,
-              page_table=page_table)
-        x = nn.LayerNorm(dtype=self.dtype, name="ln_f")(x)
+            x = TransformerBlock(**self.block_config(i), name=f"block{i}")(
+                x, positions=positions if (use_rope or self.decode) else None,
+                page_table=page_table)
+        x = make_norm(self.norm, self.norm_eps, self.dtype, "ln_f")(x)
         logits = nn.Dense(self.vocab, use_bias=False, dtype=self.dtype,
                           name="lm_head")(x)
         # a kv-cache step's few rows go to a sampler or to numpy: float32.
@@ -308,10 +474,16 @@ def transformer_param_specs(params, model_axis: str = "model"):
     def spec_for(path, leaf):
         names = [getattr(p, "key", str(p)) for p in path]
         name = "/".join(names)
+        if "router" in names or any(n.startswith("experts_") for n in names):
+            raise ValueError(
+                "transformer_param_specs has no layout for a routed-expert "
+                f"block ({name}): its experts shard over an expert axis, "
+                "which this function does not describe")
         if leaf.ndim < 2:
             return P()
         if ("qkv" in name or "mlp_up" in name or "q_proj" in name
-                or "kv_proj" in name):
+                or "kv_proj" in name or "k_proj" in name
+                or "v_proj" in name):
             return P(None, model_axis)
         if "proj" in name or "mlp_down" in name:
             return P(model_axis, None)
@@ -366,6 +538,11 @@ def tp_block_apply(block_params, x, *, heads: int, axis: str = "tp"):
     dim = x.shape[-1]
     if heads % n:
         raise ValueError(f"heads={heads} not divisible by tp axis size {n}")
+    if "mlp_up" not in block_params or "bias" not in block_params["ln1"]:
+        raise ValueError(
+            "tp_block_apply handles LayerNorm + MLP blocks only, not "
+            "RMSNorm or routed-expert ones (params: "
+            f"{sorted(block_params)})")
     w = dim // n  # per-rank head-block width (heads//n heads, contiguous)
     head_dim = dim // heads
 

@@ -16,6 +16,14 @@ and accumulates dq/dk/dv blockwise. On the Pallas path it is Pallas too
 sequence is one block, a dk/dv call and a dq call where it is more);
 elsewhere a ``lax.scan`` over K/V blocks, whose block primitive
 (:func:`_block_bwd`) also powers ring attention's distributed backward.
+
+``window=W`` beside ``causal=True`` narrows the mask to the band
+``0 <= i - j < W`` (sliding-window attention). The kernels already leave
+out every tile above the diagonal and copy none of its blocks; a window is
+the same device from the other side: tiles wholly before the band are left
+out and their blocks not copied, tiles the band's edges cross are masked,
+in the forward, the fused backward and both calls of the two-call one.
+``window=None`` traces the program it always traced.
 """
 
 from __future__ import annotations
@@ -45,8 +53,13 @@ def _block_sizes(t_q: int, t_k: int, block_q: int, block_k: int):
     return max(bq, 1), max(bk, 1)
 
 
-def _causal_mask(q_ids, k_ids):
-    return q_ids[:, None] >= k_ids[None, :]
+def _causal_mask(q_ids, k_ids, window: Optional[int] = None):
+    """Row i sees column j where ``j <= i`` and, under a ``window``, where
+    ``i - j < window``."""
+    mask = q_ids[:, None] >= k_ids[None, :]
+    if window is not None:
+        mask = mask & (q_ids[:, None] - k_ids[None, :] < window)
+    return mask
 
 
 def lse_from_state(m, l):
@@ -60,7 +73,8 @@ def lse_from_state(m, l):
 
 
 def _attention_scan(q, k, v, *, causal: bool, sm_scale: float,
-                    q_offset, kv_offset, block_k: int):
+                    q_offset, kv_offset, block_k: int,
+                    window: Optional[int] = None):
     """Online-softmax attention over K/V blocks with a lax.scan.
 
     q: [B, Tq, H, D]; k, v: [B, Tk, H, D]. ``q_offset``/``kv_offset`` are the
@@ -91,7 +105,8 @@ def _attention_scan(q, k, v, *, causal: bool, sm_scale: float,
         s = jnp.einsum("bhqd,bhkd->bhqk", qf, k_blk)  # [B,H,Tq,bk]
         if causal:
             k_ids = kv_offset + j * bk + jnp.arange(bk)
-            s = jnp.where(_causal_mask(q_ids, k_ids)[None, None], s, NEG_INF)
+            s = jnp.where(_causal_mask(q_ids, k_ids, window)[None, None], s,
+                          NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.exp(s - m_new[..., None])
         alpha = jnp.exp(m - m_new)
@@ -125,7 +140,8 @@ def _finalize(m, l, acc, dtype):
 
 
 def _block_bwd(q, k_blk, v_blk, dout, delta, lse, *, causal: bool,
-               sm_scale: float, q_offset, kv_offset):
+               sm_scale: float, q_offset, kv_offset,
+               window: Optional[int] = None):
     """Gradient contributions of one K/V block, recomputing p from lse.
 
     q/dout: [B, Tq, H, D]; k_blk/v_blk: [B, Tk, H, D];
@@ -142,7 +158,8 @@ def _block_bwd(q, k_blk, v_blk, dout, delta, lse, *, causal: bool,
         t_q, t_k = q.shape[1], k_blk.shape[1]
         q_ids = q_offset + jnp.arange(t_q)
         k_ids = kv_offset + jnp.arange(t_k)
-        s = jnp.where(_causal_mask(q_ids, k_ids)[None, None], s, NEG_INF)
+        s = jnp.where(_causal_mask(q_ids, k_ids, window)[None, None], s,
+                      NEG_INF)
     p = jnp.exp(s - lse[..., None])                      # [B,H,Tq,Tk]
     dv = jnp.einsum("bhqk,bqhd->bkhd", p, dof)
     dp = jnp.einsum("bqhd,bkhd->bhqk", dof, vf)
@@ -250,15 +267,25 @@ def _lane_cols(x, n: int):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
+def _band_blocks(window: int, blk: int) -> int:
+    """How many square blocks of ``blk`` rows a q block's band touches, the
+    diagonal's included: k block ``qi - d`` holds a column with
+    ``r - c < window`` for d below this."""
+    return (window + blk - 2) // blk + 1
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       m_scratch, l_scratch, acc_scratch,
                       *, sm_scale: float, causal: bool, block, sub,
-                      past_blocks: bool):
+                      past_blocks: bool, window: Optional[int] = None):
     """One grid step: the q block ``[bq, D]`` against the k/v block
     ``[bk, D]``, as a static schedule of ``[cq, ck]`` sub-tiles in one basic
     block, so the compiler overlaps one sub-tile's products with another's
     softmax. ``past_blocks``: some grid step lies wholly below the diagonal
-    (the q sequence spans more than one block)."""
+    (the q sequence spans more than one block). ``window`` (causal only):
+    row r sees column c where ``0 <= r - c < window``; blocks and sub-tiles
+    wholly outside that band are left out from both sides, those its edges
+    cross are masked."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
@@ -280,9 +307,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     # other is applied to the f32 scores.
     scale_q = math.frexp(sm_scale)[0] == 0.5
 
-    def _products(i: int, j: int, diagonal):
+    def _products(i: int, j: int, diagonal, band=None):
         """Sub-tile (i, j) of the block. ``diagonal``: None for no mask,
-        else row r sees column c where ``r - c >= diagonal``."""
+        else row r sees column c where ``r - c >= diagonal``; ``band``:
+        None, else only where ``r - c < band`` too."""
         rows, cols = pl.ds(i * cq, cq), pl.ds(j * ck, ck)
         q, k, v = q_ref[0, rows, :], k_ref[0, cols, :], v_ref[0, cols, :]
         if scale_q:
@@ -294,10 +322,15 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32)         # [cq, ck]
         if not scale_q:
             s = s * sm_scale
-        if diagonal is not None:
+        if diagonal is not None or band is not None:
             r = lax.broadcasted_iota(jnp.int32, (cq, ck), 0)
             c = lax.broadcasted_iota(jnp.int32, (cq, ck), 1)
-            s = jnp.where(r - c >= diagonal, s, NEG_INF)
+            if diagonal is not None:
+                s = jnp.where(r - c >= diagonal, s, NEG_INF)
+            if band is not None:
+                # a row masked whole here has m = NEG_INF and p = 1: the
+                # diagonal's sub-tile, which comes later, rescales it away
+                s = jnp.where(r - c < band, s, NEG_INF)
         m_prev = m_scratch[rows, :]                      # [cq, 128]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - _lane_cols(m_new, ck))
@@ -326,14 +359,39 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 r0, c0 = rel + i * cq, j * ck
                 needed = c0 <= r0 + (cq - 1)
                 if not isinstance(rel, int):
-                    pl.when(needed)(
-                        functools.partial(_products, i, j, c0 - r0))
-                elif needed:
+                    if window is None:
+                        pl.when(needed)(
+                            functools.partial(_products, i, j, c0 - r0))
+                    else:
+                        pl.when(jnp.logical_and(
+                            needed, r0 - c0 - (ck - 1) < window))(
+                            functools.partial(_products, i, j, c0 - r0,
+                                              window - (r0 - c0)))
+                elif needed and (window is None
+                                 or r0 - c0 - (ck - 1) < window):
                     past = c0 + (ck - 1) <= r0
-                    _products(i, j, None if past else c0 - r0)
+                    inside = window is None or r0 - c0 + (cq - 1) < window
+                    _products(i, j, None if past else c0 - r0,
+                              None if inside else window - (r0 - c0))
 
     if not causal:
         _block(None)
+    elif window is not None:
+        rel = qi * bq - kj * bk
+        # the block wholly inside the band; the blocks an edge crosses, by a
+        # schedule known now where the blocks are square (rel is a multiple
+        # of the block), else decided per sub-tile on the chip
+        inside = jnp.logical_and(rel >= bk - 1, rel + (bq - 1) < window)
+        if past_blocks and bq + bk - 1 <= window:
+            pl.when(inside)(functools.partial(_block, None))
+        if bq == bk:
+            for d in range(_band_blocks(window, bk)):
+                if not (d >= 1 and d * bk + (bq - 1) < window):
+                    pl.when(rel == d * bk)(functools.partial(_block, d * bk))
+        else:
+            crossed = jnp.logical_and(rel > -bq, rel - (bk - 1) < window)
+            pl.when(jnp.logical_and(crossed, jnp.logical_not(inside)))(
+                functools.partial(_block, rel))
     else:
         rel = qi * bq - kj * bk
         if past_blocks:
@@ -376,7 +434,7 @@ def _record_fwd_tile(bq: int, bk: int, grid) -> None:
 
 def _flash_fwd_pallas(q, k, v, *, causal: bool, sm_scale: float,
                       block_q: Optional[int], block_k: Optional[int],
-                      interpret: bool):
+                      interpret: bool, window: Optional[int] = None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -416,11 +474,15 @@ def _flash_fwd_pallas(q, k, v, *, causal: bool, sm_scale: float,
             # a wholly-future block keeps the index of the last block its
             # q rows need: the pipeline sees no change and issues no copy
             kj = jnp.minimum(kj, (qi * bq + (bq - 1)) // bk)
+            if window is not None:
+                # and so does a block wholly before the window
+                kj = jnp.maximum(
+                    kj, jnp.maximum(qi * bq - (window - 1), 0) // bk)
         return row, kj, 0
 
     kernel = functools.partial(
         _flash_fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block=(bq, bk), sub=(cq, ck), past_blocks=t_q > bq,
+        block=(bq, bk), sub=(cq, ck), past_blocks=t_q > bq, window=window,
     )
     out, lse = pl.pallas_call(
         kernel,
@@ -533,13 +595,15 @@ def _dot(a, b, contract_a: int, contract_b: int):
 
 
 def _bwd_block(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *,
-               sm_scale: float, sub: int, diagonal: bool,
-               want_dq: bool, want_dkv: bool):
+               sm_scale: float, sub: int, rel: Optional[int],
+               want_dq: bool, want_dkv: bool, window: Optional[int] = None):
     """Gradient contributions of the square block the refs hold (q, o, do,
     lse: its q rows; k, v: its k rows), as a static schedule of ``[sub,
-    sub]`` sub-tiles in one basic block. ``diagonal``: the block lies on
-    the causal diagonal, so only sub-tiles on or below it are computed and
-    only those on it are masked; else every sub-tile, unmasked.
+    sub]`` sub-tiles in one basic block. ``rel``: None for every sub-tile,
+    unmasked; else the block's first q row lies ``rel`` rows past its first
+    k column under the causal mask (0: the block on the diagonal), so only
+    sub-tiles that hold a column with ``0 <= r - c`` (``< window``) are
+    computed and only those an edge crosses are masked.
 
     Per sub-tile, the flash backward: s = q k^T and p = exp(s - lse) again,
     dp = do v^T, ds = p (dp - delta) with delta = rowsum(do o), all in f32;
@@ -572,16 +636,26 @@ def _bwd_block(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *,
         q, do = q_ref[0, rows, :], do_ref[0, rows, :]
         if scale_q:
             q = (q.astype(jnp.float32) * sm_scale).astype(dtype)
-        for j in range(i + 1 if diagonal else n):
+        for j in range(n):
+            # r - c at the sub-tile's first row and column
+            off = None if rel is None else rel + (i - j) * sub
+            if off is not None and (off + sub <= 0 or (
+                    window is not None and off - sub >= window - 1)):
+                continue
             cols = pl.ds(j * sub, sub)
             k, v = k_ref[0, cols, :], v_ref[0, cols, :]
             s = _dot(q, k, 1, 1)                         # [sub, sub]
             if not scale_q:
                 s = s * sm_scale
-            if diagonal and i == j:
+            if off is not None and (off < sub - 1 or (
+                    window is not None and off + sub > window)):
                 r = lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
                 c = lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
-                s = jnp.where(r >= c, s, NEG_INF)
+                if off < sub - 1:
+                    s = jnp.where(r >= c if off == 0 else r - c >= -off,
+                                  s, NEG_INF)
+                if window is not None and off + sub > window:
+                    s = jnp.where(r - c < window - off, s, NEG_INF)
             # a fully-masked row's lse is LSE_MASKED: p underflows to 0
             p = jnp.exp(s - _lane_cols(lse[i * sub:(i + 1) * sub], sub))
             dp = _dot(do, v, 1, 1)
@@ -593,9 +667,9 @@ def _bwd_block(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *,
             if want_dq:
                 add(dq, i, _dot(ds, k, 1, 0))
     if want_dq:
-        dq = [x * sm_scale for x in dq]
+        dq = [None if x is None else x * sm_scale for x in dq]
     if want_dkv and not scale_q:
-        dk = [x * sm_scale for x in dk]
+        dk = [None if x is None else x * sm_scale for x in dk]
     return dq, dk, dv
 
 
@@ -608,16 +682,19 @@ def _store_tiles(ref, tiles, sub: int) -> None:
 
 
 def _add_tiles(scratch, tiles, sub: int) -> None:
-    """Add a block's f32 sub-tile values to its accumulator."""
+    """Add a block's f32 sub-tile values to its accumulator (None: a row
+    or column of sub-tiles the window left out whole)."""
     from jax.experimental import pallas as pl
 
     for i, x in enumerate(tiles):
-        scratch[pl.ds(i * sub, sub), :] += x
+        if x is not None:
+            scratch[pl.ds(i * sub, sub), :] += x
 
 
 def hvd_flash_bwd(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                   dq_ref, dk_ref, dv_ref, *scratch,
-                  sm_scale: float, causal: bool, sub: int):
+                  sm_scale: float, causal: bool, sub: int,
+                  window: Optional[int] = None):
     """The whole backward of one (batch, head) whose sequence is one block:
     five products a sub-tile. Grid ``[batch*kv_heads, group]``: under GQA
     the group's q heads take turns on the resident dk/dv block, summed in
@@ -626,7 +703,8 @@ def hvd_flash_bwd(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
     dq, dk, dv = _bwd_block(
         q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, sm_scale=sm_scale,
-        sub=sub, diagonal=causal, want_dq=True, want_dkv=True)
+        sub=sub, rel=0 if causal else None, want_dq=True, want_dkv=True,
+        window=window)
     _store_tiles(dq_ref, dq, sub)
     if not scratch:                   # one q head a kv head: no sum
         _store_tiles(dk_ref, dk, sub)
@@ -649,22 +727,36 @@ def hvd_flash_bwd(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dv_ref[0] = dv_scratch[:].astype(dv_ref.dtype)
 
 
-def _bwd_blocks(causal: bool, qi, kj, block):
-    """Run ``block(diagonal)`` for the grid step's (q block, k block) pair:
-    under the causal mask the diagonal block by its triangle, a past block
-    whole, a future block not at all."""
+def _bwd_blocks(causal: bool, qi, kj, block, window: Optional[int] = None,
+                blk: int = 0):
+    """Run ``block(rel)`` for the grid step's (q block, k block) pair (rel
+    as :func:`_bwd_block` takes it): under the causal mask the diagonal
+    block by its triangle, a past block whole, a future block not at all;
+    under a ``window`` of square blocks of ``blk`` rows besides, a block
+    the band's far edge crosses by what lies inside it, and a block wholly
+    before the band not at all."""
     from jax.experimental import pallas as pl
 
     if not causal:
-        block(False)
+        block(None)
         return
-    pl.when(qi == kj)(functools.partial(block, True))
-    pl.when(qi > kj)(functools.partial(block, False))
+    pl.when(qi == kj)(functools.partial(block, 0))
+    if window is None:
+        pl.when(qi > kj)(functools.partial(block, None))
+        return
+    whole = [d for d in range(1, _band_blocks(window, blk))
+             if (d + 1) * blk - 1 < window]
+    if whole:
+        pl.when(jnp.logical_and(qi > kj, qi - kj <= whole[-1]))(
+            functools.partial(block, None))
+    for d in range(len(whole) + 1, _band_blocks(window, blk)):
+        pl.when(qi - kj == d)(functools.partial(block, d * blk))
 
 
 def hvd_flash_bwd_dkv(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                       dk_ref, dv_ref, dk_scratch, dv_scratch,
-                      *, sm_scale: float, causal: bool, sub: int):
+                      *, sm_scale: float, causal: bool, sub: int,
+                      window: Optional[int] = None):
     """dk and dv of one k block, summed over the q blocks (and, under GQA,
     the group's q heads) that see it. Grid ``[batch*kv_heads, k blocks,
     group, q blocks]``, the last two the reduction."""
@@ -679,14 +771,14 @@ def hvd_flash_bwd_dkv(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dk_scratch[:] = jnp.zeros_like(dk_scratch)
         dv_scratch[:] = jnp.zeros_like(dv_scratch)
 
-    def block(diagonal: bool):
+    def block(rel):
         _, dk, dv = _bwd_block(
             q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, sm_scale=sm_scale,
-            sub=sub, diagonal=diagonal, want_dq=False, want_dkv=True)
+            sub=sub, rel=rel, want_dq=False, want_dkv=True, window=window)
         _add_tiles(dk_scratch, dk, sub)
         _add_tiles(dv_scratch, dv, sub)
 
-    _bwd_blocks(causal, qi, kj, block)
+    _bwd_blocks(causal, qi, kj, block, window, q_ref.shape[1])
 
     @pl.when(last)
     def _write():
@@ -696,7 +788,8 @@ def hvd_flash_bwd_dkv(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 def hvd_flash_bwd_dq(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                      dq_ref, dq_scratch,
-                     *, sm_scale: float, causal: bool, sub: int):
+                     *, sm_scale: float, causal: bool, sub: int,
+                     window: Optional[int] = None):
     """dq of one q block, summed over the k blocks it sees. Grid
     ``[batch*heads, q blocks, k blocks]``, the last the reduction."""
     from jax.experimental import pallas as pl
@@ -707,13 +800,13 @@ def hvd_flash_bwd_dq(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     def _init():
         dq_scratch[:] = jnp.zeros_like(dq_scratch)
 
-    def block(diagonal: bool):
+    def block(rel):
         dq, _, _ = _bwd_block(
             q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, sm_scale=sm_scale,
-            sub=sub, diagonal=diagonal, want_dq=True, want_dkv=False)
+            sub=sub, rel=rel, want_dq=True, want_dkv=False, window=window)
         _add_tiles(dq_scratch, dq, sub)
 
-    _bwd_blocks(causal, qi, kj, block)
+    _bwd_blocks(causal, qi, kj, block, window, q_ref.shape[1])
 
     @pl.when(kj == pl.num_programs(2) - 1)
     def _write():
@@ -721,7 +814,8 @@ def hvd_flash_bwd_dq(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 
 def _flash_bwd_pallas(q, k, v, out, lse, dout, *, causal: bool,
-                      sm_scale: float, blk: int, sub: int, interpret: bool):
+                      sm_scale: float, blk: int, sub: int, interpret: bool,
+                      window: Optional[int] = None):
     """dq, dk, dv by the Pallas kernels, in square blocks of ``blk`` rows.
     One block a sequence: one fused call (:func:`hvd_flash_bwd`). More: dk
     and dv accumulate over q blocks and dq over k blocks, which no one grid
@@ -747,7 +841,11 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, *, causal: bool,
     # lse as rows [1, T]: a [T, 1] column is padded to 128 lanes in HBM
     args = (rows_first(q), rows_first(k), rows_first(v), rows_first(out),
             rows_first(dout), lse.reshape(b * h, 1, t))
-    kernel_kw = dict(sm_scale=sm_scale, causal=causal, sub=sub)
+    kernel_kw = dict(sm_scale=sm_scale, causal=causal, sub=sub,
+                     window=window)
+    # a block wholly before the band keeps the index of the nearest one the
+    # step needs too, as a wholly-future block does
+    far = None if window is None else _band_blocks(window, blk) - 1
     call_kw = dict(
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
@@ -778,7 +876,11 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, *, causal: bool,
         # nearest block the step needs: the pipeline sees no change and
         # issues no copy
         def dkv_q_index(bkv, kj, gi, qi):
-            return bkv * g + gi, jnp.maximum(qi, kj) if causal else qi
+            if causal:
+                qi = jnp.maximum(qi, kj)
+                if window is not None:
+                    qi = jnp.minimum(qi, jnp.minimum(kj + far, n - 1))
+            return bkv * g + gi, qi
 
         q_spec = pl.BlockSpec(
             (1, blk, d), lambda *at: (*dkv_q_index(*at), 0))
@@ -801,8 +903,11 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, *, causal: bool,
 
         def dq_kv_index(bh, qi, kj):
             # grid row bh = batch*h + head  ->  kv row, as the forward's
-            return ((bh // h) * h_kv + (bh % h) // g,
-                    jnp.minimum(kj, qi) if causal else kj, 0)
+            if causal:
+                kj = jnp.minimum(kj, qi)
+                if window is not None:
+                    kj = jnp.maximum(kj, jnp.maximum(qi - far, 0))
+            return (bh // h) * h_kv + (bh % h) // g, kj, 0
 
         q_spec = pl.BlockSpec((1, blk, d), lambda bh, qi, kj: (bh, qi, 0))
         kv_spec = pl.BlockSpec((1, blk, d), dq_kv_index)
@@ -849,34 +954,36 @@ def reduce_group(dx, g: int):
     return dx.reshape(b, t, h // g, g, d).sum(axis=3)
 
 
-def _fwd_impl(q, k, v, causal, sm_scale, block_sizes):
+def _fwd_impl(q, k, v, causal, sm_scale, block_sizes, window):
     block_q, block_k, use_pallas, interpret = block_sizes
     if use_pallas:
         # GQA handled zero-copy inside the kernel's kv index map
         return _flash_fwd_pallas(
             q, k, v, causal=causal, sm_scale=sm_scale,
-            block_q=block_q, block_k=block_k, interpret=interpret)
+            block_q=block_q, block_k=block_k, interpret=interpret,
+            window=window)
     g = gqa_group(q, k)
     m, l, acc = _attention_scan(
         q, rep_group(k, g), rep_group(v, g), causal=causal,
         sm_scale=sm_scale,
-        q_offset=0, kv_offset=0, block_k=block_k or SCAN_BLOCK_K)
+        q_offset=0, kv_offset=0, block_k=block_k or SCAN_BLOCK_K,
+        window=window)
     return _finalize(m, l, acc, q.dtype), lse_from_state(m, l)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal, sm_scale, block_sizes):
-    return _fwd_impl(q, k, v, causal, sm_scale, block_sizes)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, sm_scale, block_sizes, window):
+    return _fwd_impl(q, k, v, causal, sm_scale, block_sizes, window)[0]
 
 
 @jax.named_scope("hvd.flash_fwd")
-def _flash_fwd(q, k, v, causal, sm_scale, block_sizes):
-    out, lse = _fwd_impl(q, k, v, causal, sm_scale, block_sizes)
+def _flash_fwd(q, k, v, causal, sm_scale, block_sizes, window):
+    out, lse = _fwd_impl(q, k, v, causal, sm_scale, block_sizes, window)
     return out, (q, k, v, out, lse)
 
 
 @jax.named_scope("hvd.flash_bwd")
-def _flash_bwd(causal, sm_scale, block_sizes, res, g):
+def _flash_bwd(causal, sm_scale, block_sizes, window, res, g):
     """O(T) extra-memory backward: p is recomputed from lse block by block
     (saves no score matrix — the flash-attention trade). On the Pallas path
     the kernels of :func:`_flash_bwd_pallas`, wherever :func:`_bwd_tile`
@@ -888,13 +995,13 @@ def _flash_bwd(causal, sm_scale, block_sizes, res, g):
     if tile:
         return _flash_bwd_pallas(
             q, k, v, out, lse, g, causal=causal, sm_scale=sm_scale,
-            blk=tile[0], sub=tile[1], interpret=interpret)
+            blk=tile[0], sub=tile[1], interpret=interpret, window=window)
     return _flash_bwd_scan(q, k, v, out, lse, g, causal=causal,
-                           sm_scale=sm_scale, block_k=block_k)
+                           sm_scale=sm_scale, block_k=block_k, window=window)
 
 
 def _flash_bwd_scan(q, k, v, out, lse, g, *, causal: bool, sm_scale: float,
-                    block_k: Optional[int]):
+                    block_k: Optional[int], window: Optional[int] = None):
     """The backward as a scan over K/V blocks of ``block_k`` (default
     ``SCAN_BLOCK_K``) rows in f32. Residual K/V stay H_kv-wide under GQA;
     each block is broadcast per step and its gradient group-summed back
@@ -915,7 +1022,7 @@ def _flash_bwd_scan(q, k, v, out, lse, g, *, causal: bool, sm_scale: float,
         dq_c, dk_b, dv_b = _block_bwd(
             q, rep_group(k_blk, grp), rep_group(v_blk, grp), g, delta,
             lse, causal=causal,
-            sm_scale=sm_scale, q_offset=0, kv_offset=j * bk)
+            sm_scale=sm_scale, q_offset=0, kv_offset=j * bk, window=window)
         return dq + dq_c, (reduce_group(dk_b, grp), reduce_group(dv_b, grp))
 
     dq0 = jnp.zeros(q.shape, jnp.float32)
@@ -1043,6 +1150,7 @@ def repeat_kv_heads(q, k, v):
 
 
 def flash_attention(q, k, v, *, causal: bool = False,
+                    window: Optional[int] = None,
                     sm_scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
@@ -1052,6 +1160,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
     [B, Tk, H_kv, D] with ``H % H_kv == 0`` — grouped-query attention
     (H_kv < H) broadcasts each K/V head over its query group; MQA is
     ``H_kv == 1``. Returns [B, Tq, H, D].
+
+    ``window`` (with ``causal=True``): row i sees column j only where
+    ``0 <= i - j < window`` (sliding-window attention). The kernels, forward
+    and backward, leave out every tile wholly outside that band, copy none
+    of its blocks, and mask the tiles its edges cross; the scans, which
+    hold every q row against each K/V block, mask.
 
     ``use_pallas`` defaults to True on TPU backends (the VMEM-tiled
     kernels, forward and backward) and False elsewhere (the scan path).
@@ -1078,9 +1192,17 @@ def flash_attention(q, k, v, *, causal: bool = False,
     if k.shape != v.shape:
         raise ValueError(f"k/v shape mismatch: {k.shape} vs {v.shape}")
     gqa_group(q, k)  # validate H % H_kv == 0
+    if window is not None:
+        if not causal or window < 1 or q.shape[1] != k.shape[1]:
+            raise ValueError(
+                "flash attention: window needs causal=True, window >= 1 and "
+                f"q and k of one length (got causal={causal}, window="
+                f"{window}, t_q={q.shape[1]}, t_k={k.shape[1]})")
+        if window >= k.shape[1]:
+            window = None            # the band is the whole triangle
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     return _flash(q, k, v, causal, sm_scale,
-                  (block_q, block_k, use_pallas, interpret))
+                  (block_q, block_k, use_pallas, interpret), window)

@@ -10,16 +10,28 @@ highest-gate expert; tokens beyond an expert's capacity are dropped (output
 falls back to zero for them — the standard Switch behavior). Dispatch and
 combine are the transpose of each other, so the layer is differentiable end
 to end, router included (straight-through on the gate value).
+
+:func:`routed_experts` is the other formulation, for many experts and
+several a token, where the ``[T, E, C]`` one-hot would be the layer: top-k
+routing without dropped tokens. The assignments to the experts a chip holds
+are sorted by expert into a buffer of static shape that holds the worst
+case, three grouped matrix products run over its tiles in use as Pallas
+kernels, and the weighted rows are gathered back per token. The layer is told which experts it holds, routes over all
+of them and computes its own experts' part; it exchanges nothing, so it
+serves one chip (a model's share of a deployment), not yet the ``expert``
+axis.
 """
 
 from __future__ import annotations
 
-from typing import Callable
+import functools
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu.observability import metrics as _metrics
 from horovod_tpu.parallel.mesh import EXPERT_AXIS
 
 
@@ -155,3 +167,385 @@ def expert_parallel_moe(router_params, expert_params, x, expert_fn: Callable,
     # aux loss averaged over shards (each shard routed its own tokens)
     aux = lax.pmean(aux, axis_name)
     return y.astype(x.dtype), aux
+
+
+# --------------------------------------------------------------------------
+# routing without dropped tokens: sort + grouped matrix products
+
+#: rows of one tile of the sorted buffer. Every held expert's rows start on
+#: a tile, so a tile multiplies one expert's matrix: no masks in the
+#: kernels, at up to a tile of padding rows an expert (a v5e chip, [R, 2304]
+#: x [16, 2304, 896] bfloat16 with 16,318 rows in use, ms a product:
+#: ``lax.ragged_dot`` 1.97, jax's megablox ``gmm`` 0.67-1.15 by tiling, the
+#: dense product of as many rows 0.40; PERF.md section 6, PR 35)
+TILE_ROWS = 256
+#: VMEM the grouped products ask Mosaic for: a row tile, one expert's matrix
+#: whole and the result tile, double-buffered
+_VMEM_LIMIT = 48 << 20
+
+
+def route_top_k(x, router, top_k: int, select=None):
+    """The router in float32: probabilities over every routed expert, the
+    ``top_k`` largest and their weights normalised to sum to one. ``x``
+    ``[T, D]``, ``router`` ``[D, E]`` -> weights ``[T, k]`` f32, experts
+    ``[T, k]`` int32. Where ``select`` is given, a token's experts are the
+    ``top_k`` of ``select(probabilities)`` ``[T, E]`` instead; their
+    weights are the router's all the same."""
+    logits = jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    _, experts = lax.top_k(probs if select is None else select(probs), top_k)
+    # the chosen logits by a one-hot product: its gradient is a product
+    # too, where ``top_k``'s own is a scatter. Their softmax is the chosen
+    # probabilities over their sum, and stays finite where a token's chosen
+    # experts all have probabilities that round to nothing
+    chosen = jax.nn.one_hot(experts, logits.shape[-1], dtype=logits.dtype)
+    return jax.nn.softmax(jnp.sum(logits[:, None, :] * chosen, axis=-1),
+                          axis=-1), experts
+
+
+def buffer_rows(tokens: int, top_k: int, count: int) -> int:
+    """Rows of the sorted buffer: the worst case, every assignment held
+    here, and a tile of padding an expert, so no assignment is ever without
+    a row. Nothing holds a router near balance (untrained, the chip read
+    0 to 30,639 of 65,536 assignments on 16 of 64 experts, layer by layer
+    and step by step, where balance sends 16,384). The grouped products
+    pass over the tiles no row fills; the gathers and the activation run
+    over the whole of it."""
+    return (-(-tokens * top_k // TILE_ROWS) + count) * TILE_ROWS
+
+
+def _plan(experts, *, first: int, count: int):
+    """Where each assignment goes. ``experts`` ``[T, k]``: the routed
+    expert of each of a token's slots. The slots whose expert is held here
+    (``first <= e < first + count``) are laid out by expert, each expert's
+    run starting on a tile of ``TILE_ROWS`` rows (an expert with no slot
+    keeps one tile: its weight gradient is written there), in a buffer of
+    :func:`buffer_rows` rows. Returns a dict of int32 arrays:
+    ``row_of_slot`` ``[T * k]`` (the buffer row of each slot; the buffer's
+    length for a slot not held here), ``slot_of_row`` ``[rows]`` (``T * k``
+    for a padding row), ``tile_expert`` ``[rows / TILE_ROWS]`` (a tile past
+    the last expert's rows reads as the last expert's), ``tiles`` ``[1]``
+    (the tiles in use: the experts' runs fill the buffer's first ``tiles``
+    tiles) and the counter ``local`` (slots held here)."""
+    tokens, top_k = experts.shape
+    slots = tokens * top_k
+    rows = buffer_rows(tokens, top_k, count)
+    local = experts.reshape(slots) - first
+    key = jnp.where((local >= 0) & (local < count), local, count)
+    # sorted by expert, slots not held here last; stable: token order
+    # within an expert
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    rank = jnp.argsort(order).astype(jnp.int32)
+    # tables of ``count`` entries are read by a comparison against every
+    # held expert, and per tile, not per row: ``bincount`` is a scatter, and
+    # a gather of scalars costs the chip 15 ns an element
+    mine = key[:, None] == jnp.arange(count)[None, :]            # [slots, E]
+    sizes = jnp.sum(mine, axis=0, dtype=jnp.int32)
+    starts = jnp.cumsum(sizes) - sizes
+    tiles_of = jnp.maximum(-(-sizes // TILE_ROWS), 1)
+    tile_ends = jnp.cumsum(tiles_of)
+    row_starts = (tile_ends - tiles_of) * TILE_ROWS
+    n_tiles = rows // TILE_ROWS
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_ends, jnp.arange(n_tiles), side="right",
+                         method="compare_all"),
+        count - 1).astype(jnp.int32)
+
+    row = rank + jnp.sum(jnp.where(mine, (row_starts - starts)[None, :], 0),
+                         axis=1)
+    row_of_slot = jnp.where(key < count, row, rows).astype(jnp.int32)
+
+    within = (jnp.arange(rows, dtype=jnp.int32).reshape(n_tiles, TILE_ROWS)
+              - row_starts[tile_expert][:, None])                # [tiles, T]
+    real = (within >= 0) & (within < sizes[tile_expert][:, None])
+    sorted_at = jnp.clip(starts[tile_expert][:, None] + within, 0, slots - 1)
+    slot_of_row = jnp.where(real, order[sorted_at], slots).reshape(
+        rows).astype(jnp.int32)
+    return {
+        "row_of_slot": row_of_slot, "slot_of_row": slot_of_row,
+        "tile_expert": tile_expert,
+        "tiles": tile_ends[-1:].astype(jnp.int32), "local": jnp.sum(sizes),
+    }
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _to_rows(x, slot_of_row, row_of_slot, top_k: int):
+    """``x`` ``[T, ...]`` -> ``[rows, ...]``: each buffer row its slot's
+    token's row of ``x`` (token 0's for a padding row). Its transpose is a
+    gather too (:func:`_to_tokens`): a scatter-add of as many rows takes
+    ten times a gather's time on the chip."""
+    return x[jnp.minimum(slot_of_row // top_k, x.shape[0] - 1)]
+
+
+def _to_rows_fwd(x, slot_of_row, row_of_slot, top_k):
+    return _to_rows(x, slot_of_row, row_of_slot, top_k), (
+        slot_of_row, row_of_slot)
+
+
+@jax.named_scope("hvd.moe_route")
+def _to_rows_bwd(top_k, res, g):
+    slot_of_row, row_of_slot = res
+    return _to_tokens(g, slot_of_row, row_of_slot, top_k), None, None
+
+
+_to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _to_tokens(y, slot_of_row, row_of_slot, top_k: int):
+    """``y`` ``[rows, ...]`` -> ``[T, ...]``: each token the float32 sum of
+    its slots' buffer rows (a slot with no row adds nothing)."""
+    has_row = row_of_slot < y.shape[0]
+    rows = y[jnp.minimum(row_of_slot, y.shape[0] - 1)]
+    rows = jnp.where(has_row.reshape((-1,) + (1,) * (y.ndim - 1)),
+                     rows.astype(jnp.float32), 0)
+    return jnp.sum(rows.reshape(-1, top_k, *y.shape[1:]),
+                   axis=1).astype(y.dtype)
+
+
+def _to_tokens_fwd(y, slot_of_row, row_of_slot, top_k):
+    return _to_tokens(y, slot_of_row, row_of_slot, top_k), (
+        slot_of_row, row_of_slot)
+
+
+@jax.named_scope("hvd.moe_route")
+def _to_tokens_bwd(top_k, res, g):
+    slot_of_row, row_of_slot = res
+    real = slot_of_row < row_of_slot.shape[0]
+    rows = _to_rows(g, slot_of_row, row_of_slot, top_k)
+    real = real.reshape(real.shape + (1,) * (rows.ndim - 1))
+    return jnp.where(real, rows, 0), None, None
+
+
+_to_tokens.defvjp(_to_tokens_fwd, _to_tokens_bwd)
+
+
+def hvd_moe_gmm(tile_expert_ref, tiles_ref, lhs_ref, rhs_ref, out_ref, *,
+                transpose_rhs: bool):
+    """One row tile of the buffer times its expert's matrix (or its
+    transpose). A tile past the ``tiles_ref[0]`` in use is passed over: the
+    index maps keep the last tile in use in place, so nothing is copied
+    for it either, and its rows of the result stay as they were
+    allocated. The work follows the rows the router sent here."""
+    from jax.experimental import pallas as pl
+
+    del tile_expert_ref                    # read by the index maps
+
+    @pl.when(pl.program_id(0) < tiles_ref[0])
+    def _multiply():
+        out_ref[...] = lax.dot_general(
+            lhs_ref[...], rhs_ref[0],
+            (((1,), (1 if transpose_rhs else 0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(out_ref.dtype)
+
+
+def hvd_moe_tgmm(tile_expert_ref, tiles_ref, lhs_ref, dout_ref, out_ref):
+    """An expert's weight gradient, ``lhs^T dout`` summed over its row
+    tiles, in the float32 output block that stays in VMEM while the grid
+    walks one expert's tiles (padding rows add zeros: their ``dout`` is;
+    the tiles past those in use are passed over)."""
+    from jax.experimental import pallas as pl
+
+    i = pl.program_id(2)
+    before = tile_expert_ref[jnp.maximum(i - 1, 0)]
+
+    @pl.when(jnp.logical_or(i == 0, tile_expert_ref[i] != before))
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(i < tiles_ref[0])
+    def _add():
+        out_ref[0] += lax.dot_general(
+            lhs_ref[...], dout_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+
+def _fit_block(n: int, cap: int) -> int:
+    """The widest block of at most ``cap`` columns that divides ``n`` in
+    whole lanes, else ``n``."""
+    return next((b for b in range(cap - cap % 128, 127, -128) if n % b == 0),
+                n) if n > cap else n
+
+
+def _in_use(i, tiles):
+    """Tile ``i``, or the last tile in use where ``i`` lies past it: a
+    block index that does not change asks for no copy."""
+    return jnp.minimum(i, tiles[0] - 1)
+
+
+def _pallas_gmm(lhs, rhs, tile_expert, tiles, *, transpose_rhs: bool,
+                interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows = lhs.shape[0]
+    n_out = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    matrix_bytes = rhs.shape[1] * rhs.shape[2] * rhs.dtype.itemsize
+    if 2 * matrix_bytes > _VMEM_LIMIT // 2:
+        raise ValueError(
+            f"routed experts: an expert's matrix {rhs.shape[1:]} in "
+            f"{rhs.dtype} does not fit the grouped product's VMEM whole "
+            f"({matrix_bytes} bytes); this kernel has no tiling over it")
+    return pl.pallas_call(
+        functools.partial(hvd_moe_gmm, transpose_rhs=transpose_rhs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(rows // TILE_ROWS,),
+            in_specs=[
+                pl.BlockSpec((TILE_ROWS, lhs.shape[1]),
+                             lambda i, te, n: (_in_use(i, n), 0)),
+                # one expert's tiles follow each other: its matrix is
+                # copied once
+                pl.BlockSpec((1,) + rhs.shape[1:],
+                             lambda i, te, n: (te[i], 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((TILE_ROWS, n_out),
+                                   lambda i, te, n: (_in_use(i, n), 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, n_out), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="hvd_moe_gmm",
+    )(tile_expert, tiles, lhs, rhs)
+
+
+def _pallas_tgmm(lhs, dout, tile_expert, tiles, count: int, *,
+                 interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, k = lhs.shape
+    n = dout.shape[1]
+    # the float32 output block [bk, bn], double-buffered, beside the tiles
+    bk, bn = (_fit_block(k, 768), n) if k >= n else (k, _fit_block(n, 768))
+    return pl.pallas_call(
+        hvd_moe_tgmm,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(k // bk, n // bn, rows // TILE_ROWS),
+            in_specs=[
+                pl.BlockSpec((TILE_ROWS, bk),
+                             lambda a, b, i, te, n: (_in_use(i, n), a)),
+                pl.BlockSpec((TILE_ROWS, bn),
+                             lambda a, b, i, te, n: (_in_use(i, n), b)),
+            ],
+            out_specs=pl.BlockSpec((1, bk, bn),
+                                   lambda a, b, i, te, n: (te[i], a, b)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((count, k, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="hvd_moe_tgmm",
+    )(tile_expert, tiles, lhs, dout)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def grouped_matmul(lhs, rhs, tile_expert, tiles, interpret: bool = False):
+    """``lhs`` ``[rows, K]`` times, tile of ``TILE_ROWS`` rows by tile,
+    the matrix ``rhs[tile_expert[i]]`` of ``rhs`` ``[E, K, N]`` (float32
+    parameters, multiplied in ``lhs``'s dtype with float32 accumulation)
+    -> ``[rows, N]``, over the first ``tiles[0]`` tiles: the rows of the
+    result past them are not written, and read as anything. The gradient
+    of ``rhs`` is float32: one expert's rows are whole tiles that follow
+    each other, and every expert has one at least among those in use."""
+    return _pallas_gmm(lhs, rhs.astype(lhs.dtype), tile_expert, tiles,
+                       transpose_rhs=False, interpret=interpret)
+
+
+def _grouped_matmul_fwd(lhs, rhs, tile_expert, tiles, interpret):
+    return grouped_matmul(lhs, rhs, tile_expert, tiles, interpret), (
+        lhs, rhs, tile_expert, tiles)
+
+
+@jax.named_scope("hvd.moe_experts")
+def _grouped_matmul_bwd(interpret, res, g):
+    lhs, rhs, tile_expert, tiles = res
+    g = g.astype(lhs.dtype)
+    dlhs = _pallas_gmm(g, rhs.astype(lhs.dtype), tile_expert, tiles,
+                       transpose_rhs=True, interpret=interpret)
+    drhs = _pallas_tgmm(lhs, g, tile_expert, tiles, rhs.shape[0],
+                        interpret=interpret)
+    return dlhs, drhs.astype(rhs.dtype), None, None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def routed_experts(x, router, gate, up, down, *, top_k: int, first: int = 0,
+                   select=None, dtype=None,
+                   interpret: Optional[bool] = None):
+    """One routed-expert layer without dropped tokens, for the experts held
+    here: ``x`` ``[T, D]``; ``router`` ``[D, E]`` over all ``E`` routed
+    experts; ``gate``, ``up`` ``[count, D, F]`` and ``down`` ``[count, F,
+    D]``, the SwiGLU experts ``first … first + count - 1``. Returns ``(y,
+    local)``: ``y`` ``[T, D]``, each token's ``sum_j w_j expert_j(x)`` over
+    its ``top_k`` experts that are held here (weights normalised over all
+    ``top_k``; what the others would add is another holder's to compute),
+    and ``local``, a float32 scalar, the assignments that landed here.
+    ``select`` (``probabilities [T, E] -> scores [T, E]``) puts another
+    choice of experts in the router's place, their weights still the
+    router's (:func:`route_top_k`): a measurement hands in scores that
+    spread the tokens evenly where the router is untrained, as a block is
+    handed its ``attention_fn``.
+
+    The router runs in float32 (``highest`` precision); the assignments to
+    held experts are sorted by expert into a buffer of static shape that
+    holds every one of them whatever the router does
+    (:func:`buffer_rows`), three grouped matrix products run over its
+    tiles in use in ``dtype`` (Pallas kernels: a row tile times its
+    expert's matrix), and the weighted rows are summed back per token, by
+    gathers both ways. The buffer's rows past the tiles in use are never
+    written and never read back: nothing is dropped, and the products' work
+    follows the rows the router sent here.
+
+    One chip, no exchange: the caller's tokens are all the tokens.
+    ``interpret`` defaults to running the kernels interpreted off TPU."""
+    tokens, count = x.shape[0], gate.shape[0]
+    dtype = dtype or x.dtype
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if _metrics.enabled():
+        _metrics.gauge(
+            "moe_rows_budget",
+            help="rows of a routed layer's sorted buffer, fixed at trace "
+                 "time: every assignment held here and a tile of padding "
+                 "an expert").set(buffer_rows(tokens, top_k, count))
+
+    with jax.named_scope("hvd.moe_route"):
+        weights, experts = route_top_k(x, router, top_k, select)
+        plan = _plan(experts, first=first, count=count)
+        slot_of_row, row_of_slot = plan["slot_of_row"], plan["row_of_slot"]
+        xs = _to_rows(x.astype(dtype), slot_of_row, row_of_slot, top_k)
+        w_rows = _to_rows(weights.reshape(-1, 1), slot_of_row, row_of_slot, 1)
+    with jax.named_scope("hvd.moe_experts"):
+        groups = (plan["tile_expert"], plan["tiles"], interpret)
+        act = (jax.nn.silu(grouped_matmul(xs, gate, *groups))
+               * grouped_matmul(xs, up, *groups))
+        ys = grouped_matmul(act, down, *groups)
+    with jax.named_scope("hvd.moe_route"):
+        # a padding row (every row past the tiles in use is one) holds
+        # whatever the products left there: chosen away, never multiplied
+        real = (slot_of_row < row_of_slot.shape[0])[:, None]
+        ys = (jnp.where(real, ys.astype(jnp.float32), 0) * w_rows).astype(
+            dtype)
+        y = _to_tokens(ys, slot_of_row, row_of_slot, top_k)
+    return y, plan["local"].astype(jnp.float32)
+
+
+def record_rows(batch_stats) -> float:
+    """Sum the routed blocks' ``moe_rows`` counters of a step's
+    ``batch_stats`` (``models.TransformerLM`` keeps each routed block's
+    last ``local`` there) and set the gauge ``moe_local_rows``. It reads
+    the device: call it where the loop already waits for a step (a logging
+    interval, after the checked steps), not every step."""
+    leaves = [v for path, v in jax.tree_util.tree_leaves_with_path(
+        batch_stats) if getattr(path[-1], "key", None) == "moe_rows"]
+    total = float(jax.device_get(sum(leaves))) if leaves else 0.0
+    if _metrics.enabled():
+        _metrics.gauge(
+            "moe_local_rows",
+            help="assignments to experts held here in the last step read, "
+                 "summed over the routed layers: what the grouped "
+                 "products' time follows").set(total)
+    return total

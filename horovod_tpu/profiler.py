@@ -40,7 +40,23 @@ Device scopes, as they read in an instruction's ``op_name``:
 - ``hvd.allreduce``, ``hvd.allgather``, … (+ ``/<name>`` where the caller
   gave ``name=``) — an in-jit ``hvd.<collective>``, a user's own included
 - ``hvd.flash_fwd`` / ``hvd.flash_bwd`` — flash attention's two halves
-- ``hvd_<kernel>`` — one ``pallas_call`` (also the Mosaic ``kernel_name``)
+- ``hvd.moe_route`` — a routed-expert layer's router, top-k, sort and the
+  gathers into the sorted buffer and back; ``hvd.moe_experts`` — its grouped
+  matrix products and the activation between them. Both cover the forward
+  and, in or under a ``transpose(...)`` component, the backward
+- ``hvd_<kernel>`` — one ``pallas_call`` (also the Mosaic ``kernel_name``):
+  ``hvd_flash_fwd``; ``hvd_moe_gmm`` (a row tile of the sorted buffer times
+  its expert's matrix, or its transpose) and ``hvd_moe_tgmm`` (an expert's
+  weight gradient) inside ``hvd.moe_experts``, keyed apart from it by
+  :func:`scope_of`; the quantisation and optimizer kernels of
+  ``ops/pallas_kernels.py``
+
+Trace-time gauges say what a trace chose: ``flash_fwd_tile`` /
+``flash_bwd_tile`` (+ ``*_grid_steps``), ``moe_rows_budget`` (rows of a
+routed layer's sorted buffer: the worst case). ``moe_local_rows`` is a
+step's counter (the assignments that landed on the experts held here: what
+the grouped products' time follows), set by ``parallel.moe.record_rows``
+from the step's ``batch_stats``.
 
 Host spans: ``hvd.step`` (``InstrumentedStep.__call__``, a step marker
 carrying ``step_num``), ``hvd.step/dispatch`` (the wrapped step call inside
@@ -179,11 +195,11 @@ def scope_of(op_name: str, kind: str = ""):
     (``hvd.forward`` in or under a ``transpose(...)`` component: the
     transposed pass, and what ``jax.checkpoint`` recomputes during it),
     ``"forward"`` (any other ``hvd.forward``), else ``None``. ``kernel`` is
-    the innermost ``hvd.flash_*`` / ``hvd_<kernel>`` component, else
-    ``None``."""
+    the innermost ``hvd.flash_*`` / ``hvd.moe_*`` / ``hvd_<kernel>``
+    component, else ``None``."""
     parts = _components(op_name)
     kernel = next((p for p in reversed(parts)
-                   if p.startswith(("hvd.flash_", "hvd_"))), None)
+                   if p.startswith(("hvd.flash_", "hvd.moe_", "hvd_"))), None)
     if kind.startswith(_COLLECTIVE_KINDS) or \
             any("hvd.sync" in p for p in parts):
         return "sync", kernel

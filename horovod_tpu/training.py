@@ -1027,6 +1027,11 @@ def make_transformer_pp_train_step(
         pipeline_apply, pipeline_apply_interleaved,
     )
 
+    if getattr(model, "layers", None) is not None or model.norm != "layernorm":
+        raise ValueError(
+            "make_transformer_pp_train_step builds LayerNorm blocks that "
+            "are all alike (learned positions): a TransformerLM with a "
+            "per-layer description or RMSNorm has no pipeline builder yet")
     mesh = basics.mesh()
     ax = axis or PIPELINE_AXIS
     n_stages = mesh.shape[ax]

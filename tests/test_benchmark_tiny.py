@@ -10,7 +10,8 @@ four virtual devices and this suite's for eight; a cell takes
 import pytest
 
 pytest.register_assert_rewrite("benchmarks.tests.test_check",
-                               "benchmarks.tests.test_correct")
+                               "benchmarks.tests.test_correct",
+                               "benchmarks.tests.test_mellum")
 
 from benchmarks.tests.test_check import (  # noqa: E402,F401
     test_a_second_four_chip_cell_needs_eight_cells,
@@ -24,6 +25,11 @@ from benchmarks.tests.test_correct import (  # noqa: E402,F401
     test_broken_timed_path_is_not_correct,
     test_control_is_not_correct,
     test_unbroken_run_is_correct,
+)
+from benchmarks.tests.test_mellum import (  # noqa: E402,F401
+    test_broken_mellum_timed_path_is_not_correct,
+    test_mellum_control_is_not_correct,
+    test_unbroken_mellum_run_is_correct,
 )
 
 

@@ -1,0 +1,554 @@
+"""A block chosen per layer (``TransformerLM(layers=...)``: window and full
+attention, YaRN, routed experts, RMSNorm) against the benchmark's plain
+reference of the ``mellum`` family on seeded weights, at a small size
+(hidden 64, four layers sliding x3 + full with a window of 8 at T 32,
+8 experts top-2): the loss, every gradient leaf, three AdamW steps through
+``make_jit_train_step`` + ``DistributedOptimizer``; the four shares of the
+deployment add up to the uncut layer; the routed layer against a dense
+per-expert loop under the most uneven routing there is; and what the older
+entry points do with a kind of block they do not handle.
+"""
+
+import functools
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks import common, compare
+from benchmarks.reference import steps as ref_steps
+from horovod_tpu import models
+from horovod_tpu.models.transformer import TransformerBlock
+from horovod_tpu.observability import metrics
+from horovod_tpu.parallel import moe
+
+OPT = {"name": "adamw", "lr": 3e-4, "b1": 0.9, "b2": 0.999, "eps": 1e-8,
+       "weight_decay": 1e-4}
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    with open(os.path.join(common.BENCH_DIR, "tests",
+                           "tiny_mellum.json")) as f:
+        return dict(json.load(f), compute_dtype="float32")
+
+
+@pytest.fixture(scope="module")
+def ref():
+    return common.load_module("reference", "mellum")
+
+
+@pytest.fixture(scope="module")
+def adapter():
+    return common.load_module("adapters", "mellum")
+
+
+@pytest.fixture()
+def highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def _batches(n, rows=2, t=32, seed=0):
+    rng = np.random.default_rng(seed)
+    return [(rng.integers(0, 256, (rows, t)).astype(np.int32),
+             rng.integers(0, 256, (rows, t)).astype(np.int32))
+            for _ in range(n)]
+
+
+SELECTIONS = ["top_k", "forced_uniform"]
+
+
+@pytest.mark.parametrize("selection", SELECTIONS)
+def test_loss_and_every_gradient_leaf(cfg, ref, adapter, highest, selection):
+    cfg = dict(cfg, router_selection=selection)
+    built = adapter.build(cfg, {"optimizer": OPT})
+    weights = ref.make_weights(cfg, common.split_seed(5))
+    (tokens, targets), = _batches(1)
+    want_loss, want = ref.loss_and_grads(cfg, weights, tokens, targets)
+
+    def loss(params):
+        logits, _ = built["model"].apply(
+            {"params": params, "batch_stats": built["batch_stats"]}, tokens,
+            mutable=["batch_stats"])
+        return built["loss_fn"](logits, targets)
+
+    got_loss, got = jax.value_and_grad(loss)(built["to_tree"](weights))
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-5)
+    got = built["ref_names"](got, list(weights))
+    assert set(got) == set(want)
+    for name in want:
+        scale = float(jnp.abs(want[name]).max())
+        np.testing.assert_allclose(got[name], want[name], rtol=0,
+                                   atol=2e-5 * scale, err_msg=name)
+
+
+@pytest.mark.parametrize("selection", SELECTIONS)
+def test_three_adamw_steps_through_the_jit_builder(hvd, cfg, ref, adapter,
+                                                   highest, selection):
+    from horovod_tpu import training
+
+    cfg = dict(cfg, router_selection=selection)
+    built = adapter.build(cfg, {"optimizer": OPT})
+    halves = common.split_seed(11)
+    pool = _batches(3, rows=8)
+    tx = hvd.DistributedOptimizer(built["tx"])
+    step = training.make_jit_train_step(built["model"], tx,
+                                        loss_fn=built["loss_fn"])
+    weights = ref.make_weights(cfg, halves)
+    params = training.replicate(built["to_tree"](weights))
+    stats = training.replicate(built["batch_stats"])
+    opt_state = training.replicate(tx.init(params))
+    losses = []
+    for tokens, targets in pool:
+        params, stats, opt_state, loss = step(
+            params, stats, opt_state, training.shard_batch(tokens),
+            training.shard_batch(targets))
+        losses.append(float(loss))
+    reference = ref_steps.first_steps(
+        ref, cfg, {"optimizer": OPT, "reference": {"rows_per_block": 1}},
+        halves, pool)
+    np.testing.assert_allclose(losses, reference["losses"], rtol=1e-5)
+    got = ref_steps.to_floats(ref_steps.diff_norms(
+        built["ref_names"](params, list(weights)),
+        ref.make_weights(cfg, halves)))
+    for gap, leaf, *_ in compare.leaf_gaps(got, reference["update_norms"]):
+        assert gap < 2e-3, (leaf, gap)
+    # the routed blocks' counters came through the builder's state: 8 rows
+    # of 32 tokens, top-2 over 4 of 8 experts
+    rows = moe.record_rows(stats)
+    assert 0 < rows < 4 * 8 * 32 * 2
+    if selection == "forced_uniform":
+        # what the scores choose, whatever the router has become
+        want = sum(int((jax.lax.top_k(ref.forced_scores(i, 0, 8 * 32, 8),
+                                      2)[1] < 4).sum()) for i in range(4))
+        assert rows == want
+    assert metrics.value("moe_local_rows") == rows
+    assert metrics.value("moe_rows_budget") == moe.buffer_rows(8 * 32, 2, 4)
+
+
+# ----------------------------------------------------- the four shares add up
+
+
+def _share_cfg(cfg, share):
+    return dict(cfg, first_expert=share * 2, num_experts=2)
+
+
+def _uncut(cfg):
+    """The tiny configuration with every head and expert: four times the
+    share's heads, all 8 experts."""
+    return dict(cfg, num_attention_heads=4 * cfg["num_attention_heads"],
+                num_key_value_heads=4 * cfg["num_key_value_heads"],
+                num_experts=8, first_expert=0)
+
+
+def _share_of(full, cfg, share):
+    """Share ``share`` of 4 of one uncut layer's weights: its query heads
+    and their KV head, its experts; the router whole."""
+    hd = cfg["head_dim"]
+    q = slice(share * cfg["num_attention_heads"] * hd,
+              (share + 1) * cfg["num_attention_heads"] * hd)
+    kv = slice(share * cfg["num_key_value_heads"] * hd,
+               (share + 1) * cfg["num_key_value_heads"] * hd)
+    e = slice(share * 2, share * 2 + 2)
+    return dict(full, wq=full["wq"][:, q], wk=full["wk"][:, kv],
+                wv=full["wv"][:, kv], wo=full["wo"][q],
+                wg=full["wg"][e], wu=full["wu"][e], wd=full["wd"][e])
+
+
+@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
+def test_the_four_shares_add_up_to_the_uncut_layer(cfg, ref, adapter, kind,
+                                                   highest):
+    """Heads split 4 ways each add their part of the attention projection,
+    experts split 4 ways their part of the routed sum: the program's parts
+    over shares 0-3 sum to what the reference's uncut layer computes."""
+    uncut = _uncut(cfg)
+    weights = ref.make_weights(uncut, common.split_seed(3))
+    full = {k: weights[f"l0.{k}"] for k in ref._LAYER_KEYS}
+    x = jax.random.normal(jax.random.PRNGKey(1), (32, cfg["hidden_size"]))
+    ropes = ref.rope_tables(cfg["rope_parameters"][kind], cfg["head_dim"],
+                            32)
+    window = cfg["sliding_window"] if kind == "sliding_attention" else 32
+    mm = ref.MATMULS["float32"]
+
+    def ref_block(w, c):
+        return ref._block(x, w, *ropes, window, 0, 0, cfg=c, mm=mm)
+
+    silent = dict(full, wd=jnp.zeros_like(full["wd"]))
+    want_attention = ref_block(silent, uncut) - x
+    h = ref._rms_norm(x, full["g2"], cfg["rms_norm_eps"])
+    want_experts = ref._experts(h, full, 0, 0, cfg=uncut, mm=mm)
+    np.testing.assert_allclose(
+        ref_block(full, uncut) - x - want_attention,
+        ref._experts(ref._rms_norm(x + want_attention, full["g2"],
+                                   cfg["rms_norm_eps"]),
+                     full, 0, 0, cfg=uncut, mm=mm), atol=1e-6)
+
+    got_attention, got_experts, landed = 0.0, 0.0, 0.0
+    for share in range(4):
+        mine = _share_of(full, cfg, share)
+        share_cfg = dict(_share_cfg(cfg, share), layer_types=[kind],
+                         mlp_layer_types=["sparse"], num_layers=1)
+        layer, = adapter.layers(share_cfg)
+        block = TransformerBlock(**models.TransformerLM(
+            vocab=8, dim=cfg["hidden_size"], depth=1, heads=1,
+            layers=(layer,), norm="rmsnorm", pos_embedding="rope",
+            dtype=jnp.float32).block_config(0))
+        tree = adapter.to_tree(
+            {f"l0.{k}": v for k, v in mine.items()})["block0"]
+        silent = dict(tree, experts_down=jnp.zeros_like(tree["experts_down"]))
+        out = block.apply({"params": silent}, x[None],
+                          positions=jnp.arange(32)[None])
+        got_attention = got_attention + (out[0] - x)
+        part, local = moe.routed_experts(
+            h, mine["wr"], mine["wg"], mine["wu"], mine["wd"], top_k=2,
+            first=share * 2)
+        landed += float(local)
+        got_experts = got_experts + part
+    # every one of the 32 tokens' two assignments landed on one share
+    assert landed == 32 * 2
+    np.testing.assert_allclose(got_attention, want_attention, atol=2e-6)
+    np.testing.assert_allclose(got_experts, want_experts, atol=2e-6)
+
+
+# ------------------------------------------------------------ the routed layer
+
+
+def _dense_experts(x, router, gate, up, down, top_k, first, select=None):
+    weights, chosen = moe.route_top_k(x, router, top_k, select)
+    y = jnp.zeros_like(x)
+    for e in range(gate.shape[0]):
+        mine = jnp.sum(jnp.where(chosen == first + e, weights, 0.0), axis=-1)
+        y = y + mine[:, None] * (
+            (jax.nn.silu(x @ gate[e]) * (x @ up[e])) @ down[e])
+    return y
+
+
+def _routed_inputs(tokens=96, dim=64, width=32, count=4, routed=8, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return (jax.random.normal(ks[0], (tokens, dim)),
+            jax.random.normal(ks[1], (dim, routed)) * 0.5,
+            jax.random.normal(ks[2], (count, dim, width)) * 0.1,
+            jax.random.normal(ks[3], (count, dim, width)) * 0.1,
+            jax.random.normal(ks[4], (count, width, dim)) * 0.1)
+
+
+@pytest.mark.parametrize("first,count", [(0, 8), (0, 4), (4, 4), (2, 3)])
+def test_routed_layer_matches_a_dense_per_expert_loop(first, count, highest):
+    args = _routed_inputs(count=count)
+    w = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+
+    def loss(fn, *a):
+        return jnp.sum(fn(*a) * w)
+
+    routed = lambda *a: moe.routed_experts(*a, top_k=2, first=first)[0]
+    dense = functools.partial(_dense_experts, top_k=2, first=first)
+    got = jax.value_and_grad(functools.partial(loss, routed),
+                             argnums=range(5))(*args)
+    want = jax.value_and_grad(functools.partial(loss, dense),
+                              argnums=range(5))(*args)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.abs(b).max()))
+
+
+def test_select_chooses_and_the_router_weighs(highest):
+    """Scores handed in choose a token's experts; its weights are the
+    router's probabilities of those experts over their sum, and the router
+    has its gradient."""
+    args = _routed_inputs(count=4)
+    x, router = args[:2]
+    scores = jax.random.uniform(jax.random.PRNGKey(3), (x.shape[0], 8))
+    select = lambda probs: scores
+    weights, chosen = moe.route_top_k(x, router, 2, select)
+    np.testing.assert_array_equal(chosen, jax.lax.top_k(scores, 2)[1])
+    probs = jnp.take_along_axis(jax.nn.softmax(x @ router, axis=-1), chosen,
+                                axis=-1)
+    np.testing.assert_allclose(
+        weights, probs / probs.sum(-1, keepdims=True), rtol=1e-5)
+
+    w = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+    routed = lambda *a: jnp.sum(w * moe.routed_experts(
+        *a, top_k=2, select=select)[0])
+    dense = lambda *a: jnp.sum(w * _dense_experts(*a, 2, 0, select))
+    got = jax.value_and_grad(routed, argnums=range(5))(*args)
+    want = jax.value_and_grad(dense, argnums=range(5))(*args)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.abs(b).max()))
+    assert float(jnp.abs(got[1][1]).max()) > 0
+    local = moe.routed_experts(*args, top_k=2, select=select)[1]
+    assert float(local) == float((chosen < 4).sum())
+
+
+def test_weights_stay_finite_where_the_chosen_probabilities_vanish(highest):
+    """A router that has collapsed onto experts a token was not sent to:
+    the chosen experts' probabilities round to nothing in float32, their
+    weights and the gradient do not."""
+    x, router, gate, up, down = _routed_inputs(count=8)
+    x = jnp.abs(x)
+    router = router.at[:, 0].set(40.0)            # logits of 1,000 and more
+    select = lambda probs: jnp.arange(8.0)[None, :] + 0 * probs  # 7 and 6
+    weights, chosen = moe.route_top_k(x, router, 2, select)
+    assert bool(jnp.all(chosen == jnp.array([7, 6])))
+    probs = jax.nn.softmax(x @ router, axis=-1)
+    assert float(probs[:, 6:].max()) == 0.0
+    np.testing.assert_allclose(weights.sum(-1), 1.0, rtol=1e-6)
+    grads = jax.grad(lambda *a: jnp.sum(moe.routed_experts(
+        *a, top_k=2, select=select)[0]), argnums=range(5))(
+            x, router, gate, up, down)
+    assert all(bool(jnp.all(jnp.isfinite(g))) for g in grads)
+
+
+@pytest.mark.parametrize("layer", [0, 3])
+def test_forced_scores_spread_the_tokens(ref, layer):
+    """The scores a timed cell chooses by: every expert gets its share of a
+    step's assignments within a fifth, and a token's scores are its own
+    whatever the step holds beside it."""
+    scores = ref.forced_scores(layer, 0, 2048, 64)
+    chosen = np.asarray(jax.lax.top_k(scores, 8)[1])
+    assert all(len(set(row)) == 8 for row in chosen[:64])
+    counts = np.bincount(chosen.ravel(), minlength=64)
+    assert counts.min() > 0.8 * 256 and counts.max() < 1.2 * 256
+    np.testing.assert_array_equal(scores[512:640],
+                                  ref.forced_scores(layer, 512, 128, 64))
+    assert not np.array_equal(scores, ref.forced_scores(layer + 1, 0, 2048,
+                                                        64))
+
+
+def _routed_grads(x, router, gate, up, down, **kw):
+    def loss(*a):
+        return jnp.sum(moe.routed_experts(*a, top_k=2, **kw)[0])
+
+    return jax.grad(loss, argnums=range(5))(x, router, gate, up, down)
+
+
+def test_every_token_to_one_expert_drops_nothing(highest):
+    """The most uneven routing: the router sends every token's first
+    choice to expert 0. The buffer holds the worst case: every assignment
+    has its row."""
+    x, router, gate, up, down = _routed_inputs(count=8)
+    x = jnp.abs(x)
+    router = router.at[:, 0].set(10.0)
+    weights, chosen = moe.route_top_k(x, router, 2)
+    assert bool(jnp.all(chosen[:, 0] == 0)) and float(weights[:, 0].min()) > 0.99
+    y, local = moe.routed_experts(x, router, gate, up, down, top_k=2)
+    assert float(local) == 2.0 * x.shape[0]
+    np.testing.assert_allclose(
+        y, _dense_experts(x, router, gate, up, down, 2, 0), atol=1e-5)
+
+
+def test_no_assignment_here_adds_nothing(highest):
+    """The other end: no token chooses an expert held here. Each held
+    expert keeps its one tile, all padding; the rows the products never
+    wrote (interpreted, they read NaN) reach neither the result nor any
+    gradient, and the held matrices' gradients are written, as zeros."""
+    x, router, gate, up, down = _routed_inputs(count=4)
+    x = jnp.abs(x)
+    router = router.at[:, :2].set(10.0)
+    y, local = moe.routed_experts(x, router, gate, up, down, top_k=2,
+                                  first=4)
+    assert float(local) == 0.0 and not np.any(np.asarray(y))
+    grads = _routed_grads(x, router, gate, up, down, first=4)
+    for g in grads:
+        assert not np.any(np.asarray(g))
+
+
+@pytest.mark.parametrize("tokens", [64, 600])
+def test_the_products_pass_over_tiles_no_row_fills(tokens, highest):
+    """The grouped products' work follows the rows the router sent here:
+    ``tiles`` counts each held expert's whole tiles (one at least), the
+    runs fill the buffer's first ``tiles`` tiles, and a product leaves the
+    rows past them as they were allocated."""
+    x, router, gate, up, down = _routed_inputs(tokens=tokens, count=4)
+    _, chosen = moe.route_top_k(x, router, 2)
+    plan = moe._plan(chosen, first=2, count=4)
+    sizes = np.bincount(np.asarray(chosen).ravel(), minlength=8)[2:6]
+    tiles = int(np.maximum(-(-sizes // moe.TILE_ROWS), 1).sum())
+    assert plan["tiles"].tolist() == [tiles]
+    assert int(plan["local"]) == sizes.sum()
+    rows = moe.buffer_rows(tokens, 2, 4)
+    assert plan["slot_of_row"].shape == (rows,) and tiles < rows // moe.TILE_ROWS
+    in_use = np.asarray(plan["slot_of_row"]) < tokens * 2
+    assert in_use.sum() == sizes.sum()
+    assert not in_use[tiles * moe.TILE_ROWS:].any()
+    xs = jnp.ones((rows, x.shape[1]), jnp.float32)
+    out = np.asarray(moe.grouped_matmul(xs, gate, plan["tile_expert"],
+                                        plan["tiles"], True))
+    assert np.isfinite(out[:tiles * moe.TILE_ROWS]).all()
+    assert np.isnan(out[tiles * moe.TILE_ROWS:]).all()
+
+
+def test_buffer_rows():
+    # the benchmark's layer: 8192 tokens at top-8, 16 experts held: every
+    # assignment and a tile of padding an expert
+    assert moe.buffer_rows(8192, 8, 16) == 65536 + 16 * 256
+    assert moe.buffer_rows(64, 2, 8) == (1 + 8) * 256
+
+
+# ------------------------------------- scopes and names, in the TPU lowering
+
+
+def test_routed_layer_lowers_for_tpu_under_its_scopes():
+    """The grouped products are Mosaic calls ``hvd_moe_gmm`` (forward and
+    the input's gradient) and ``hvd_moe_tgmm`` (the matrices' gradients)
+    under ``hvd.moe_experts``, the gathers are under ``hvd.moe_route``, in
+    the forward and in the backward: what the benchmark's ``moe_*`` readers
+    key on through ``profiler.scope_of``."""
+    import re
+
+    from horovod_tpu import profiler
+
+    S = jax.ShapeDtypeStruct
+    args = (S((512, 256), jnp.bfloat16), S((256, 8), jnp.float32),
+            S((4, 256, 128), jnp.float32), S((4, 256, 128), jnp.float32),
+            S((4, 128, 256), jnp.float32))
+
+    @jax.named_scope("hvd.forward")
+    def loss(*a):
+        y, _ = moe.routed_experts(*a, top_k=2, interpret=False)
+        return y.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=range(5))).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert sorted(re.findall(r'kernel_name = "([^"]*)"', text)) == (
+        ["hvd_moe_gmm"] * 6 + ["hvd_moe_tgmm"] * 3)
+    calls = re.findall(r'loc\("([^"]*/pallas_call)"', text)
+    assert calls and all("hvd.moe_experts" in c for c in calls), calls
+    kinds = {profiler.scope_of(c, "custom-call") for c in calls}
+    assert kinds == {("forward", "hvd_moe_gmm"), ("backward", "hvd_moe_gmm"),
+                     ("backward", "hvd_moe_tgmm")}
+    gathers = [n for n in re.findall(r'loc\("([^"]*)"', text)
+               if n.endswith("/gather")]
+    assert any(profiler.scope_of(n) == ("forward", "hvd.moe_route")
+               for n in gathers)
+    assert any(profiler.scope_of(n) == ("backward", "hvd.moe_route")
+               for n in gathers)
+    assert "stablehlo.scatter" not in text
+
+
+def test_scope_of_names_the_routed_scopes():
+    from horovod_tpu.profiler import scope_of
+
+    assert scope_of("jit(s)/jvp(hvd.forward)/block0/hvd.moe_route/sort") == (
+        "forward", "hvd.moe_route")
+    assert scope_of("jit(s)/transpose(jvp(hvd.forward))/block0/"
+                    "hvd.moe_experts/mul") == ("backward", "hvd.moe_experts")
+    assert scope_of("jit(s)/jvp(hvd.forward)/block0/hvd.moe_experts/"
+                    "hvd_moe_gmm/pallas_call") == ("forward", "hvd_moe_gmm")
+
+
+# ------------------------------ what the older entry points do with such blocks
+
+
+def _tiny_lm(**kw):
+    layer = models.Layer(heads=4, head_dim=16, kv_heads=1, **kw)
+    return models.TransformerLM(
+        vocab=64, dim=64, depth=2, heads=4, layers=(layer, layer),
+        norm="rmsnorm", pos_embedding="rope", max_len=64, dtype=jnp.float32)
+
+
+def test_param_tree_of_a_described_block():
+    model = _tiny_lm(window=8, ffn=models.Experts(routed=8, top_k=2,
+                                                  width=32, count=4))
+    variables = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 16), "int32"))
+    block = variables["params"]["block0"]
+    assert set(block) == {"ln1", "q_proj", "k_proj", "v_proj", "proj", "ln2",
+                          "router", "experts_gate", "experts_up",
+                          "experts_down"}
+    assert set(block["ln1"]) == {"scale"}
+    assert block["q_proj"]["kernel"].shape == (64, 64)
+    assert block["k_proj"]["kernel"].shape == (64, 16)
+    assert block["router"].shape == (64, 8)
+    assert block["experts_gate"].shape == (4, 64, 32)
+    assert "pos_embed" not in variables["params"]
+    assert variables["batch_stats"]["block1"]["moe_rows"].shape == ()
+
+
+def test_the_default_blocks_are_what_they_were():
+    """No ``layers``: the parameter tree of ``depth`` blocks alike, names
+    and shapes as ever (the GPT-2 adapter's model)."""
+    model = models.TransformerLM(vocab=64, dim=32, depth=1, heads=4,
+                                 max_len=16, dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), "int32"))["params"]
+    assert set(params) == {"tok_embed", "pos_embed", "block0", "ln_f",
+                           "lm_head"}
+    assert set(params["block0"]) == {"ln1", "qkv", "proj", "ln2", "mlp_up",
+                                     "mlp_down"}
+    assert set(params["block0"]["ln1"]) == {"scale", "bias"}
+
+
+def test_layers_must_match_depth_and_rotary():
+    layer = models.Layer(heads=4, head_dim=16)
+    tokens = jnp.zeros((1, 8), "int32")
+    for kw in (dict(depth=3, pos_embedding="rope"),
+               dict(depth=2, pos_embedding="learned")):
+        model = models.TransformerLM(vocab=64, dim=64, heads=4,
+                                     layers=(layer, layer), **kw)
+        with pytest.raises(ValueError, match="layers describes 2 blocks"):
+            model.init(jax.random.PRNGKey(0), tokens)
+
+
+def test_a_described_mlp_block_generates():
+    """A described block with full attention and an MLP decodes through the
+    kv cache as a plain block does: generate() against a full-forward
+    rollout."""
+    model = _tiny_lm(ffn=2)
+    prompt = jnp.arange(6)[None] % 64
+    params = model.init(jax.random.PRNGKey(0), prompt)["params"]
+    out = models.generate(model, params, prompt, max_new_tokens=4)
+    tokens = prompt
+    for _ in range(4):
+        nxt = jnp.argmax(model.apply({"params": params}, tokens)[:, -1], -1)
+        tokens = jnp.concatenate([tokens, nxt[:, None]], axis=1)
+    np.testing.assert_array_equal(out, tokens)
+
+
+@pytest.mark.parametrize("kw", [
+    dict(window=8), dict(ffn=models.Experts(routed=8, top_k=2, width=32))])
+def test_kinds_the_kv_cache_does_not_handle_raise(kw):
+    model = _tiny_lm(**kw)
+    prompt = jnp.zeros((1, 4), "int32")
+    variables = model.init(jax.random.PRNGKey(0), prompt)
+    with pytest.raises(NotImplementedError, match="kv-cache decoding"):
+        models.generate(model, variables["params"], prompt, max_new_tokens=2)
+
+
+def test_param_specs_and_tp_block_refuse_routed_blocks():
+    model = _tiny_lm(ffn=models.Experts(routed=8, top_k=2, width=32))
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 4), "int32"))["params"]
+    with pytest.raises(ValueError, match="routed-expert block"):
+        models.transformer_param_specs(params)
+    from horovod_tpu.models.transformer import tp_block_apply
+
+    with pytest.raises(ValueError, match="fused qkv|LayerNorm \\+ MLP"):
+        tp_block_apply(params["block0"], jnp.zeros((1, 4, 64)), heads=4)
+    # separate k and v projections split by column, as q does
+    mlp = _tiny_lm(ffn=2)
+    specs = models.transformer_param_specs(mlp.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), "int32"))["params"])
+    assert specs["block0"]["k_proj"]["kernel"] == specs["block0"]["q_proj"][
+        "kernel"] != specs["block0"]["proj"]["kernel"]
+
+
+def test_yarn_matches_the_reference_tables(cfg, ref):
+    from horovod_tpu.models.transformer import apply_rope
+
+    rope = cfg["rope_parameters"]["full_attention"]
+    yarn = models.Yarn(
+        factor=rope["factor"],
+        original_max_len=rope["original_max_position_embeddings"],
+        beta_fast=rope["beta_fast"], beta_slow=rope["beta_slow"],
+        attention_factor=rope["attention_factor"])
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 32, 2, 16))
+    got = apply_rope(x, jnp.arange(32)[None], base=rope["rope_theta"],
+                     yarn=yarn)
+    want = ref._rope(x[0], *ref.rope_tables(rope, 16, 32))
+    np.testing.assert_allclose(got[0], want, atol=1e-5)
+    plain = apply_rope(x, jnp.arange(32)[None], base=rope["rope_theta"])
+    assert not np.allclose(plain, got, atol=1e-3)

@@ -729,15 +729,15 @@ def test_pallas_backward_of_fully_masked_rows_is_zero(blocks):
     g = jnp.asarray(np.random.RandomState(6).randn(b, t, h, d), jnp.float32)
     sm_scale = d ** -0.5
     out, res = fa._flash_fwd(q, k, v, True, sm_scale,
-                             (None, None, False, False))
+                             (None, None, False, False), None)
     masked = np.zeros((t,), bool)
     masked[:8] = masked[150:160] = True
     out = jnp.where(masked[None, :, None, None], 0.0, out)
     lse = jnp.where(masked[None, None, :], fa.LSE_MASKED, res[4])
     res = (q, k, v, out, lse)
     bwd = functools.partial(fa._flash_bwd, True, sm_scale)
-    got = bwd((blocks, blocks, True, True), res, g)
-    want = bwd((blocks, blocks, False, False), res, g)
+    got = bwd((blocks, blocks, True, True), None, res, g)
+    want = bwd((blocks, blocks, False, False), None, res, g)
     assert all(np.isfinite(np.asarray(x)).all() for x in got)
     assert (np.asarray(got[0])[:, masked] == 0).all()
     assert np.abs(np.asarray(got[0])[:, ~masked]).min() > 0
