@@ -16,7 +16,8 @@ several a token, where the ``[T, E, C]`` one-hot would be the layer: top-k
 routing without dropped tokens. The assignments to the experts a chip holds
 are sorted by expert into a buffer of static shape that holds the worst
 case, three grouped matrix products run over its tiles in use as Pallas
-kernels, and the weighted rows are gathered back per token. The layer is told which experts it holds, routes over all
+kernels, and a fourth kernel sums the weighted rows back per token, reading
+the rows held here. The layer is told which experts it holds, routes over all
 of them and computes its own experts' part; it exchanges nothing, so it
 serves one chip (a model's share of a deployment), not yet the ``expert``
 axis.
@@ -182,6 +183,16 @@ TILE_ROWS = 256
 #: VMEM the grouped products ask Mosaic for: a row tile, one expert's matrix
 #: whole and the result tile, double-buffered
 _VMEM_LIMIT = 48 << 20
+#: tokens one grid step of the way back (:func:`_pallas_combine`) sums, and
+#: the rows of one block it reads of the sorted buffer for them: the rows a
+#: token tile has on one held expert follow each other there, 32 on average
+#: at 256 tokens and top-8 of 64, so one window a (tile, expert) as a rule
+TOKEN_TILE = 256
+WINDOW_ROWS = 64
+#: windows one product of the way back takes at once: the experts in groups
+#: of so many, a window each, ``[TOKEN_TILE, group x window]`` times
+#: ``[group x window, D]``
+_WINDOW_GROUP = 16
 
 
 def route_top_k(x, router, top_k: int, select=None):
@@ -210,8 +221,10 @@ def buffer_rows(tokens: int, top_k: int, count: int) -> int:
     a row. Nothing holds a router near balance (untrained, the chip read
     0 to 30,639 of 65,536 assignments on 16 of 64 experts, layer by layer
     and step by step, where balance sends 16,384). The grouped products
-    pass over the tiles no row fills; the gathers and the activation run
-    over the whole of it."""
+    pass over the tiles no row fills, and the way back to the tokens reads
+    the rows held here (:func:`_pallas_combine`); the gather into the
+    buffer (:func:`_to_rows`) and the activation between the products
+    still run over the whole of it."""
     return (-(-tokens * top_k // TILE_ROWS) + count) * TILE_ROWS
 
 
@@ -227,7 +240,10 @@ def _plan(experts, *, first: int, count: int):
     for a padding row), ``tile_expert`` ``[rows / TILE_ROWS]`` (a tile past
     the last expert's rows reads as the last expert's), ``tiles`` ``[1]``
     (the tiles in use: the experts' runs fill the buffer's first ``tiles``
-    tiles) and the counter ``local`` (slots held here)."""
+    tiles), ``seg_start`` and ``seg_rows`` ``[ceil(T / TOKEN_TILE), count]``
+    (the first buffer row and the number of rows that a tile of
+    ``TOKEN_TILE`` tokens has on each held expert: the sort is stable, so
+    they follow each other) and the counter ``local`` (slots held here)."""
     tokens, top_k = experts.shape
     slots = tokens * top_k
     rows = buffer_rows(tokens, top_k, count)
@@ -241,11 +257,20 @@ def _plan(experts, *, first: int, count: int):
     # held expert, and per tile, not per row: ``bincount`` is a scatter, and
     # a gather of scalars costs the chip 15 ns an element
     mine = key[:, None] == jnp.arange(count)[None, :]            # [slots, E]
-    sizes = jnp.sum(mine, axis=0, dtype=jnp.int32)
+    # by tile of TOKEN_TILE tokens first: the sort is stable, so the rows a
+    # token tile has on one expert are one run of that expert's rows
+    token_tiles = -(-tokens // TOKEN_TILE)
+    seg_rows = jnp.sum(
+        jnp.pad(mine, ((0, token_tiles * TOKEN_TILE * top_k - slots), (0, 0))
+                ).reshape(token_tiles, TOKEN_TILE * top_k, count),
+        axis=1, dtype=jnp.int32)                                 # [tiles, E]
+    sizes = jnp.sum(seg_rows, axis=0)
     starts = jnp.cumsum(sizes) - sizes
     tiles_of = jnp.maximum(-(-sizes // TILE_ROWS), 1)
     tile_ends = jnp.cumsum(tiles_of)
     row_starts = (tile_ends - tiles_of) * TILE_ROWS
+    seg_start = (row_starts[None, :] + jnp.cumsum(seg_rows, axis=0)
+                 - seg_rows).astype(jnp.int32)
     n_tiles = rows // TILE_ROWS
     tile_expert = jnp.minimum(
         jnp.searchsorted(tile_ends, jnp.arange(n_tiles), side="right",
@@ -264,38 +289,37 @@ def _plan(experts, *, first: int, count: int):
         rows).astype(jnp.int32)
     return {
         "row_of_slot": row_of_slot, "slot_of_row": slot_of_row,
+        "seg_start": seg_start, "seg_rows": seg_rows,
         "tile_expert": tile_expert,
         "tiles": tile_ends[-1:].astype(jnp.int32), "local": jnp.sum(sizes),
     }
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _to_rows(x, slot_of_row, row_of_slot, top_k: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _to_rows(x, plan, top_k: int, interpret: bool):
     """``x`` ``[T, ...]`` -> ``[rows, ...]``: each buffer row its slot's
-    token's row of ``x`` (token 0's for a padding row). Its transpose is a
-    gather too (:func:`_to_tokens`): a scatter-add of as many rows takes
-    ten times a gather's time on the chip."""
-    return x[jnp.minimum(slot_of_row // top_k, x.shape[0] - 1)]
+    token's row of ``x`` (token 0's for a padding row). ``plan`` is
+    :func:`_plan`'s. Its transpose is no scatter-add (:func:`_to_tokens`):
+    one of as many rows takes ten times a gather's time on the chip."""
+    return x[jnp.minimum(plan["slot_of_row"] // top_k, x.shape[0] - 1)]
 
 
-def _to_rows_fwd(x, slot_of_row, row_of_slot, top_k):
-    return _to_rows(x, slot_of_row, row_of_slot, top_k), (
-        slot_of_row, row_of_slot)
+def _to_rows_fwd(x, plan, top_k, interpret):
+    return _to_rows(x, plan, top_k, interpret), plan
 
 
 @jax.named_scope("hvd.moe_route")
-def _to_rows_bwd(top_k, res, g):
-    slot_of_row, row_of_slot = res
-    return _to_tokens(g, slot_of_row, row_of_slot, top_k), None, None
+def _to_rows_bwd(top_k, interpret, plan, g):
+    return _to_tokens(g, plan, top_k, interpret), None
 
 
 _to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _to_tokens(y, slot_of_row, row_of_slot, top_k: int):
-    """``y`` ``[rows, ...]`` -> ``[T, ...]``: each token the float32 sum of
-    its slots' buffer rows (a slot with no row adds nothing)."""
+def _gather_to_tokens(y, row_of_slot, top_k: int):
+    """:func:`_to_tokens` by a gather of every slot's row, the slots
+    without one clamped onto the buffer's last row and chosen away after:
+    ``T * top_k`` rows read whatever share of them is held here."""
     has_row = row_of_slot < y.shape[0]
     rows = y[jnp.minimum(row_of_slot, y.shape[0] - 1)]
     rows = jnp.where(has_row.reshape((-1,) + (1,) * (y.ndim - 1)),
@@ -304,18 +328,207 @@ def _to_tokens(y, slot_of_row, row_of_slot, top_k: int):
                    axis=1).astype(y.dtype)
 
 
-def _to_tokens_fwd(y, slot_of_row, row_of_slot, top_k):
-    return _to_tokens(y, slot_of_row, row_of_slot, top_k), (
-        slot_of_row, row_of_slot)
+def _combine_kernel(start_ref, size_ref, slot_rows_ref, y_ref, out_ref, buf,
+                    sem, acc, *, count: int, window: int, align: int):
+    """One tile of tokens: the float32 sum of each token's rows of the
+    sorted buffer ``y_ref`` (in HBM). The rows the tile has on held expert
+    ``e`` are ``size_ref[e]`` rows from ``start_ref[e]`` on; they are read
+    as windows of ``window`` rows from an aligned row, a group of experts'
+    windows at a time, and summed by one 0/1 product, ``P[t, r]`` being
+    "window row ``r`` is one of token ``t``'s rows". A window's rows that
+    are not the segment's may never have been written (or be another
+    tile's): they are zeroed before the product, where ``0 x NaN`` would
+    be NaN. A segment longer than a window takes further rounds. The first
+    windows of the next tile are copied while this one is summed (the grid
+    runs in order), into the other half of ``buf``. The experts are walked
+    by ``fori_loop``: sixteen unrolled bodies a call site, eight sites a
+    step, cost the step's set-up half a minute of tracing."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    tile, half = pl.program_id(0), pl.program_id(0) % 2
+    rows = y_ref.shape[0]
+    slot_rows = slot_rows_ref[...]                       # [tokens, top_k]
+
+    def segment(tile, e):
+        """Expert ``e``'s rows of the tile, and the aligned row its first
+        window starts on."""
+        start, size = start_ref[tile * count + e], size_ref[tile * count + e]
+        return start, size, start // align * align
+
+    def part(tile, e, w):
+        """Round ``w`` of expert ``e``: of its segment the rows ``[lo,
+        hi)`` (none: ``hi <= lo``), in the window read from row ``at``
+        (kept inside the buffer)."""
+        start, size, first = segment(tile, e)
+        first += w * window
+        return (jnp.maximum(start, first),
+                jnp.minimum(start + size, first + window),
+                jnp.minimum(first, rows - window))
+
+    def copy(at, half, k):
+        return pltpu.make_async_copy(
+            y_ref.at[pl.ds(pl.multiple_of(at, align), window), :],
+            buf.at[half, pl.ds(pl.multiple_of(k * window, window), window),
+                   :], sem.at[half, k])
+
+    def start(tile, group, held, w, half):
+        def one(k, carry):
+            lo, hi, at = part(tile, group + k, w)
+            pl.when(hi > lo)(copy(at, half, k).start)
+            return carry
+
+        lax.fori_loop(0, held, one, None)
+
+    first_held = min(_WINDOW_GROUP, count)
+    pl.when(tile == 0)(lambda: start(0, 0, first_held, 0, 0))
+    pl.when(tile + 1 < pl.num_programs(0))(
+        lambda: start(tile + 1, 0, first_held, 0, 1 - half))
+    acc[...] = jnp.zeros_like(acc)
+
+    for group in range(0, count, _WINDOW_GROUP):
+        held = min(_WINDOW_GROUP, count - group)
+        width = held * window
+
+        def one_round(w, carry, group=group, held=held, width=width):
+            # round 0 of the first group was started a tile ahead
+            pl.when((w > 0) | (group > 0))(
+                lambda: start(tile, group, held, w, half))
+            # the buffer row each column of the product's left side stands
+            # for, and the 0/1 matrix, while the copies run
+            col = lax.broadcasted_iota(jnp.int32, (1, width), 1)
+
+            def rows_of(k, row_of_col):
+                at = part(tile, group + k, w)[2]
+                here = (col >= k * window) & (col < (k + 1) * window)
+                return jnp.where(here, col + (at - k * window), row_of_col)
+
+            row_of_col = lax.fori_loop(0, held, rows_of, col)
+            chosen = slot_rows[:, 0:1] == row_of_col
+            for j in range(1, slot_rows.shape[1]):
+                chosen |= slot_rows[:, j:j + 1] == row_of_col
+
+            def land(k, carry):
+                lo, hi, at = part(tile, group + k, w)
+                pl.when(hi > lo)(copy(at, half, k).wait)
+                span = pl.ds(pl.multiple_of(k * window, window), window)
+                row = at + lax.broadcasted_iota(jnp.int32, (window, 1), 0)
+                buf[half, span, :] = jnp.where((row >= lo) & (row < hi),
+                                               buf[half, span, :], 0)
+                return carry
+
+            lax.fori_loop(0, held, land, None)
+            acc[...] += lax.dot_general(
+                chosen.astype(buf.dtype), buf[half, pl.ds(0, width), :],
+                (((1,), (0,)), ((), ())),
+                precision=(lax.Precision.HIGHEST
+                           if buf.dtype == jnp.float32 else None),
+                preferred_element_type=jnp.float32)
+            return carry
+
+        def windows(k, most, group=group):
+            start_, size, first = segment(tile, group + k)
+            return jnp.maximum(most, jnp.where(
+                size > 0, -(-(start_ + size - first) // window), 0))
+
+        lax.fori_loop(0, lax.fori_loop(0, held, windows, 0), one_round, None)
+
+    out_ref[...] = acc[...].astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("top_k", "interpret"))
+def _pallas_combine(y, plan, *, top_k: int, interpret: bool):
+    """:func:`_to_tokens` on a lane-wide ``[rows, D]`` operand as a Pallas
+    kernel (:func:`_combine_kernel`): it reads the rows held here, in
+    windows, and not a row for every slot. The call carries no ``name=``:
+    its device time is its scope's, ``hvd.moe_route``. Jitted, so that a
+    step's call sites (a layer's combine and its dispatch's transpose,
+    layer after layer) trace and lower the kernel once between them; each
+    keeps the ``op_name`` of its own scope."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, d = y.shape
+    token_tiles, count = plan["seg_start"].shape
+    tokens = plan["row_of_slot"].shape[0] // top_k
+    padded = token_tiles * TOKEN_TILE
+    slot_rows = jnp.pad(plan["row_of_slot"].reshape(tokens, top_k),
+                        ((0, padded - tokens), (0, 0)), constant_values=rows)
+    out = pl.pallas_call(
+        functools.partial(_combine_kernel, count=count, window=WINDOW_ROWS,
+                          align=32 // y.dtype.itemsize),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(token_tiles,),
+            in_specs=[
+                pl.BlockSpec((TOKEN_TILE, top_k), lambda i, s, n: (i, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((TOKEN_TILE, d), lambda i, s, n: (i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, min(count, _WINDOW_GROUP) * WINDOW_ROWS, d),
+                           y.dtype),
+                pltpu.SemaphoreType.DMA((2, min(count, _WINDOW_GROUP))),
+                pltpu.VMEM((TOKEN_TILE, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((padded, d), y.dtype),
+        # in order: a tile's first windows are copied during the one before
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(plan["seg_start"].reshape(-1), plan["seg_rows"].reshape(-1),
+      slot_rows, y)
+    return out[:tokens]
+
+
+def _combine_fits(d: int, itemsize: int, count: int) -> bool:
+    """Whether :func:`_combine_kernel` has room at rows of ``d`` elements:
+    both halves of a group's windows, the float32 sum and a product's
+    result, the block of the result twice and the 0/1 matrix."""
+    group = min(count, _WINDOW_GROUP) * WINDOW_ROWS
+    return (2 * group * d * itemsize + 2 * TOKEN_TILE * d * 4
+            + 2 * TOKEN_TILE * d * itemsize
+            + TOKEN_TILE * group * (4 + itemsize)) <= _VMEM_LIMIT * 3 // 4
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _to_tokens(y, plan, top_k: int, interpret: bool):
+    """``y`` ``[rows, ...]`` -> ``[T, ...]``: each token the float32 sum of
+    its slots' buffer rows (a slot with no row adds nothing). ``plan`` is
+    :func:`_plan`'s. The operand's shape chooses the form: ``[rows, D]`` in
+    whole lanes (that fit the kernel's VMEM), summed ``top_k`` rows a token
+    as the plan was made, is the kernel's; anything else (the router
+    weights' ``[rows, 1]``) gathers."""
+    row_of_slot = plan["row_of_slot"]
+    token_tiles, count = plan["seg_start"].shape
+    if (y.ndim == 2 and y.shape[1] % 128 == 0
+            and token_tiles == -(-(row_of_slot.shape[0] // top_k)
+                                 // TOKEN_TILE)
+            and _combine_fits(y.shape[1], y.dtype.itemsize, count)):
+        if _metrics.enabled():
+            for dim, n in (("tokens", TOKEN_TILE), ("rows", WINDOW_ROWS)):
+                _metrics.gauge(
+                    "moe_combine_tile",
+                    help="tokens one grid step of the routed layer's way "
+                         "back to the tokens sums, and rows of one window "
+                         "it reads of the sorted buffer; absent where the "
+                         "gather ran", dim=dim).set(n)
+        return _pallas_combine(y, plan, top_k=top_k, interpret=interpret)
+    return _gather_to_tokens(y, row_of_slot, top_k)
+
+
+def _to_tokens_fwd(y, plan, top_k, interpret):
+    return _to_tokens(y, plan, top_k, interpret), plan
 
 
 @jax.named_scope("hvd.moe_route")
-def _to_tokens_bwd(top_k, res, g):
-    slot_of_row, row_of_slot = res
-    real = slot_of_row < row_of_slot.shape[0]
-    rows = _to_rows(g, slot_of_row, row_of_slot, top_k)
+def _to_tokens_bwd(top_k, interpret, plan, g):
+    real = plan["slot_of_row"] < plan["row_of_slot"].shape[0]
+    rows = _to_rows(g, plan, top_k, interpret)
     real = real.reshape(real.shape + (1,) * (rows.ndim - 1))
-    return jnp.where(real, rows, 0), None, None
+    return jnp.where(real, rows, 0), None
 
 
 _to_tokens.defvjp(_to_tokens_fwd, _to_tokens_bwd)
@@ -494,10 +707,13 @@ def routed_experts(x, router, gate, up, down, *, top_k: int, first: int = 0,
     holds every one of them whatever the router does
     (:func:`buffer_rows`), three grouped matrix products run over its
     tiles in use in ``dtype`` (Pallas kernels: a row tile times its
-    expert's matrix), and the weighted rows are summed back per token, by
-    gathers both ways. The buffer's rows past the tiles in use are never
-    written and never read back: nothing is dropped, and the products' work
-    follows the rows the router sent here.
+    expert's matrix), and the weighted rows are summed back per token
+    (:func:`_to_tokens`: a kernel that reads the rows held here, where the
+    rows are whole lanes wide; the same kernel is the transpose of the
+    gather into the buffer). The buffer's rows past the tiles in use are
+    never written and never read back: nothing is dropped, and the work of
+    the products and of the way back follows the rows the router sent
+    here.
 
     One chip, no exchange: the caller's tokens are all the tokens.
     ``interpret`` defaults to running the kernels interpreted off TPU."""
@@ -515,9 +731,8 @@ def routed_experts(x, router, gate, up, down, *, top_k: int, first: int = 0,
     with jax.named_scope("hvd.moe_route"):
         weights, experts = route_top_k(x, router, top_k, select)
         plan = _plan(experts, first=first, count=count)
-        slot_of_row, row_of_slot = plan["slot_of_row"], plan["row_of_slot"]
-        xs = _to_rows(x.astype(dtype), slot_of_row, row_of_slot, top_k)
-        w_rows = _to_rows(weights.reshape(-1, 1), slot_of_row, row_of_slot, 1)
+        xs = _to_rows(x.astype(dtype), plan, top_k, interpret)
+        w_rows = _to_rows(weights.reshape(-1, 1), plan, 1, interpret)
     with jax.named_scope("hvd.moe_experts"):
         groups = (plan["tile_expert"], plan["tiles"], interpret)
         act = (jax.nn.silu(grouped_matmul(xs, gate, *groups))
@@ -526,10 +741,10 @@ def routed_experts(x, router, gate, up, down, *, top_k: int, first: int = 0,
     with jax.named_scope("hvd.moe_route"):
         # a padding row (every row past the tiles in use is one) holds
         # whatever the products left there: chosen away, never multiplied
-        real = (slot_of_row < row_of_slot.shape[0])[:, None]
+        real = (plan["slot_of_row"] < tokens * top_k)[:, None]
         ys = (jnp.where(real, ys.astype(jnp.float32), 0) * w_rows).astype(
             dtype)
-        y = _to_tokens(ys, slot_of_row, row_of_slot, top_k)
+        y = _to_tokens(ys, plan, top_k, interpret)
     return y, plan["local"].astype(jnp.float32)
 
 

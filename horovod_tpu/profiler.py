@@ -40,8 +40,9 @@ Device scopes, as they read in an instruction's ``op_name``:
 - ``hvd.allreduce``, ``hvd.allgather``, … (+ ``/<name>`` where the caller
   gave ``name=``) — an in-jit ``hvd.<collective>``, a user's own included
 - ``hvd.flash_fwd`` / ``hvd.flash_bwd`` — flash attention's two halves
-- ``hvd.moe_route`` — a routed-expert layer's router, top-k, sort and the
-  gathers into the sorted buffer and back; ``hvd.moe_experts`` — its grouped
+- ``hvd.moe_route`` — a routed-expert layer's router, top-k, sort, the
+  gather into the sorted buffer and the kernel that sums the rows back per
+  token (a ``pallas_call`` with no name); ``hvd.moe_experts`` — its grouped
   matrix products and the activation between them. Both cover the forward
   and, in or under a ``transpose(...)`` component, the backward
 - ``hvd_<kernel>`` — one ``pallas_call`` (also the Mosaic ``kernel_name``):
@@ -53,7 +54,9 @@ Device scopes, as they read in an instruction's ``op_name``:
 
 Trace-time gauges say what a trace chose: ``flash_fwd_tile`` /
 ``flash_bwd_tile`` (+ ``*_grid_steps``), ``moe_rows_budget`` (rows of a
-routed layer's sorted buffer: the worst case). ``moe_local_rows`` is a
+routed layer's sorted buffer: the worst case), ``moe_combine_tile`` (the
+tile of the kernel that sums the rows back; absent where the gather ran).
+``moe_local_rows`` is a
 step's counter (the assignments that landed on the experts held here: what
 the grouped products' time follows), set by ``parallel.moe.record_rows``
 from the step's ``batch_stats``.

@@ -382,6 +382,138 @@ def test_the_products_pass_over_tiles_no_row_fills(tokens, highest):
     assert np.isnan(out[tiles * moe.TILE_ROWS:]).all()
 
 
+# ------------------------------------------- the way back to the tokens
+
+
+def _way_back(tokens, first, count, routing="uniform", routed=8, top_k=2,
+              dim=128, dtype=jnp.float32):
+    """A plan and a sorted buffer whose rows past the tiles in use hold
+    NaN, as the grouped products leave them interpreted."""
+    keys = jax.random.split(jax.random.PRNGKey(tokens + 7 * first), 2)
+    if routing == "one expert":
+        chosen = jnp.tile(jnp.array([[first, first + 1]], jnp.int32),
+                          (tokens, 1))
+    elif routing == "none here":
+        away = [e for e in range(routed) if not first <= e < first + count]
+        chosen = jnp.tile(jnp.array([away[:top_k]], jnp.int32), (tokens, 1))
+    else:
+        chosen = jax.lax.top_k(jax.random.uniform(keys[0], (tokens, routed)),
+                               top_k)[1].astype(jnp.int32)
+    plan = moe._plan(chosen, first=first, count=count)
+    y = jax.random.normal(keys[1], (moe.buffer_rows(tokens, top_k, count),
+                                    dim))
+    y = y.at[int(plan["tiles"][0]) * moe.TILE_ROWS:].set(jnp.nan)
+    return y.astype(dtype), plan, top_k
+
+
+@pytest.mark.parametrize("tokens,first,count,routing,dtype", [
+    (64, 0, 8, "uniform", "float32"),       # all held
+    (64, 4, 4, "uniform", "float32"),
+    (64, 2, 3, "uniform", "bfloat16"),
+    (600, 0, 8, "uniform", "bfloat16"),     # all held, three token tiles
+    (600, 4, 4, "uniform", "float32"),
+    (600, 2, 3, "uniform", "float32"),
+    (333, 0, 8, "uniform", "float32"),      # a count no tile divides
+    (333, 4, 4, "uniform", "bfloat16"),
+    (333, 2, 3, "uniform", "float32"),
+    (600, 0, 8, "one expert", "float32"),   # segments of several windows
+    (333, 2, 3, "one expert", "bfloat16"),
+    (600, 4, 4, "none here", "float32"),
+])
+def test_the_way_back_reads_the_rows_held_here(tokens, first, count, routing,
+                                               dtype):
+    """The kernel (interpreted) against the gather of every slot's row: the
+    same float32 sums, and nothing of the rows no product wrote."""
+    y, plan, top_k = _way_back(tokens, first, count, routing,
+                               dtype=getattr(jnp, dtype))
+    assert bool(jnp.isnan(y[-1, 0]))
+    jaxpr = str(jax.make_jaxpr(
+        lambda a, w: moe._to_tokens(a, w, top_k, True))(y, plan))
+    assert "pallas_call" in jaxpr and "gather" not in jaxpr
+    got = np.asarray(moe._to_tokens(y, plan, top_k, True), np.float32)
+    want = np.asarray(moe._gather_to_tokens(y, plan["row_of_slot"], top_k),
+                      np.float32)
+    assert got.shape == (tokens, y.shape[1]) and np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    if routing == "none here":
+        assert int(plan["local"]) == 0 and not got.any()
+    if routing == "one expert":
+        assert int(plan["seg_rows"].max()) == min(tokens, moe.TOKEN_TILE)
+
+
+def test_the_way_back_takes_the_held_experts_in_groups():
+    """More held experts than one product takes windows of (24 of 32, in
+    groups of 16 and 8): the groups' sums add up."""
+    assert moe._WINDOW_GROUP < 24
+    y, plan, top_k = _way_back(300, 4, 24, routed=32, top_k=4)
+    got = moe._to_tokens(y, plan, top_k, True)
+    assert bool(jnp.isfinite(got).all()) and int(plan["local"]) > 600
+    np.testing.assert_allclose(
+        got, moe._gather_to_tokens(y, plan["row_of_slot"], top_k),
+        rtol=1e-6, atol=1e-6)
+
+
+def test_segments_are_the_rows_of_a_token_tile_on_an_expert():
+    """``seg_start`` / ``seg_rows``: the sort is stable, so the rows that a
+    tile of tokens has on one held expert follow each other."""
+    _, plan, top_k = _way_back(600, 2, 3)
+    rows = np.asarray(plan["row_of_slot"]).reshape(-1, top_k)
+    expert_of_row = np.repeat(np.asarray(plan["tile_expert"]), moe.TILE_ROWS)
+    for tile in range(-(-600 // moe.TOKEN_TILE)):
+        mine = rows[tile * moe.TOKEN_TILE:(tile + 1) * moe.TOKEN_TILE]
+        mine = mine[mine < expert_of_row.size]
+        for e in range(3):
+            here = np.sort(mine[expert_of_row[mine] == e])
+            start, n = (int(plan[k][tile, e]) for k in ("seg_start",
+                                                        "seg_rows"))
+            assert n == here.size
+            np.testing.assert_array_equal(here, start + np.arange(n))
+
+
+def test_an_operand_one_lane_wide_keeps_the_gather():
+    """The router weights' ``f32[rows, 1]`` (and any operand that is not
+    ``[rows, D]`` in whole lanes) gathers, chosen from its shape; the gauge
+    ``moe_combine_tile`` says which form a trace took."""
+    y, plan, top_k = _way_back(600, 4, 4)
+    slots = jax.random.normal(jax.random.PRNGKey(2), (y.shape[0], 1))
+    # ... and rows too wide for the kernel's windows to fit its VMEM
+    wide = jax.ShapeDtypeStruct((y.shape[0], 1 << 14), jnp.bfloat16)
+    assert moe._combine_fits(2304, 2, 16) and not moe._combine_fits(
+        1 << 14, 2, 4)
+    for operand, k in ((slots, 1), (y[:, :64], top_k), (y[:, :128], 1),
+                       (wide, top_k)):
+        metrics.REGISTRY.reset()
+        jaxpr = str(jax.make_jaxpr(
+            lambda a, w: moe._to_tokens(a, w, k, True))(operand, plan))
+        assert "pallas_call" not in jaxpr and "gather" in jaxpr
+        assert metrics.value("moe_combine_tile", dim="tokens") is None
+    jax.make_jaxpr(lambda a, w: moe._to_tokens(a, w, top_k, True))(y, plan)
+    assert metrics.value("moe_combine_tile", dim="tokens") == moe.TOKEN_TILE
+    assert metrics.value("moe_combine_tile", dim="rows") == moe.WINDOW_ROWS
+
+
+@pytest.mark.parametrize("first,count", [(0, 8), (4, 4), (2, 3)])
+def test_routed_layer_through_the_kernel_matches_a_dense_loop(first, count,
+                                                              highest):
+    """Hidden 128, whole lanes: the combine and the dispatch's transpose
+    both run the kernel, and the layer and every gradient still match the
+    dense per-expert loop."""
+    args = _routed_inputs(tokens=300, dim=128, count=count)
+    w = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+    routed = lambda *a: jnp.sum(w * moe.routed_experts(
+        *a, top_k=2, first=first)[0])
+    dense = lambda *a: jnp.sum(w * _dense_experts(*a, 2, first))
+    jaxpr = str(jax.make_jaxpr(jax.grad(routed, argnums=range(5)))(*args))
+    # 3 + 6 grouped products, the combine and the dispatch's transpose; the
+    # scalar weights' way back is the one gather of ``top_k`` x tokens rows
+    assert jaxpr.count("pallas_call") == 11
+    got = jax.value_and_grad(routed, argnums=range(5))(*args)
+    want = jax.value_and_grad(dense, argnums=range(5))(*args)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.abs(b).max()))
+
+
 def test_buffer_rows():
     # the benchmark's layer: 8192 tokens at top-8, 16 experts held: every
     # assignment and a tile of padding an expert
@@ -395,9 +527,11 @@ def test_buffer_rows():
 def test_routed_layer_lowers_for_tpu_under_its_scopes():
     """The grouped products are Mosaic calls ``hvd_moe_gmm`` (forward and
     the input's gradient) and ``hvd_moe_tgmm`` (the matrices' gradients)
-    under ``hvd.moe_experts``, the gathers are under ``hvd.moe_route``, in
-    the forward and in the backward: what the benchmark's ``moe_*`` readers
-    key on through ``profiler.scope_of``."""
+    under ``hvd.moe_experts``; the gathers and the kernel of the way back
+    (a call with no name of its own, so its time is its scope's) are under
+    ``hvd.moe_route``, in the forward and in the backward: what the
+    benchmark's ``moe_*`` readers key on through ``profiler.scope_of``. No
+    gather reads a row of width ``D`` for every slot."""
     import re
 
     from horovod_tpu import profiler
@@ -412,15 +546,25 @@ def test_routed_layer_lowers_for_tpu_under_its_scopes():
         y, _ = moe.routed_experts(*a, top_k=2, interpret=False)
         return y.astype(jnp.float32).sum()
 
-    text = jax.jit(jax.grad(loss, argnums=range(5))).trace(*args).lower(
-        lowering_platforms=("tpu",)).as_text(debug_info=True)
-    assert sorted(re.findall(r'kernel_name = "([^"]*)"', text)) == (
+    # with the value: the gradient of a sum needs no combine of its own
+    text = jax.jit(jax.value_and_grad(loss, argnums=range(5))).trace(
+        *args).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    names = sorted(re.findall(r'kernel_name = "([^"]*)"', text))
+    # the way back is lowered as a function its sites call
+    assert {n for n in names if not n.startswith("hvd_moe_")} == {
+        "_combine_kernel"}
+    assert [n for n in names if n.startswith("hvd_moe_")] == (
         ["hvd_moe_gmm"] * 6 + ["hvd_moe_tgmm"] * 3)
     calls = re.findall(r'loc\("([^"]*/pallas_call)"', text)
-    assert calls and all("hvd.moe_experts" in c for c in calls), calls
-    kinds = {profiler.scope_of(c, "custom-call") for c in calls}
+    assert len(calls) == 9 and all("hvd.moe_experts" in c for c in calls)
+    way_back = [c + "/pallas_call" for c in re.findall(
+        r'loc\("([^"]*/jit\(_pallas_combine\))"', text)]
+    assert len(way_back) == 2 and not any("hvd_moe_" in c for c in way_back)
+    kinds = {profiler.scope_of(c, "custom-call") for c in calls + way_back}
     assert kinds == {("forward", "hvd_moe_gmm"), ("backward", "hvd_moe_gmm"),
-                     ("backward", "hvd_moe_tgmm")}
+                     ("backward", "hvd_moe_tgmm"),
+                     ("forward", "hvd.moe_route"),
+                     ("backward", "hvd.moe_route")}
     gathers = [n for n in re.findall(r'loc\("([^"]*)"', text)
                if n.endswith("/gather")]
     assert any(profiler.scope_of(n) == ("forward", "hvd.moe_route")
@@ -428,6 +572,11 @@ def test_routed_layer_lowers_for_tpu_under_its_scopes():
     assert any(profiler.scope_of(n) == ("backward", "hvd.moe_route")
                for n in gathers)
     assert "stablehlo.scatter" not in text
+    # 512 tokens x top-2: the one gather of as many rows left is the scalar
+    # weights' (``f32[1024, 1]``)
+    wide = [l for l in text.splitlines() if "stablehlo.gather" in l
+            and re.search(r"-> tensor<1024x256x\w+>", l)]
+    assert "stablehlo.gather" in text and not wide, wide
 
 
 def test_scope_of_names_the_routed_scopes():
@@ -439,6 +588,15 @@ def test_scope_of_names_the_routed_scopes():
                     "hvd.moe_experts/mul") == ("backward", "hvd.moe_experts")
     assert scope_of("jit(s)/jvp(hvd.forward)/block0/hvd.moe_experts/"
                     "hvd_moe_gmm/pallas_call") == ("forward", "hvd_moe_gmm")
+    # the way back's call has no name: in the forward it is the combine's,
+    # in the backward the transpose of the dispatch, both ``hvd.moe_route``
+    assert scope_of("jit(s)/jvp(hvd.forward)/block0/hvd.moe_route/"
+                    "jit(_pallas_combine)/pallas_call",
+                    "custom-call") == ("forward", "hvd.moe_route")
+    assert scope_of("jit(s)/transpose(jvp(hvd.forward))/block0/"
+                    "hvd.moe_route/hvd.moe_route/jit(_pallas_combine)/"
+                    "pallas_call", "custom-call") == ("backward",
+                                                      "hvd.moe_route")
 
 
 # ------------------------------ what the older entry points do with such blocks

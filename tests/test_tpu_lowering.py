@@ -399,6 +399,36 @@ def test_token_xent_keeps_no_float32_copy_of_the_logits(one_v5e_chip, dtype,
     assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
 
 
+# -------------------------- the routed layer's way back to the tokens, on the chip
+
+
+@pytest.mark.parametrize("tokens,top_k,count,dim,dtype", [
+    (8192, 8, 16, 2304, "bfloat16"),   # the benchmark's routed cell
+    (2048, 8, 16, 2304, "bfloat16"),   # the ladder's other shape
+    (600, 2, 3, 256, "float32"),       # a count no tile divides, exact f32
+])
+def test_moe_way_back_compiles_for_v5e(one_v5e_chip, tokens, top_k, count,
+                                       dim, dtype):
+    """Mosaic takes the kernel that sums a token's rows of the sorted
+    buffer: its window copies start on the dtype's tiling, its scratch
+    fits the VMEM it asks for, and no gather is left beside it."""
+    from horovod_tpu.parallel import moe
+
+    rows = moe.buffer_rows(tokens, top_k, count)
+    tiles = -(-tokens // moe.TOKEN_TILE)
+    ints = functools.partial(S, dtype=jnp.int32, sharding=one_v5e_chip)
+    plan = {"slot_of_row": ints((rows,)),
+             "row_of_slot": ints((tokens * top_k,)),
+             "seg_start": ints((tiles, count)),
+             "seg_rows": ints((tiles, count))}
+    y = S((rows, dim), dtype, sharding=one_v5e_chip)
+    text = jax.jit(
+        lambda y, plan: moe._to_tokens(y, plan, top_k, False)
+    ).lower(y, plan).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert " gather(" not in text and " while(" not in text
+
+
 # ------------------------------- the staged backward, after the TPU's compiler
 
 
