@@ -22,6 +22,7 @@ from horovod_tpu.models.mlp import MLP  # noqa: F401
 from horovod_tpu.models.transformer import (  # noqa: F401
     Experts,
     Layer,
+    SwiGLU,
     TransformerLM,
     TransformerTiny,
     TransformerSmall,

@@ -53,6 +53,14 @@ class Yarn:
 
 
 @dataclasses.dataclass(frozen=True)
+class SwiGLU:
+    """A bias-free SwiGLU MLP in place of a block's GELU MLP:
+    ``(silu(h W_gate) * (h W_up)) W_down``, ``width`` wide."""
+
+    width: int
+
+
+@dataclasses.dataclass(frozen=True)
 class Experts:
     """A routed-expert FFN (:func:`horovod_tpu.parallel.moe.routed_experts`)
     in place of a block's MLP: a float32 router ``routed`` wide, ``top_k``
@@ -60,7 +68,11 @@ class Experts:
     ``count`` from ``first`` (default: all) and computes their part of the
     layer. ``select`` (``probabilities [tokens, routed] -> scores``) chooses
     a token's experts in the router's place, by the ``top_k`` of its
-    scores; the weights stay the router's."""
+    scores; the weights stay the router's. ``scale`` multiplies the routed
+    sum (a model's routed scaling factor). ``shared`` is the
+    width of a SwiGLU expert every token passes through, added to the
+    routed sum unweighted: it is computed whole wherever the layer is, so
+    across the holders of a layer's experts it counts once."""
 
     routed: int
     top_k: int
@@ -68,6 +80,8 @@ class Experts:
     first: int = 0
     count: Optional[int] = None
     select: Optional[Callable] = None
+    scale: float = 1.0
+    shared: Optional[int] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,8 +90,12 @@ class Layer:
     description (``TransformerLM(layers=...)``): its attention (``heads``
     query heads on ``kv_heads`` K/V heads of ``head_dim``, separate
     bias-free q/k/v projections; rotary with ``rope_base`` and optional
-    ``yarn``; causal, within ``window`` positions where set) and its FFN
-    (a GELU MLP ``mlp_ratio`` x dim wide, or :class:`Experts`)."""
+    ``yarn`` over the first ``rotary_dim`` features of a head, default all;
+    causal, within ``window`` positions where set; with ``gate`` each
+    head's output times ``sigmoid(h W_g)``, ``W_g`` ``[dim, heads]``, before
+    the output projection: the head-wise gate of arXiv:2505.06708) and its
+    FFN (a GELU MLP ``mlp_ratio`` x dim wide, a :class:`SwiGLU` MLP, or
+    :class:`Experts`)."""
 
     heads: int
     head_dim: int
@@ -85,21 +103,35 @@ class Layer:
     rope_base: float = 10000.0
     yarn: Optional[Yarn] = None
     window: Optional[int] = None
-    ffn: Union[int, Experts] = 4
+    ffn: Union[int, SwiGLU, Experts] = 4
+    rotary_dim: Optional[int] = None
+    gate: bool = False
 
 
 def apply_rope(x, positions, *, base: float = 10000.0,
-               yarn: Optional[Yarn] = None):
+               yarn: Optional[Yarn] = None,
+               rotary_dim: Optional[int] = None):
     """Rotary position embedding on ``[B, T, H, D]`` (D even), rotate-half
     (NeoX-style) convention: feature i pairs with feature i + D/2, rotated
     by ``positions * base**(-2i/D)`` (frequencies and amplitude rescaled
-    where ``yarn`` is set).
+    where ``yarn`` is set). With ``rotary_dim`` (even, under D) only the
+    first ``rotary_dim`` features are rotated, as a head of that size would
+    be (``yarn``'s correction dims reckoned over it too), and the rest
+    pass through.
 
     Positions are the *global* token indices, so under sequence parallelism
     each shard rotates with its own offsets and ring/Ulysses attention sees
     correctly phased K — relative-position behavior is preserved across
     shard boundaries (the property that makes RoPE the long-context default
     over a learned absolute table)."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        if rotary_dim % 2 or not 0 < rotary_dim < x.shape[-1]:
+            raise ValueError(
+                f"rotary_dim must be even and at most the head's "
+                f"{x.shape[-1]} features, got {rotary_dim}")
+        return jnp.concatenate(
+            [apply_rope(x[..., :rotary_dim], positions, base=base, yarn=yarn),
+             x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     half = d // 2
     if yarn is None:
@@ -170,11 +202,15 @@ class TransformerBlock(nn.Module):
     page_size: int = 0
     num_pages: int = 0
     # a block from a per-layer description (:class:`Layer`): a head size of
-    # its own (separate q/k/v projections), YaRN, a window, routed experts
+    # its own (separate q/k/v projections), YaRN, a window, rotary over part
+    # of a head, a head-wise output gate, a SwiGLU MLP or routed experts
     head_dim: Optional[int] = None
     yarn: Optional[Yarn] = None
     window: Optional[int] = None
     experts: Optional[Experts] = None
+    rotary_dim: Optional[int] = None
+    gate: bool = False
+    swiglu: Optional[SwiGLU] = None
     norm: str = "layernorm"  # or "rmsnorm"
     norm_eps: float = 1e-6
 
@@ -184,11 +220,15 @@ class TransformerBlock(nn.Module):
     @nn.compact
     def __call__(self, x, positions=None, page_table=None):
         if self.decode and (self.window is not None
-                            or self.experts is not None):
+                            or self.experts is not None
+                            or self.rotary_dim is not None or self.gate
+                            or self.swiglu is not None):
             raise NotImplementedError(
                 "kv-cache decoding (generate(), the serving engine) handles "
-                "full causal attention and MLP blocks only: this block has "
-                f"window={self.window}, experts={self.experts}")
+                "full causal attention with whole-head rotary and GELU MLP "
+                f"blocks only: this block has window={self.window}, "
+                f"experts={self.experts}, rotary_dim={self.rotary_dim}, "
+                f"gate={self.gate}, ffn={self.swiglu}")
         head_dim = self.head_dim or self.dim // self.heads
         h_kv = self.kv_heads or self.heads
         h = self._norm("ln1")(x)
@@ -222,8 +262,10 @@ class TransformerBlock(nn.Module):
                     "use_rope=True requires positions (global token "
                     "indices) — TransformerLM passes them automatically"
                 )
-            q = apply_rope(q, positions, base=self.rope_base, yarn=self.yarn)
-            k = apply_rope(k, positions, base=self.rope_base, yarn=self.yarn)
+            rope = dict(base=self.rope_base, yarn=self.yarn,
+                        rotary_dim=self.rotary_dim)
+            q = apply_rope(q, positions, **rope)
+            k = apply_rope(k, positions, **rope)
         if self.decode and self.paged:
             from horovod_tpu.ops.flash_attention import (
                 paged_decode_attention,
@@ -290,6 +332,11 @@ class TransformerBlock(nn.Module):
             att = self.attention_fn(q, k, v, causal=True, window=self.window)
         else:
             att = self.attention_fn(q, k, v, causal=True)
+        if self.gate:
+            # one sigmoid a head from the block's normalised input
+            g = nn.Dense(self.heads, use_bias=False, dtype=self.dtype,
+                         name="gate_proj")(h)
+            att = att * jax.nn.sigmoid(g)[..., None].astype(att.dtype)
         att = att.reshape(*att.shape[:2], self.heads * head_dim)
         x = x + nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
                          name="proj")(att)
@@ -297,11 +344,22 @@ class TransformerBlock(nn.Module):
         h = self._norm("ln2")(x)
         if self.experts is not None:
             return x + self._routed(h)
+        if self.swiglu is not None:
+            return x + self._swiglu(h, self.swiglu.width, "mlp")
         h = nn.Dense(self.mlp_ratio * self.dim, dtype=self.dtype,
                      name="mlp_up")(h)
         h = nn.gelu(h)
         h = nn.Dense(self.dim, dtype=self.dtype, name="mlp_down")(h)
         return x + h
+
+    def _swiglu(self, h, width, prefix):
+        """``(silu(h W_gate) * (h W_up)) W_down``, bias-free, in ``dtype``:
+        parameters ``{prefix}_gate``, ``{prefix}_up``, ``{prefix}_down``."""
+        gate, up = (nn.Dense(width, use_bias=False, dtype=self.dtype,
+                             name=f"{prefix}_{part}")(h)
+                    for part in ("gate", "up"))
+        return nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
+                        name=f"{prefix}_down")(nn.silu(gate) * up)
 
     def _routed(self, h):
         """The routed-expert FFN over the block's tokens, flattened: float32
@@ -316,6 +374,10 @@ class TransformerBlock(nn.Module):
         gate = self.param("experts_gate", init, (count, self.dim, e.width))
         up = self.param("experts_up", init, (count, self.dim, e.width))
         down = self.param("experts_down", init, (count, e.width, self.dim))
+        if e.scale != 1.0:
+            # the routed sum is linear in the down projections: the factor
+            # rides in their cast to ``dtype`` and costs no pass of its own
+            down = down * e.scale
         y, rows = routed_experts(
             h.reshape(-1, self.dim), router, gate, up, down, top_k=e.top_k,
             first=e.first, select=e.select, dtype=self.dtype)
@@ -324,7 +386,12 @@ class TransformerBlock(nn.Module):
         if self.is_mutable_collection("batch_stats"):
             self.variable("batch_stats", "moe_rows", jnp.zeros, (),
                           jnp.float32).value = rows
-        return y.reshape(h.shape)
+        y = y.reshape(h.shape)
+        if e.shared is not None:
+            # every token's own expert: it waits for nothing of the routing
+            with jax.named_scope("hvd.moe_shared"):
+                y = y + self._swiglu(h, e.shared, "shared")
+        return y
 
 
 def make_norm(kind: str, eps: float, dtype, name: str):
@@ -383,11 +450,15 @@ class TransformerLM(nn.Module):
                         kv_heads=self.kv_heads, rope_base=self.rope_base)
         layer = self.layers[i]
         routed = isinstance(layer.ffn, Experts)
+        swiglu = isinstance(layer.ffn, SwiGLU)
         return dict(
-            common, heads=layer.heads, mlp_ratio=0 if routed else layer.ffn,
+            common, heads=layer.heads,
+            mlp_ratio=0 if routed or swiglu else layer.ffn,
             kv_heads=layer.kv_heads, rope_base=layer.rope_base,
             head_dim=layer.head_dim, yarn=layer.yarn, window=layer.window,
-            experts=layer.ffn if routed else None)
+            experts=layer.ffn if routed else None,
+            swiglu=layer.ffn if swiglu else None,
+            rotary_dim=layer.rotary_dim, gate=layer.gate)
 
     @nn.compact
     def __call__(self, tokens, positions=None, train: bool = True,
@@ -479,6 +550,10 @@ def transformer_param_specs(params, model_axis: str = "model"):
                 "transformer_param_specs has no layout for a routed-expert "
                 f"block ({name}): its experts shard over an expert axis, "
                 "which this function does not describe")
+        if "gate_proj" in names or "mlp_gate" in names:
+            raise ValueError(
+                "transformer_param_specs has no layout for a gated or "
+                f"SwiGLU block ({name}): no model it describes has one")
         if leaf.ndim < 2:
             return P()
         if ("qkv" in name or "mlp_up" in name or "q_proj" in name

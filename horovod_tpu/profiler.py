@@ -43,8 +43,10 @@ Device scopes, as they read in an instruction's ``op_name``:
 - ``hvd.moe_route`` — a routed-expert layer's router, top-k, sort, the
   gather into the sorted buffer and the kernel that sums the rows back per
   token (a ``pallas_call`` with no name); ``hvd.moe_experts`` — its grouped
-  matrix products and the activation between them. Both cover the forward
-  and, in or under a ``transpose(...)`` component, the backward
+  matrix products and the activation between them; ``hvd.moe_shared`` —
+  the shared expert every token passes through beside its routed ones
+  (plain matrix products). All cover the forward and, in or under a
+  ``transpose(...)`` component, the backward
 - ``hvd_<kernel>`` — one ``pallas_call`` (also the Mosaic ``kernel_name``):
   ``hvd_flash_fwd``; ``hvd_moe_gmm`` (a row tile of the sorted buffer times
   its expert's matrix, or its transpose) and ``hvd_moe_tgmm`` (an expert's

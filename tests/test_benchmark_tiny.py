@@ -11,7 +11,8 @@ import pytest
 
 pytest.register_assert_rewrite("benchmarks.tests.test_check",
                                "benchmarks.tests.test_correct",
-                               "benchmarks.tests.test_mellum")
+                               "benchmarks.tests.test_mellum",
+                               "benchmarks.tests.test_laguna")
 
 from benchmarks.tests.test_check import (  # noqa: E402,F401
     test_a_second_four_chip_cell_needs_eight_cells,
@@ -25,6 +26,11 @@ from benchmarks.tests.test_correct import (  # noqa: E402,F401
     test_broken_timed_path_is_not_correct,
     test_control_is_not_correct,
     test_unbroken_run_is_correct,
+)
+from benchmarks.tests.test_laguna import (  # noqa: E402,F401
+    test_broken_laguna_timed_path_is_not_correct,
+    test_laguna_control_is_not_correct,
+    test_unbroken_laguna_run_is_correct,
 )
 from benchmarks.tests.test_mellum import (  # noqa: E402,F401
     test_broken_mellum_timed_path_is_not_correct,

@@ -404,6 +404,7 @@ def test_token_xent_keeps_no_float32_copy_of_the_logits(one_v5e_chip, dtype,
 
 @pytest.mark.parametrize("tokens,top_k,count,dim,dtype", [
     (8192, 8, 16, 2304, "bfloat16"),   # the benchmark's routed cell
+    (8192, 8, 32, 2048, "bfloat16"),   # its other one: two groups of windows
     (2048, 8, 16, 2304, "bfloat16"),   # the ladder's other shape
     (600, 2, 3, 256, "float32"),       # a count no tile divides, exact f32
 ])

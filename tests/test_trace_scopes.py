@@ -284,7 +284,7 @@ def test_benchmark_manifest_check_passes():
         [sys.executable, os.path.join(_ROOT, "benchmarks", "run.py"),
          "--check"], cwd=_ROOT, capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stderr
-    assert "check ok: 4 cell(s)" in out.stdout
+    assert "check ok: 5 cell(s)" in out.stdout
     with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     new = ["phase_forward_ms.train", "phase_backward_ms.train",
