@@ -314,6 +314,43 @@ def phase_kernels(sz):
         run(name + " fwd+bwd", grads(flash), grads(dense), (q, k, v),
             close(0.03, True))
 
+    # the routed-expert layer (top-2 of 8 experts, all held), bfloat16
+    # products: the layer and every gradient against a dense per-expert
+    # loop in float32
+    from horovod_tpu import metrics
+    from horovod_tpu.parallel import moe
+
+    def routed(x, router, gate, up, down):
+        return moe.routed_experts(x, router, gate, up, down, top_k=2,
+                                  dtype=jnp.bfloat16,
+                                  interpret=not sz.mosaic)[0]
+
+    def dense_experts(x, router, gate, up, down):
+        weights, chosen = moe.route_top_k(x, router, 2)
+        with jax.default_matmul_precision("highest"):
+            return sum(
+                jnp.sum(jnp.where(chosen == e, weights, 0.0), -1)[:, None]
+                * ((jax.nn.silu(x @ gate[e]) * (x @ up[e])) @ down[e])
+                for e in range(gate.shape[0]))
+
+    def layer_and_grads(layer):
+        return jax.value_and_grad(lambda *a: jnp.sum(
+            layer(*a).astype(jnp.float32) ** 2), argnums=range(5))
+
+    # x as bfloat16 rounds it: the layer rounds its copy, the router reads
+    # it as it came
+    layer_args = [jnp.asarray(rng.randn(*shape) * scale, jnp.float32)
+                  for shape, scale in (((1024, 256), 1.0), ((256, 8), 0.1),
+                                       ((8, 256, 128), 0.1),
+                                       ((8, 256, 128), 0.1),
+                                       ((8, 128, 256), 0.1))]
+    layer_args[0] = layer_args[0].astype(jnp.bfloat16).astype(jnp.float32)
+    run("routed_experts fwd+bwd", layer_and_grads(routed),
+        layer_and_grads(dense_experts), layer_args, close(0.03, True))
+    print(f"  moe_experts_fused={metrics.value('moe_experts_fused')} "
+          f"moe_combine_tile="
+          f"{metrics.value('moe_combine_tile', dim='tokens')}", flush=True)
+
 
 # --------------------------------------------------------------------------
 # phase 2: eager allreduce

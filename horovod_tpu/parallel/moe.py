@@ -15,12 +15,13 @@ to end, router included (straight-through on the gate value).
 several a token, where the ``[T, E, C]`` one-hot would be the layer: top-k
 routing without dropped tokens. The assignments to the experts a chip holds
 are sorted by expert into a buffer of static shape that holds the worst
-case, three grouped matrix products run over its tiles in use as Pallas
-kernels, and a fourth kernel sums the weighted rows back per token, reading
-the rows held here. The layer is told which experts it holds, routes over all
-of them and computes its own experts' part; it exchanges nothing, so it
-serves one chip (a model's share of a deployment), not yet the ``expert``
-axis.
+case, the experts' SwiGLU runs over its tiles in use as grouped matrix
+products in Pallas kernels that also do what a row needs between them (the
+activation, the combine's weighting, their backwards), and a further
+kernel sums the weighted rows back per token, reading the rows held here.
+The layer is told which experts it holds, routes over all of them and
+computes its own experts' part; it exchanges nothing, so it serves one chip
+(a model's share of a deployment), not yet the ``expert`` axis.
 """
 
 from __future__ import annotations
@@ -220,10 +221,12 @@ def buffer_rows(tokens: int, top_k: int, count: int) -> int:
     here, and a tile of padding an expert, so no assignment is ever without
     a row. Nothing holds a router near balance (untrained, the chip read
     0 to 30,639 of 65,536 assignments on 16 of 64 experts, layer by layer
-    and step by step, where balance sends 16,384). The grouped products
-    pass over the tiles no row fills, and the way back to the tokens reads
-    the rows held here (:func:`_pallas_combine`); the gather into the
-    buffer (:func:`_to_rows`) and the activation between the products
+    and step by step, where balance sends 16,384). The grouped products,
+    with the activation and the weighting inside them
+    (:func:`expert_mlp`), pass over the tiles no row fills, and the way
+    back to the tokens reads the rows held here (:func:`_pallas_combine`);
+    the gather into the buffer (:func:`_to_rows`, and the like of it that
+    is the way back's transpose) and the plan's tables of a number a row
     still run over the whole of it."""
     return (-(-tokens * top_k // TILE_ROWS) + count) * TILE_ROWS
 
@@ -525,13 +528,21 @@ def _to_tokens_fwd(y, plan, top_k, interpret):
 
 @jax.named_scope("hvd.moe_route")
 def _to_tokens_bwd(top_k, interpret, plan, g):
-    real = plan["slot_of_row"] < plan["row_of_slot"].shape[0]
-    rows = _to_rows(g, plan, top_k, interpret)
-    real = real.reshape(real.shape + (1,) * (rows.ndim - 1))
-    return jnp.where(real, rows, 0), None
+    # zeros on the padding rows: their slot is ``T * top_k``, which reads
+    # the row of zeros put after ``g``, and not a choice over ``[rows,
+    # ...]`` behind the gather
+    zeros = jnp.zeros((1,) + g.shape[1:], g.dtype)
+    return _to_rows(jnp.concatenate([g, zeros]), plan, top_k, interpret), None
 
 
 _to_tokens.defvjp(_to_tokens_fwd, _to_tokens_bwd)
+
+
+def _dot(lhs, rhs, transpose_rhs: bool = False):
+    """``lhs @ rhs`` (or ``lhs @ rhs^T``) with float32 accumulation."""
+    return lax.dot_general(
+        lhs, rhs, (((1,), (1 if transpose_rhs else 0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 def hvd_moe_gmm(tile_expert_ref, tiles_ref, lhs_ref, rhs_ref, out_ref, *,
@@ -547,31 +558,34 @@ def hvd_moe_gmm(tile_expert_ref, tiles_ref, lhs_ref, rhs_ref, out_ref, *,
 
     @pl.when(pl.program_id(0) < tiles_ref[0])
     def _multiply():
-        out_ref[...] = lax.dot_general(
-            lhs_ref[...], rhs_ref[0],
-            (((1,), (1 if transpose_rhs else 0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(out_ref.dtype)
+        out_ref[...] = _dot(lhs_ref[...], rhs_ref[0], transpose_rhs).astype(
+            out_ref.dtype)
 
 
-def hvd_moe_tgmm(tile_expert_ref, tiles_ref, lhs_ref, dout_ref, out_ref):
-    """An expert's weight gradient, ``lhs^T dout`` summed over its row
-    tiles, in the float32 output block that stays in VMEM while the grid
-    walks one expert's tiles (padding rows add zeros: their ``dout`` is;
-    the tiles past those in use are passed over)."""
+def hvd_moe_tgmm(tile_expert_ref, tiles_ref, lhs_ref, *refs):
+    """An expert's weight gradients ``lhs^T dout`` for each of ``refs``'
+    first half, the ``dout``s that share ``lhs``, summed over its row
+    tiles in ``refs``' second half, the float32 output blocks that stay in
+    VMEM while the grid walks one expert's tiles (padding rows add zeros:
+    their ``dout`` is; the tiles past those in use are passed over)."""
     from jax.experimental import pallas as pl
 
+    douts, outs = refs[:len(refs) // 2], refs[len(refs) // 2:]
     i = pl.program_id(2)
     before = tile_expert_ref[jnp.maximum(i - 1, 0)]
 
     @pl.when(jnp.logical_or(i == 0, tile_expert_ref[i] != before))
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        for out_ref in outs:
+            out_ref[...] = jnp.zeros_like(out_ref)
 
     @pl.when(i < tiles_ref[0])
     def _add():
-        out_ref[0] += lax.dot_general(
-            lhs_ref[...], dout_ref[...], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        lhs = lhs_ref[...]
+        for dout_ref, out_ref in zip(douts, outs):
+            out_ref[0] += lax.dot_general(
+                lhs, dout_ref[...], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
 
 def _fit_block(n: int, cap: int) -> int:
@@ -587,12 +601,34 @@ def _in_use(i, tiles):
     return jnp.minimum(i, tiles[0] - 1)
 
 
+def _row_tiles(width: int):
+    """The block of a ``[rows, width]`` operand or result that grid step
+    ``i`` of a grouped product holds: row tile ``i`` (:func:`_in_use`)."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec((TILE_ROWS, width),
+                        lambda i, te, n: (_in_use(i, n), 0))
+
+
+def _row_scalars():
+    """A number a row, lane-dense: tile ``i``'s block of a ``[1, rows]``
+    array (a ``[rows, 1]`` column is padded to 128 lanes in HBM)."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec((1, TILE_ROWS), lambda i, te, n: (0, _in_use(i, n)))
+
+
+def _matrix_of_tile(rhs):
+    """The matrix of tile ``i``'s expert, whole. One expert's tiles follow
+    each other: its matrix is copied once."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec((1,) + rhs.shape[1:], lambda i, te, n: (te[i], 0, 0))
+
+
+@functools.partial(jax.jit, static_argnames=("transpose_rhs", "interpret"))
 def _pallas_gmm(lhs, rhs, tile_expert, tiles, *, transpose_rhs: bool,
                 interpret: bool):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    rows = lhs.shape[0]
     n_out = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     matrix_bytes = rhs.shape[1] * rhs.shape[2] * rhs.dtype.itemsize
     if 2 * matrix_bytes > _VMEM_LIMIT // 2:
@@ -600,38 +636,34 @@ def _pallas_gmm(lhs, rhs, tile_expert, tiles, *, transpose_rhs: bool,
             f"routed experts: an expert's matrix {rhs.shape[1:]} in "
             f"{rhs.dtype} does not fit the grouped product's VMEM whole "
             f"({matrix_bytes} bytes); this kernel has no tiling over it")
-    return pl.pallas_call(
+    return _grouped_call(
         functools.partial(hvd_moe_gmm, transpose_rhs=transpose_rhs),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(rows // TILE_ROWS,),
-            in_specs=[
-                pl.BlockSpec((TILE_ROWS, lhs.shape[1]),
-                             lambda i, te, n: (_in_use(i, n), 0)),
-                # one expert's tiles follow each other: its matrix is
-                # copied once
-                pl.BlockSpec((1,) + rhs.shape[1:],
-                             lambda i, te, n: (te[i], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((TILE_ROWS, n_out),
-                                   lambda i, te, n: (_in_use(i, n), 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((rows, n_out), lhs.dtype),
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret,
-        name="hvd_moe_gmm",
-    )(tile_expert, tiles, lhs, rhs)
+        (tile_expert, tiles, lhs, rhs),
+        [_row_tiles(lhs.shape[1]), _matrix_of_tile(rhs)],
+        [(n_out, lhs.dtype)], interpret=interpret, name="hvd_moe_gmm")[0]
 
 
-def _pallas_tgmm(lhs, dout, tile_expert, tiles, count: int, *,
+@functools.partial(jax.jit, static_argnames=("count", "interpret"))
+def _pallas_tgmm(lhs, douts, tile_expert, tiles, count: int, *,
                  interpret: bool):
+    """``lhs^T dout`` by expert for each of the tuple ``douts`` (of one
+    shape), ``lhs`` read once for all of them: a tuple of ``[count, K, N]``
+    float32."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     rows, k = lhs.shape
-    n = dout.shape[1]
-    # the float32 output block [bk, bn], double-buffered, beside the tiles
-    bk, bn = (_fit_block(k, 768), n) if k >= n else (k, _fit_block(n, 768))
+    n = douts[0].shape[1]
+    # the float32 output blocks [bk, bn], one a dout, double-buffered,
+    # beside the tiles: whole matrices where they fit (a grid step costs
+    # 0.35 us whatever it holds, and a block more is a grid as long again)
+    cap = _VMEM_LIMIT * 3 // 4 // (8 * len(douts))
+    bk, bn = ((_fit_block(k, cap // n), n) if k >= n
+              else (k, _fit_block(n, cap // k)))
+    dout_spec = pl.BlockSpec((TILE_ROWS, bn),
+                             lambda a, b, i, te, n: (_in_use(i, n), b))
+    out_spec = pl.BlockSpec((1, bk, bn),
+                            lambda a, b, i, te, n: (te[i], a, b))
     return pl.pallas_call(
         hvd_moe_tgmm,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -640,17 +672,15 @@ def _pallas_tgmm(lhs, dout, tile_expert, tiles, count: int, *,
             in_specs=[
                 pl.BlockSpec((TILE_ROWS, bk),
                              lambda a, b, i, te, n: (_in_use(i, n), a)),
-                pl.BlockSpec((TILE_ROWS, bn),
-                             lambda a, b, i, te, n: (_in_use(i, n), b)),
-            ],
-            out_specs=pl.BlockSpec((1, bk, bn),
-                                   lambda a, b, i, te, n: (te[i], a, b)),
+                *[dout_spec] * len(douts)],
+            out_specs=[out_spec] * len(douts),
         ),
-        out_shape=jax.ShapeDtypeStruct((count, k, n), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((count, k, n), jnp.float32)
+                   ] * len(douts),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="hvd_moe_tgmm",
-    )(tile_expert, tiles, lhs, dout)
+    )(tile_expert, tiles, lhs, *douts)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -677,12 +707,183 @@ def _grouped_matmul_bwd(interpret, res, g):
     g = g.astype(lhs.dtype)
     dlhs = _pallas_gmm(g, rhs.astype(lhs.dtype), tile_expert, tiles,
                        transpose_rhs=True, interpret=interpret)
-    drhs = _pallas_tgmm(lhs, g, tile_expert, tiles, rhs.shape[0],
-                        interpret=interpret)
+    drhs, = _pallas_tgmm(lhs, (g,), tile_expert, tiles, rhs.shape[0],
+                         interpret=interpret)
     return dlhs, drhs.astype(rhs.dtype), None, None
 
 
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def hvd_moe_mlp_fwd(tile_expert_ref, tiles_ref, x_ref, w_ref, gate_ref,
+                    up_ref, down_ref, g_ref, u_ref, act_ref, y_ref):
+    """One row tile through its expert's SwiGLU: times the gate and up
+    matrices, the weighted activation ``silu(g) u w`` from the float32
+    accumulators, rounded once, times the down matrix. The product with the
+    down matrix is linear in the activation, so the combine's weighting
+    rides there, ``F`` wide and not ``D``. ``g`` and ``u`` are kept,
+    rounded, for the backward, and the weighted activation for the down
+    matrix's gradient. ``w_ref`` holds the tile's weights as a row; a
+    padding row's is 0."""
+    from jax.experimental import pallas as pl
+
+    del tile_expert_ref                    # read by the index maps
+
+    @pl.when(pl.program_id(0) < tiles_ref[0])
+    def _multiply():
+        x = x_ref[...]
+        g, u = _dot(x, gate_ref[0]), _dot(x, up_ref[0])
+        g_ref[...] = g.astype(g_ref.dtype)
+        u_ref[...] = u.astype(u_ref.dtype)
+        act = (jax.nn.silu(g) * u * w_ref[...].reshape(-1, 1)).astype(
+            act_ref.dtype)
+        act_ref[...] = act
+        y_ref[...] = _dot(act, down_ref[0]).astype(y_ref.dtype)
+
+
+def hvd_moe_mlp_bwd(tile_expert_ref, tiles_ref, dy_ref, w_ref, g_ref, u_ref,
+                    gate_ref, up_ref, down_ref, dg_ref, du_ref, dw_ref,
+                    dx_ref):
+    """The backward of :func:`hvd_moe_mlp_fwd` for a tile of ``d ys``, in
+    float32 on the accumulator ``t = d ys . down^T``: ``d w = rowsum(t
+    act)`` (``<d ys, act . down> = <d ys . down^T, act>``: the unweighted
+    result is neither kept nor made again), written as a row; ``d act = t
+    w``; ``d g`` and ``d u`` by silu's derivative, with ``act = silu(g) u``
+    from the kept ``g`` and ``u``, rounded for the matrices' gradients;
+    and ``d xs = d g . gate^T + d u . up^T`` summed in float32 and rounded
+    once (autodiff would write both and add them over the whole
+    buffer)."""
+    from jax.experimental import pallas as pl
+
+    del tile_expert_ref
+
+    @pl.when(pl.program_id(0) < tiles_ref[0])
+    def _multiply():
+        t = _dot(dy_ref[...], down_ref[0], transpose_rhs=True)
+        g = g_ref[...].astype(jnp.float32)
+        u = u_ref[...].astype(jnp.float32)
+        gate = jax.nn.sigmoid(g)
+        silu = g * gate
+        dw_ref[...] = jnp.sum(t * silu * u, axis=1)[None, :]
+        dact = t * w_ref[...].reshape(-1, 1)
+        du = (dact * silu).astype(du_ref.dtype)
+        dg = (dact * u * (gate * (1 + g * (1 - gate)))).astype(dg_ref.dtype)
+        du_ref[...] = du
+        dg_ref[...] = dg
+        dx_ref[...] = (_dot(dg, gate_ref[0], transpose_rhs=True)
+                       + _dot(du, up_ref[0], transpose_rhs=True)
+                       ).astype(dx_ref.dtype)
+
+
+def _grouped_call(kernel, operands, in_specs, results, *, interpret: bool,
+                  name: Optional[str] = None):
+    """``kernel`` over the buffer's row tiles, ``plan``'s ``tile_expert``
+    and ``tiles`` by scalar prefetch (the first two ``operands``);
+    ``results`` are ``(width, or None for a number a row, dtype)``. The
+    call is named ``name``, else as ``kernel`` is."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows = operands[2].shape[0]
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(rows // TILE_ROWS,),
+            in_specs=in_specs,
+            out_specs=[_row_scalars() if width is None else _row_tiles(width)
+                       for width, _ in results],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(
+            (1, rows) if width is None else (rows, width), dtype)
+            for width, dtype in results],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name=name or kernel.__name__,
+    )(*operands)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _pallas_mlp_fwd(xs, w, gate, up, down, tile_expert, tiles, *,
+                    interpret: bool):
+    d, f = gate.shape[1:]
+    return _grouped_call(
+        hvd_moe_mlp_fwd, (tile_expert, tiles, xs, w, gate, up, down),
+        [_row_tiles(d), _row_scalars(), _matrix_of_tile(gate),
+         _matrix_of_tile(up), _matrix_of_tile(down)],
+        [(f, xs.dtype)] * 3 + [(d, xs.dtype)], interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _pallas_mlp_bwd(dys, w, g, u, gate, up, down, tile_expert, tiles, *,
+                    interpret: bool):
+    d, f = gate.shape[1:]
+    return _grouped_call(
+        hvd_moe_mlp_bwd, (tile_expert, tiles, dys, w, g, u, gate, up, down),
+        [_row_tiles(d), _row_scalars(), _row_tiles(f), _row_tiles(f),
+         _matrix_of_tile(gate), _matrix_of_tile(up), _matrix_of_tile(down)],
+        [(f, dys.dtype), (f, dys.dtype), (None, jnp.float32),
+         (d, dys.dtype)], interpret=interpret)
+
+
+def _experts_fit(d: int, f: int, itemsize: int) -> bool:
+    """Whether the fused calls have room at experts of ``[d, f]``: an
+    expert's three matrices, double-buffered, beside (the backward holds
+    most) two tiles of ``d`` and four of ``f`` elements a row, twice each,
+    and the float32 accumulators: one of ``d`` and six of ``f`` a row."""
+    return (6 * d * f * itemsize
+            + TILE_ROWS * (2 * (2 * d + 4 * f) * itemsize + (d + 6 * f) * 4)
+            ) <= _VMEM_LIMIT * 7 // 8
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def expert_mlp(xs, w_rows, gate, up, down, tile_expert, tiles,
+               interpret: bool = False):
+    """The held experts' SwiGLU over the sorted buffer and the combine's
+    weighting, ``w_rows * ((silu(xs gate[e]) * (xs up[e])) down[e])`` tile
+    by tile of ``TILE_ROWS`` rows with ``e = tile_expert[i]``, over the
+    first ``tiles[0]`` tiles: ``xs`` ``[rows, D]``, ``w_rows`` ``[rows]``
+    float32 (0 for a padding row), ``gate`` and ``up`` ``[E, D, F]`` and
+    ``down`` ``[E, F, D]`` (float32 parameters, multiplied in ``xs``'s
+    dtype with float32 accumulation) -> ``[rows, D]``. One derivative for
+    the whole of it, so that everything a row needs between the products
+    is done on the tile a product holds, in float32 on its accumulator,
+    and only for the tiles in use: one call forward
+    (:func:`hvd_moe_mlp_fwd`), three backward (:func:`hvd_moe_mlp_bwd`,
+    and :func:`hvd_moe_tgmm` for the gate's and the up's gradients
+    together and for the down's). The rows past the tiles in use are
+    neither written nor read, in any result."""
+    return _expert_mlp_fwd(xs, w_rows, gate, up, down, tile_expert, tiles,
+                           interpret)[0]
+
+
+def _expert_mlp_fwd(xs, w_rows, gate, up, down, tile_expert, tiles,
+                    interpret):
+    w = w_rows.reshape(1, -1)
+    g, u, act, ys = _pallas_mlp_fwd(
+        xs, w, *(m.astype(xs.dtype) for m in (gate, up, down)), tile_expert,
+        tiles, interpret=interpret)
+    return ys, (xs, w, g, u, act, gate, up, down, tile_expert, tiles)
+
+
+@jax.named_scope("hvd.moe_experts")
+def _expert_mlp_bwd(interpret, res, dys):
+    xs, w, g, u, act, gate, up, down, tile_expert, tiles = res
+    dys = dys.astype(xs.dtype)
+    dg, du, dw, dxs = _pallas_mlp_bwd(
+        dys, w, g, u, *(m.astype(xs.dtype) for m in (gate, up, down)),
+        tile_expert, tiles, interpret=interpret)
+    # padding rows add zeros: ``act`` is weighted by 0 there, and so are
+    # ``d g`` and ``d u``
+    dgate, dup = _pallas_tgmm(xs, (dg, du), tile_expert, tiles,
+                              gate.shape[0], interpret=interpret)
+    ddown, = _pallas_tgmm(act, (dys,), tile_expert, tiles, gate.shape[0],
+                          interpret=interpret)
+    return (dxs, dw.reshape(-1), dgate.astype(gate.dtype),
+            dup.astype(up.dtype), ddown.astype(down.dtype), None, None)
+
+
+expert_mlp.defvjp(_expert_mlp_fwd, _expert_mlp_bwd)
 
 
 def routed_experts(x, router, gate, up, down, *, top_k: int, first: int = 0,
@@ -705,15 +906,19 @@ def routed_experts(x, router, gate, up, down, *, top_k: int, first: int = 0,
     The router runs in float32 (``highest`` precision); the assignments to
     held experts are sorted by expert into a buffer of static shape that
     holds every one of them whatever the router does
-    (:func:`buffer_rows`), three grouped matrix products run over its
-    tiles in use in ``dtype`` (Pallas kernels: a row tile times its
-    expert's matrix), and the weighted rows are summed back per token
-    (:func:`_to_tokens`: a kernel that reads the rows held here, where the
-    rows are whole lanes wide; the same kernel is the transpose of the
-    gather into the buffer). The buffer's rows past the tiles in use are
-    never written and never read back: nothing is dropped, and the work of
-    the products and of the way back follows the rows the router sent
-    here.
+    (:func:`buffer_rows`), the experts' weighted SwiGLU runs over its
+    tiles in use in ``dtype`` (:func:`expert_mlp`: Pallas kernels, a row
+    tile times its expert's matrices, with the activation, the token's
+    weight and their backwards done in float32 on the tile a product
+    holds, where an expert's three matrices fit the kernels' VMEM,
+    :func:`_experts_fit`; three separate products and passes over the
+    whole buffer between them where they do not), and the weighted rows
+    are summed back per token (:func:`_to_tokens`: a kernel that reads the
+    rows held here, where the rows are whole lanes wide; the same kernel
+    is the transpose of the gather into the buffer). The buffer's rows
+    past the tiles in use are never written and never read back: nothing
+    is dropped, and the work of the products, of what stands between them
+    and of the way back follows the rows the router sent here.
 
     One chip, no exchange: the caller's tokens are all the tokens.
     ``interpret`` defaults to running the kernels interpreted off TPU."""
@@ -733,17 +938,33 @@ def routed_experts(x, router, gate, up, down, *, top_k: int, first: int = 0,
         plan = _plan(experts, first=first, count=count)
         xs = _to_rows(x.astype(dtype), plan, top_k, interpret)
         w_rows = _to_rows(weights.reshape(-1, 1), plan, 1, interpret)
-    with jax.named_scope("hvd.moe_experts"):
-        groups = (plan["tile_expert"], plan["tiles"], interpret)
-        act = (jax.nn.silu(grouped_matmul(xs, gate, *groups))
-               * grouped_matmul(xs, up, *groups))
-        ys = grouped_matmul(act, down, *groups)
-    with jax.named_scope("hvd.moe_route"):
-        # a padding row (every row past the tiles in use is one) holds
-        # whatever the products left there: chosen away, never multiplied
         real = (plan["slot_of_row"] < tokens * top_k)[:, None]
-        ys = (jnp.where(real, ys.astype(jnp.float32), 0) * w_rows).astype(
-            dtype)
+    groups = (plan["tile_expert"], plan["tiles"], interpret)
+    if _experts_fit(*gate.shape[1:], jnp.dtype(dtype).itemsize):
+        if _metrics.enabled():
+            _metrics.gauge(
+                "moe_experts_fused",
+                help="1 where a routed layer's activation, the combine's "
+                     "weighting and their backwards were traced inside "
+                     "the grouped products' kernels; absent where they "
+                     "ran as passes over the whole buffer").set(1)
+        with jax.named_scope("hvd.moe_route"):
+            # a padding row of a tile in use is weighted by 0; the rows
+            # past the tiles in use hold whatever was there and are read
+            # by nothing (``_to_tokens`` reads the rows that have a slot)
+            w_rows = jnp.where(real, w_rows, 0).reshape(-1)
+        with jax.named_scope("hvd.moe_experts"):
+            ys = expert_mlp(xs, w_rows, gate, up, down, *groups)
+    else:
+        with jax.named_scope("hvd.moe_experts"):
+            act = (jax.nn.silu(grouped_matmul(xs, gate, *groups))
+                   * grouped_matmul(xs, up, *groups))
+            ys = grouped_matmul(act, down, *groups)
+        with jax.named_scope("hvd.moe_route"):
+            # chosen away, never multiplied: ``0 x NaN``
+            ys = (jnp.where(real, ys.astype(jnp.float32), 0) * w_rows
+                  ).astype(dtype)
+    with jax.named_scope("hvd.moe_route"):
         y = _to_tokens(ys, plan, top_k, interpret)
     return y, plan["local"].astype(jnp.float32)
 

@@ -49,16 +49,19 @@ Device scopes, as they read in an instruction's ``op_name``:
   ``transpose(...)`` component, the backward
 - ``hvd_<kernel>`` — one ``pallas_call`` (also the Mosaic ``kernel_name``):
   ``hvd_flash_fwd``; ``hvd_moe_gmm`` (a row tile of the sorted buffer times
-  its expert's matrix, or its transpose) and ``hvd_moe_tgmm`` (an expert's
-  weight gradient) inside ``hvd.moe_experts``, keyed apart from it by
+  its expert's matrix, or its transpose), ``hvd_moe_mlp_fwd`` (a row tile
+  through its expert's SwiGLU, weighted), ``hvd_moe_mlp_bwd`` (its
+  backward) and ``hvd_moe_tgmm`` (an expert's weight gradients) inside
+  ``hvd.moe_experts``, keyed apart from it by
   :func:`scope_of`; the quantisation and optimizer kernels of
   ``ops/pallas_kernels.py``
 
 Trace-time gauges say what a trace chose: ``flash_fwd_tile`` /
 ``flash_bwd_tile`` (+ ``*_grid_steps``), ``moe_rows_budget`` (rows of a
 routed layer's sorted buffer: the worst case), ``moe_combine_tile`` (the
-tile of the kernel that sums the rows back; absent where the gather ran).
-``moe_local_rows`` is a
+tile of the kernel that sums the rows back; absent where the gather ran),
+``moe_experts_fused`` (1 where the experts' activation and weighting were
+traced inside the grouped products' kernels). ``moe_local_rows`` is a
 step's counter (the assignments that landed on the experts held here: what
 the grouped products' time follows), set by ``parallel.moe.record_rows``
 from the step's ``batch_stats``.

@@ -358,11 +358,15 @@ def test_no_assignment_here_adds_nothing(highest):
 
 
 @pytest.mark.parametrize("tokens", [64, 600])
-def test_the_products_pass_over_tiles_no_row_fills(tokens, highest):
+@pytest.mark.parametrize("call", ["product", "expert_mlp"])
+def test_the_products_pass_over_tiles_no_row_fills(call, tokens, highest):
     """The grouped products' work follows the rows the router sent here:
     ``tiles`` counts each held expert's whole tiles (one at least), the
     runs fill the buffer's first ``tiles`` tiles, and a product leaves the
-    rows past them as they were allocated."""
+    rows past them as they were allocated. The fused calls of the experts'
+    MLP too, forward and backward: with every operand's rows past the
+    tiles in use poisoned, whatever they write for a tile in use and every
+    matrix's gradient is finite, and they write nothing past them."""
     x, router, gate, up, down = _routed_inputs(tokens=tokens, count=4)
     _, chosen = moe.route_top_k(x, router, 2)
     plan = moe._plan(chosen, first=2, count=4)
@@ -374,12 +378,157 @@ def test_the_products_pass_over_tiles_no_row_fills(tokens, highest):
     assert plan["slot_of_row"].shape == (rows,) and tiles < rows // moe.TILE_ROWS
     in_use = np.asarray(plan["slot_of_row"]) < tokens * 2
     assert in_use.sum() == sizes.sum()
-    assert not in_use[tiles * moe.TILE_ROWS:].any()
-    xs = jnp.ones((rows, x.shape[1]), jnp.float32)
-    out = np.asarray(moe.grouped_matmul(xs, gate, plan["tile_expert"],
-                                        plan["tiles"], True))
-    assert np.isfinite(out[:tiles * moe.TILE_ROWS]).all()
-    assert np.isnan(out[tiles * moe.TILE_ROWS:]).all()
+    used = tiles * moe.TILE_ROWS
+    assert not in_use[used:].any()
+    groups = (plan["tile_expert"], plan["tiles"], True)
+    if call == "product":
+        xs = jnp.ones((rows, x.shape[1]), jnp.float32)
+        out = np.asarray(moe.grouped_matmul(xs, gate, *groups))
+        assert np.isfinite(out[:used]).all()
+        assert np.isnan(out[used:]).all()
+        return
+    poisoned = lambda a: a.at[used:].set(jnp.nan)
+    keys = jax.random.split(jax.random.PRNGKey(tokens), 3)
+    xs = poisoned(jax.random.normal(keys[0], (rows, x.shape[1])))
+    w_rows = poisoned(jnp.where(in_use, jax.random.uniform(keys[1], (rows,)),
+                                0))
+    # as ``_to_tokens_bwd`` hands it in: zeros on the padding rows
+    dys = poisoned(jnp.where(in_use[:, None],
+                             jax.random.normal(keys[2], xs.shape), 0))
+    ys, back = jax.vjp(lambda *a: moe.expert_mlp(*a, *groups), xs, w_rows,
+                       gate, up, down)
+    dxs, dw, *matrices = back(dys)
+    for by_row in (ys, dxs, dw):
+        by_row = np.asarray(by_row)
+        assert by_row.shape[0] == rows and np.isfinite(by_row[:used]).all()
+        assert np.isnan(by_row[used:]).all()
+    assert not np.asarray(ys)[:used][~in_use[:used]].any()
+    for got, like in zip(matrices, (gate, up, down)):
+        assert got.shape == like.shape and np.isfinite(np.asarray(got)).all()
+    # the same numbers as the three products and the passes between them
+    act = (jax.nn.silu(moe.grouped_matmul(xs, gate, *groups))
+           * moe.grouped_matmul(xs, up, *groups))
+    want = moe.grouped_matmul(act, down, *groups) * w_rows[:, None]
+    np.testing.assert_allclose(np.asarray(ys)[:used], np.asarray(want)[:used],
+                               rtol=1e-5, atol=1e-6)
+
+
+def _routing_case(routing, count):
+    """Inputs whose router sends the tokens ``routing``'s way."""
+    x, router, gate, up, down = _routed_inputs(tokens=300, dim=128,
+                                               count=count)
+    select = None
+    if routing == "one expert":
+        x, router = jnp.abs(x), router.at[:, 0].set(10.0)
+    elif routing == "an expert with no row":
+        # scores no token's top 2 reach on expert 5: held by every case
+        select = lambda probs: probs.at[:, 5].set(-1.0)
+    return (x, router, gate, up, down), select
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("first,count,routing", [
+    (0, 8, "router"), (4, 4, "router"), (2, 3, "router"),
+    (0, 8, "an expert with no row"), (4, 4, "an expert with no row"),
+    (2, 4, "an expert with no row"),
+    (0, 8, "one expert"), (0, 3, "one expert"),
+])
+def test_fused_expert_mlp_matches_a_dense_loop(first, count, routing, dtype,
+                                               highest):
+    """The activation, the combine's weighting and their backwards inside
+    the grouped products' kernels (interpreted), through
+    ``routed_experts``: the layer and the gradient of x, of the router and
+    of every expert matrix against the dense per-expert loop, in float32
+    and with bfloat16 products."""
+    args, select = _routing_case(routing, count)
+    dtype = getattr(jnp, dtype)
+    c = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+    metrics.REGISTRY.reset()
+
+    def routed(*a):
+        y, _ = moe.routed_experts(a[0].astype(dtype), *a[1:], top_k=2,
+                                  first=first, select=select, dtype=dtype)
+        assert y.dtype == dtype
+        return jnp.sum(c * y)
+
+    dense = lambda *a: jnp.sum(c * _dense_experts(*a, 2, first, select))
+    got = jax.value_and_grad(routed, argnums=range(5))(*args)
+    assert metrics.value("moe_experts_fused") == 1
+    want = jax.value_and_grad(dense, argnums=range(5))(*args)
+    if routing == "an expert with no row":
+        _, chosen = moe.route_top_k(*args[:2], 2, select)
+        assert not bool(jnp.any(chosen == 5))
+        assert not np.any(np.asarray(got[1][2][5 - first]))
+    if routing == "one expert":
+        assert bool(jnp.all(moe.route_top_k(*args[:2], 2)[1][:, 0] == 0))
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got[0], want[0], rtol=tol,
+                               atol=tol * float(jnp.abs(c).sum()) * 1e-3)
+    for a, b in zip(got[1], want[1]):
+        assert a.dtype == b.dtype and bool(jnp.isfinite(a).all())
+        np.testing.assert_allclose(a, b, atol=tol * float(jnp.abs(b).max()))
+
+
+def test_experts_too_wide_for_the_fused_calls_keep_the_three_products():
+    """Which form runs is read from the shapes: two matrices of an expert,
+    double-buffered, must fit the kernels' VMEM beside the tiles. Both
+    routed cells' experts do; at ``[4096, 1408]`` in bfloat16 (46 MB for
+    the four) the layer is the three separate products with the passes
+    between them, and the gauge ``moe_experts_fused`` stays unset."""
+    assert moe._experts_fit(2304, 896, 2) and moe._experts_fit(2048, 512, 2)
+    assert not moe._experts_fit(4096, 1408, 2)
+    S = jax.ShapeDtypeStruct
+    for dim, width, fused in ((4096, 1408, False), (2048, 512, True)):
+        metrics.REGISTRY.reset()
+        text = str(jax.make_jaxpr(lambda *a: moe.routed_experts(
+            *a, top_k=2, interpret=True)[0])(
+                S((64, dim), jnp.bfloat16), S((dim, 8), jnp.float32),
+                S((2, dim, width), jnp.float32),
+                S((2, dim, width), jnp.float32),
+                S((2, width, dim), jnp.float32)))
+        assert ("hvd_moe_mlp_fwd" in text) == fused
+        assert text.count("name=_pallas_gmm") == (0 if fused else 3)
+        assert metrics.value("moe_experts_fused") == (1 if fused else None)
+
+
+def _sees(jaxpr, shapes, found):
+    """The primitives of ``jaxpr`` (and of every jaxpr inside it but a
+    ``pallas_call``'s kernel) that read or write an array of one of
+    ``shapes``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name != "pallas_call":
+            for value in eqn.params.values():
+                inner = getattr(value, "jaxpr", value)
+                if hasattr(inner, "eqns"):
+                    _sees(inner, shapes, found)
+        if eqn.primitive.name in ("pjit", "jit", "custom_vjp_call",
+                                  "custom_jvp_call", "pallas_call"):
+            continue
+        if any(getattr(v.aval, "shape", None) in shapes
+               for v in list(eqn.invars) + list(eqn.outvars)):
+            found.append(eqn.primitive.name)
+    return found
+
+
+def test_what_still_runs_over_the_whole_buffer():
+    """ROADMAP S12's remaining list, from the jaxpr of the layer and its
+    gradient: outside the kernels, the only operations over a ``[rows, D]``
+    or ``[rows, F]`` array are the dispatch's two gathers (``_to_rows`` of
+    x, and of the combine's cotangent, whose padding rows read a row of
+    zeros). No ``add_any`` (the gate's and the up's dX are summed in a
+    kernel), no ``mul``, ``logistic`` or ``select_n`` (silu * up, the
+    combine's weighting and their backwards are the kernels' too)."""
+    args = _routed_inputs(tokens=300, dim=128, width=64, count=4)
+    args = (args[0].astype(jnp.bfloat16),) + args[1:]
+    rows = moe.buffer_rows(300, 2, 4)
+    loss = lambda *a: moe.routed_experts(*a, top_k=2, first=2)[0].astype(
+        jnp.float32).sum()
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=range(5)))(*args)
+    found = _sees(jaxpr.jaxpr, {(rows, 128), (rows, 64)}, [])
+    assert sorted(found) == ["gather", "gather"], found
+    text = str(jaxpr)
+    for name in ("hvd_moe_mlp_fwd", "hvd_moe_mlp_bwd", "hvd_moe_tgmm"):
+        assert name in text, name
 
 
 # ------------------------------------------- the way back to the tokens
@@ -504,12 +653,15 @@ def test_routed_layer_through_the_kernel_matches_a_dense_loop(first, count,
         *a, top_k=2, first=first)[0])
     dense = lambda *a: jnp.sum(w * _dense_experts(*a, 2, first))
     jaxpr = str(jax.make_jaxpr(jax.grad(routed, argnums=range(5)))(*args))
-    # 3 + 6 grouped products, the combine and the dispatch's transpose; the
-    # scalar weights' way back is the one gather of ``top_k`` x tokens rows
-    assert jaxpr.count("pallas_call") == 11
+    # the experts' MLP in 1 + 3 calls, the combine and the dispatch's
+    # transpose (each a jitted function its sites share); the scalar
+    # weights' way back is the one gather of ``top_k`` x tokens rows
+    assert jaxpr.count("name=_pallas_") == 6
     got = jax.value_and_grad(routed, argnums=range(5))(*args)
     want = jax.value_and_grad(dense, argnums=range(5))(*args)
-    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    # a sum of 38,400 terms that cancel to a thousandth of their size, in
+    # another order than the loop's (the weight is on the activation)
+    np.testing.assert_allclose(got[0], want[0], rtol=3e-5)
     for a, b in zip(got[1], want[1]):
         np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.abs(b).max()))
 
@@ -525,13 +677,15 @@ def test_buffer_rows():
 
 
 def test_routed_layer_lowers_for_tpu_under_its_scopes():
-    """The grouped products are Mosaic calls ``hvd_moe_gmm`` (forward and
-    the input's gradient) and ``hvd_moe_tgmm`` (the matrices' gradients)
-    under ``hvd.moe_experts``; the gathers and the kernel of the way back
-    (a call with no name of its own, so its time is its scope's) are under
-    ``hvd.moe_route``, in the forward and in the backward: what the
-    benchmark's ``moe_*`` readers key on through ``profiler.scope_of``. No
-    gather reads a row of width ``D`` for every slot."""
+    """The experts' MLP is four Mosaic calls under ``hvd.moe_experts``,
+    each named ``hvd_moe_*``: ``hvd_moe_mlp_fwd`` forward,
+    ``hvd_moe_mlp_bwd`` and two ``hvd_moe_tgmm`` (the gate's and the up's
+    gradients in one, the down's in the other) backward. The gathers and
+    the kernel of the way back (a call with no name of its own, so its
+    time is its scope's) are under ``hvd.moe_route``, in the forward and in
+    the backward: what the benchmark's ``moe_*`` readers key on through
+    ``profiler.scope_of``. Each call is a jitted function that takes its
+    site's scope. No gather reads a row of width ``D`` for every slot."""
     import re
 
     from horovod_tpu import profiler
@@ -549,22 +703,36 @@ def test_routed_layer_lowers_for_tpu_under_its_scopes():
     # with the value: the gradient of a sum needs no combine of its own
     text = jax.jit(jax.value_and_grad(loss, argnums=range(5))).trace(
         *args).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
-    names = sorted(re.findall(r'kernel_name = "([^"]*)"', text))
-    # the way back is lowered as a function its sites call
-    assert {n for n in names if not n.startswith("hvd_moe_")} == {
-        "_combine_kernel"}
-    assert [n for n in names if n.startswith("hvd_moe_")] == (
-        ["hvd_moe_gmm"] * 6 + ["hvd_moe_tgmm"] * 3)
-    calls = re.findall(r'loc\("([^"]*/pallas_call)"', text)
-    assert len(calls) == 9 and all("hvd.moe_experts" in c for c in calls)
-    way_back = [c + "/pallas_call" for c in re.findall(
-        r'loc\("([^"]*/jit\(_pallas_combine\))"', text)]
-    assert len(way_back) == 2 and not any("hvd_moe_" in c for c in way_back)
+    names = set(re.findall(r'kernel_name = "([^"]*)"', text))
+    assert names == {"_combine_kernel", "hvd_moe_mlp_fwd", "hvd_moe_mlp_bwd",
+                     "hvd_moe_tgmm"}
+    # inside its function a call is named ``<kernel>/pallas_call``; the
+    # function's sites give the scope
+    inner = set(re.findall(r'loc\("([^"]*)/pallas_call"', text))
+    assert inner == names - {"_combine_kernel"}, inner
+    sites = re.findall(r'loc\("([^"]*/jit\(_pallas_\w+\))"', text)
+    kernel_of = {"_pallas_mlp_fwd": "hvd_moe_mlp_fwd",
+                 "_pallas_mlp_bwd": "hvd_moe_mlp_bwd",
+                 "_pallas_tgmm": "hvd_moe_tgmm"}
+    calls, way_back = [], []
+    for site in set(sites):
+        wrapper = re.search(r"jit\((\w+)\)$", site).group(1)
+        if wrapper == "_pallas_combine":
+            way_back.append(site + "/pallas_call")
+        else:
+            assert "hvd.moe_experts" in site, site
+            calls.append(f"{site}/{kernel_of[wrapper]}/pallas_call")
+    assert len(calls) == 3 and len(way_back) == 2, (calls, way_back)
+    assert not any("hvd_moe_" in c for c in way_back)
     kinds = {profiler.scope_of(c, "custom-call") for c in calls + way_back}
-    assert kinds == {("forward", "hvd_moe_gmm"), ("backward", "hvd_moe_gmm"),
+    assert kinds == {("forward", "hvd_moe_mlp_fwd"),
+                     ("backward", "hvd_moe_mlp_bwd"),
                      ("backward", "hvd_moe_tgmm"),
                      ("forward", "hvd.moe_route"),
                      ("backward", "hvd.moe_route")}
+    # three matrices' gradients: the gate's and the up's read ``xs`` once
+    tgmm = re.findall(r"call @(_pallas_tgmm\w*)", text)
+    assert len(tgmm) == 2 and len(set(tgmm)) == 2, tgmm
     gathers = [n for n in re.findall(r'loc\("([^"]*)"', text)
                if n.endswith("/gather")]
     assert any(profiler.scope_of(n) == ("forward", "hvd.moe_route")

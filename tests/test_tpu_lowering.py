@@ -430,6 +430,47 @@ def test_moe_way_back_compiles_for_v5e(one_v5e_chip, tokens, top_k, count,
     assert " gather(" not in text and " while(" not in text
 
 
+@pytest.mark.parametrize("tokens,top_k,count,dim,width", [
+    (8192, 8, 16, 2304, 896),          # mellum2_train_1chip
+    (8192, 8, 32, 2048, 512),          # laguna_train_1chip
+])
+def test_moe_expert_mlp_compiles_for_v5e(one_v5e_chip, tokens, top_k, count,
+                                         dim, width):
+    """Mosaic takes the fused calls of the experts' MLP at both routed
+    cells' shapes, forward and backward: an expert's three matrices
+    beside the tiles, and both of the gate's and the up's float32
+    gradients whole, fit the VMEM asked for, a row's weight comes in and
+    its gradient goes out as rows of 256 lanes, and no XLA operation is
+    left over a ``[rows, D]`` or ``[rows, F]`` array but the casts of the
+    cotangent this test hands in."""
+    from horovod_tpu.parallel import moe
+
+    assert moe._experts_fit(dim, width, 2)
+    rows = moe.buffer_rows(tokens, top_k, count)
+    on_chip = functools.partial(S, sharding=one_v5e_chip)
+    args = (on_chip((rows, dim), jnp.bfloat16), on_chip((rows,), jnp.float32),
+            on_chip((count, dim, width), jnp.float32),
+            on_chip((count, dim, width), jnp.float32),
+            on_chip((count, width, dim), jnp.float32),
+            on_chip((rows // moe.TILE_ROWS,), jnp.int32),
+            on_chip((1,), jnp.int32))
+
+    def both(*a):
+        ys, back = jax.vjp(lambda *d: moe.expert_mlp(*d, *a[5:], False),
+                           *a[:5])
+        return ys, back(ys)
+
+    text = jax.jit(both).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert " while(" not in text
+    entry = text[text.index("\nENTRY "):]
+    wide = [l for l in entry.splitlines()
+            if re.search(r"= \(?bf16\[%d,(%d|%d)\]" % (rows, dim, width), l)
+            and "custom-call" not in l and "parameter(" not in l
+            and "get-tuple-element" not in l and " tuple(" not in l]
+    assert not wide, wide
+
+
 # ------------------------------- the staged backward, after the TPU's compiler
 
 
