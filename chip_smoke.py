@@ -70,51 +70,45 @@ TINY = Sizes(vocab=512, dim=64, depth=2, heads=4, seq=128, batch=2,
              prompt_lens=(3, 9, 17, 30, 41, 64, 77, 100), max_new=8,
              prefill_chunk=16, kernel_elems=5000, mosaic=False)
 
-_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
-_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+def compile_mark():
+    """What the program has booked of its compile pipeline so far
+    (``profiler.book_compiles``, from ``hvd.init``): the backend's compile
+    seconds summed over functions, and the persistent cache's hits and
+    misses. Each step builder here builds one step of its name, so no
+    rebuild resets a series between two marks."""
+    from horovod_tpu.observability import metrics
+
+    snap = metrics.snapshot()
+
+    def total(name, stage=None):
+        samples = snap.get(name, {"samples": {}})["samples"]
+        return sum(v for k, v in samples.items()
+                   if stage is None or f"stage={stage}" in k.split(","))
+
+    return {"compile_s": total("compile_seconds", "compile"),
+            "hits": total("compile_cache_hits"),
+            "misses": total("compile_cache_misses")}
 
 
-class CompileLog:
-    """Executables built (compiled or fetched from the persistent cache)
-    and the seconds that took, as jax.monitoring reports them."""
-
-    def __init__(self):
-        import jax.monitoring as monitoring
-
-        self.built = 0
-        self.hits = 0
-        self.seconds = 0.0
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-        monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, duration, **_):
-        if event == _BACKEND_COMPILE:
-            self.built += 1
-            self.seconds += duration
-
-    def _on_event(self, event, **_):
-        if event == _CACHE_HIT:
-            self.hits += 1
-
-    def mark(self):
-        return (self.built, self.hits, self.seconds)
-
-    def since(self, mark):
-        return (self.built - mark[0], self.hits - mark[1],
-                self.seconds - mark[2])
+def compile_since(mark):
+    now = compile_mark()
+    return {k: now[k] - mark[k] for k in now}
 
 
 @contextlib.contextmanager
-def phase(name, log):
-    """Print one line per phase: wall seconds split into compile (as
-    jax.monitoring reports it) and the rest."""
+def phase(name):
+    """Print one line per phase: wall seconds split into the backend's
+    compile (as the program books it) and the rest."""
     print(f"[{name}] start", flush=True)
-    mark, t0 = log.mark(), time.perf_counter()
+    mark, t0 = compile_mark(), time.perf_counter()
     yield
     wall = time.perf_counter() - t0
-    built, hits, compile_s = log.since(mark)
-    print(f"[{name}] ok compile_s={compile_s:.1f} run_s={wall - compile_s:.1f} "
-          f"executables={built} persistent_cache_hits={hits}", flush=True)
+    got = compile_since(mark)
+    print(f"[{name}] ok compile_s={got['compile_s']:.1f} "
+          f"run_s={wall - got['compile_s']:.1f} "
+          f"persistent_cache_hits={got['hits']:.0f} "
+          f"misses={got['misses']:.0f}", flush=True)
 
 
 def check(cond, msg):
@@ -400,7 +394,7 @@ def teacher_batches(sz, n_chips, count, seed):
     return out
 
 
-def _train_builder(name, step, state, batches, sz, hvd, log):
+def _train_builder(name, step, state, batches, sz, hvd):
     """Warm-up then timed steps through one step builder; returns the
     trained params and every step's loss."""
     import jax
@@ -434,7 +428,7 @@ def _train_builder(name, step, state, batches, sz, hvd, log):
         params, _, opt_state, loss = step(params, {}, opt_state, tokens,
                                           targets)
         losses.append(float(loss))
-    mark, t0 = log.mark(), time.perf_counter()
+    mark, t0 = compile_mark(), time.perf_counter()
     timed = []
     for tokens, targets in batches[sz.warmup:]:
         params, _, opt_state, loss = step(params, {}, opt_state, tokens,
@@ -442,8 +436,9 @@ def _train_builder(name, step, state, batches, sz, hvd, log):
         timed.append(loss)
     jax.block_until_ready((params, timed))
     dt = time.perf_counter() - t0
-    built = log.since(mark)[0]
-    check(built == 0, f"{name}: {built} compilation(s) inside the timed steps")
+    built_s = compile_since(mark)["compile_s"]
+    check(built_s == 0, f"{name}: {built_s:.2f} s of compilation inside the "
+                        f"timed steps")
     losses += [float(x) for x in timed]
     check(all(np.isfinite(losses)), f"{name}: non-finite loss {losses}")
     check(losses[-1] < losses[0],
@@ -457,7 +452,7 @@ def _train_builder(name, step, state, batches, sz, hvd, log):
     return params, losses
 
 
-def phase_train(sz, hvd, model, log):
+def phase_train(sz, hvd, model):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -494,7 +489,7 @@ def phase_train(sz, hvd, model, log):
     _, explicit = _train_builder(
         "make_shardmap_train_step",
         make_shardmap_train_step(model, tx, loss_fn=token_xent),
-        (params, replicate(tx.init(params))), batches, sz, hvd, log)
+        (params, replicate(tx.init(params))), batches, sz, hvd)
 
     # builder 2: one global jit + DistributedOptimizer — what the one-chip
     # benchmark cells and examples/transformer_lm_benchmark.py run
@@ -503,7 +498,7 @@ def phase_train(sz, hvd, model, log):
     params, global_jit = _train_builder(
         "make_jit_train_step+DistributedOptimizer",
         make_jit_train_step(model, dtx, loss_fn=token_xent),
-        (params, replicate(dtx.init(params))), batches, sz, hvd, log)
+        (params, replicate(dtx.init(params))), batches, sz, hvd)
     check(abs(explicit[0] - global_jit[0]) <= 1e-3 * explicit[0],
           f"the two builders disagree on the first loss: {explicit[0]} vs "
           f"{global_jit[0]}")
@@ -532,10 +527,6 @@ def phase_train(sz, hvd, model, log):
     if peak:
         print(f"  peak_bytes_in_use={peak['peak_bytes_in_use']:,} "
               f"(per-chip batch {sz.batch})", flush=True)
-    # what the last traced loss (the evaluation's) keeps for a backward:
-    # the logits in the model's dtype and a float32 log-sum-exp a token
-    print(f"  token_xent_residual_mb="
-          f"{hvd.metrics.value('token_xent_residual_mb')}", flush=True)
     return jax.device_get(params)
 
 
@@ -648,7 +639,6 @@ def main(argv=None):
           f"LIBTPU_INIT_ARGS={os.environ.get('LIBTPU_INIT_ARGS', '')!r}",
           flush=True)
     sz = TINY if args.cpu_rehearsal else FULL
-    log = CompileLog()
     t_start = time.perf_counter()
 
     n = hvd.size()
@@ -660,20 +650,24 @@ def main(argv=None):
         attention_fn=functools.partial(
             flash_attention, use_pallas=True, interpret=not sz.mosaic))
 
-    with phase("kernels", log):
+    with phase("kernels"):
         phase_kernels(sz)
-    with phase("allreduce", log):
+    with phase("allreduce"):
         phase_allreduce(hvd)
-    with phase("train", log):
-        params = phase_train(sz, hvd, model, log)
-    with phase("serve", log):
+    with phase("train"):
+        params = phase_train(sz, hvd, model)
+    with phase("serve"):
         phase_serve(sz, model, params)
     hvd.shutdown()
 
-    built, hits, compile_s = log.since((0, 0, 0.0))
+    total = compile_mark()
+    steps = {k: v for k, v in hvd.metrics.snapshot()["compile_seconds"][
+        "samples"].items() if "fn=other" not in k.split(",")}
     print(f"total wall_s={time.perf_counter() - t_start:.1f} "
-          f"compile_s={compile_s:.1f} executables={built} "
-          f"persistent_cache_hits={hits}", flush=True)
+          f"compile_s={total['compile_s']:.1f} "
+          f"persistent_cache_hits={total['hits']:.0f} "
+          f"misses={total['misses']:.0f}; the steps' latest builds "
+          f"{ {k: round(v, 2) for k, v in steps.items()} }", flush=True)
     print(json.dumps({
         "ok": True,
         "device": {"platform": dev.platform, "kind": dev.device_kind,

@@ -40,6 +40,7 @@ from typing import Optional, Sequence
 import jax
 import numpy as np
 
+from horovod_tpu import profiler as _profiler
 from horovod_tpu.parallel.mesh import build_mesh, DATA_AXIS
 
 logger = logging.getLogger("horovod_tpu")
@@ -77,6 +78,7 @@ _state = _GlobalState()
 _atexit_registered = False
 
 
+@_profiler.annotate("hvd.init", record=True)
 def init(
     mesh: Optional[jax.sharding.Mesh] = None,
     *,
@@ -109,6 +111,8 @@ def init(
       coordinator_address/num_processes/process_id: multi-host wire-up.
       comm: unsupported (MPI communicator in the reference); raises if not None.
     """
+    # set-up's compile pipeline into the metrics registry, once a process
+    _profiler.book_compiles()
     # HOROVOD_XLA_FLAGS_PRESET: arm the async-collective/latency-hiding
     # XLA flags BEFORE the first backend touch below (XLA reads XLA_FLAGS
     # exactly once, at backend creation) — the env-knob spelling of

@@ -22,6 +22,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from horovod_tpu import basics
+from horovod_tpu import profiler as _profiler
 from horovod_tpu.compression import (
     Compression,
     Int8Compressor,
@@ -2056,6 +2057,7 @@ class DistributedGradientTape:
         ).inc(sum(getattr(g, "nbytes", 0) or 0 for g in leaves))
 
 
+@_profiler.annotate("hvd.broadcast_parameters", record=True)
 def broadcast_parameters(params: Any, root_rank: int = 0, *, axis=None):
     """Broadcast a pytree of parameters from root (reference
     ``torch/__init__.py:451-469``, ``tensorflow/__init__.py:126-152``

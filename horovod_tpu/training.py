@@ -56,8 +56,10 @@ _SCOPES = "hvd1"
 
 
 def _named(step):
-    """``step`` under its module name, ``<_SCOPES>_<name>``."""
+    """``step`` under its module name, ``<_SCOPES>_<name>``, its compile
+    pipeline booked under that name (``profiler.book_step``)."""
     step.__name__ = f"{_SCOPES}_{step.__name__}"
+    _profiler.book_step(step.__name__)
     return step
 
 
@@ -90,13 +92,6 @@ def _token_xent_fwd(logits, targets):
     picked = jnp.take_along_axis(
         logits, targets[..., None], axis=-1)[..., 0].astype(jnp.float32)
     lse = top + log_sum
-    if _metrics.enabled():
-        _metrics.gauge(
-            "token_xent_residual_mb",
-            help="MB token_xent keeps for its backward (the logits as they "
-                 "arrived + a float32 log-sum-exp a token), set when the "
-                 "loss is traced",
-        ).set((logits.size * logits.dtype.itemsize + lse.size * 4) / 1e6)
     # (picked - top) first: both are logits, so the loss does not round at
     # the logits' magnitude
     return jnp.mean(log_sum - (picked - top)), (logits, lse, targets)
@@ -128,47 +123,22 @@ class InstrumentedStep:
     histogram of the call-to-call interval (in a donation-throttled async
     pipeline the inter-dispatch interval converges to the true device step
     time — the same steady-state argument ``profiler.timed_steps`` makes),
-    and ``train_examples_per_sec``/``train_mfu`` gauges.
+    and a ``train_examples_per_sec`` gauge.
 
-    MFU uses the existing :func:`horovod_tpu.profiler.device_peak_flops`
-    table; without ``flops_per_step`` (or on untabled devices, e.g. CPU)
-    the gauge is simply not set. Attribute access (``.lower``, AOT
-    compilation, etc.) delegates to the wrapped callable, so the wrapper
-    is transparent to callers that lower/compile the step themselves.
+    Attribute access (``.lower``, AOT compilation, etc.) delegates to the
+    wrapped callable, so the wrapper is transparent to callers that
+    lower/compile the step themselves.
     """
 
     def __init__(self, fn, *, batch_arg: Optional[int] = None,
                  examples_per_step: Optional[int] = None,
-                 flops_per_step: Optional[float] = None,
                  name: str = "train"):
         self._fn = fn
         self._batch_arg = batch_arg
         self._examples = examples_per_step
-        self._flops = flops_per_step
         self._name = name
         self._last_t: Optional[float] = None
         self._step_idx = 0
-        self._peak_total: Optional[float] = None  # n_chips * peak, lazy
-
-    def _peak(self) -> Optional[float]:
-        if self._peak_total is None:
-            try:
-                peak = _profiler.device_peak_flops()
-            except ValueError as e:
-                # a gap in the telemetry table must not stop a training
-                # step; the entry points (benchmarks/run.py, chip_smoke.py)
-                # raise on a device kind with no peak
-                import logging
-
-                logging.getLogger("horovod_tpu").warning(
-                    "%s_mfu will not be reported: %s", self._name, e)
-                peak = None
-            try:
-                n = basics.size()
-            except RuntimeError:
-                n = len(jax.devices())
-            self._peak_total = (peak or 0.0) * n
-        return self._peak_total or None
 
     def __call__(self, *args, **kwargs):
         # host spans on the profiler's clock (recorded only while a
@@ -234,13 +204,6 @@ class InstrumentedStep:
                         f"{name}_examples_per_sec",
                         help="throughput over the last step interval",
                     ).set(examples / dt)
-                if self._flops:
-                    peak = self._peak()
-                    if peak:
-                        _metrics.gauge(
-                            f"{name}_mfu",
-                            help="model FLOP utilization vs device peak",
-                        ).set(self._flops / dt / peak)
                 # SLO plane: the step interval is the step_time series
                 # (counted in steps, not wall clock), and the
                 # gauge-sourced objectives (subscriber staleness, input
@@ -265,16 +228,13 @@ class InstrumentedStep:
 
 def instrument_step(fn, *, batch_arg: Optional[int] = None,
                     examples_per_step: Optional[int] = None,
-                    flops_per_step: Optional[float] = None,
                     name: str = "train"):
     """Public spelling of the step wrapper: a caller that compiles its own
-    step wraps it here, with the per-step FLOPs if ``train_mfu`` should
-    land in the registry (``examples/transformer_lm_benchmark.py``); the
-    ``make_*_train_step`` builders apply it automatically
-    (``instrument=False`` opts out)."""
+    step wraps it here; the ``make_*_train_step`` builders apply it
+    automatically (``instrument=False`` opts out)."""
     return InstrumentedStep(
         fn, batch_arg=batch_arg, examples_per_step=examples_per_step,
-        flops_per_step=flops_per_step, name=name,
+        name=name,
     )
 
 

@@ -77,23 +77,6 @@ def test_peak_lookup_raises_for_unknown_kind_on_tpu(monkeypatch):
     assert profiler.device_peak_hbm_bytes("TPU v5e") == 819e9
 
 
-def test_instrumented_step_skips_mfu_loudly_for_unknown_kind(
-        monkeypatch, caplog):
-    """The lookup raises for the entry points; inside a user's train step
-    a gap in the table costs the MFU gauge and one warning, not the step."""
-    from horovod_tpu.training import instrument_step
-
-    def unknown(kind=None):
-        raise ValueError("no peak entry for TPU device_kind 'TPU v9 hyper'")
-
-    monkeypatch.setattr(profiler, "device_peak_flops", unknown)
-    step = instrument_step(lambda x: x + 1, flops_per_step=1e9)
-    with caplog.at_level("WARNING", logger="horovod_tpu"):
-        assert [step(i) for i in range(3)] == [1, 2, 3]
-    said = [r for r in caplog.records if "TPU v9 hyper" in r.getMessage()]
-    assert len(said) == 1 and "train_mfu" in said[0].getMessage()
-
-
 # ------------------------------------------------- entry points off the chip
 
 

@@ -72,6 +72,10 @@ def test_core_duplicate_name(hvd_core):
     _state.core.cycle_time_ms = 500  # hold the cycle open
     n = hvd.size()
     x = stacked(hvd, np.ones((n, 2), dtype=np.float32))
+    # the loop reads the cycle time as it starts a sleep: let the 2 ms one
+    # it is in end, or a loaded host can see the first name negotiated and
+    # done before the second is enqueued
+    time.sleep(0.05)
     h = hvd.allreduce_async(x, op=hvd.Sum, name="core.dup")
     with pytest.raises(ValueError, match="Duplicate tensor name"):
         hvd.allreduce_async(x, op=hvd.Sum, name="core.dup")
