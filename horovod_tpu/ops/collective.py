@@ -275,11 +275,22 @@ def _hostlocal_mode(x) -> bool:
     return basics.process_size() > 1 and not hostlocal.is_global_array(x)
 
 
+def _named_sharding(x) -> Optional[NamedSharding]:
+    """x's ``NamedSharding``, or None: for a NumPy array, a scalar, another
+    kind of sharding, and a tracer. A tracer is answered by its type — its
+    ``.sharding`` raises, and JAX builds that error by walking every
+    equation traced so far, so a probe per leaf grew with the program."""
+    if _is_tracer(x):
+        return None
+    sharding = getattr(x, "sharding", None)
+    return sharding if isinstance(sharding, NamedSharding) else None
+
+
 def _is_stacked(x, axis) -> bool:
     """True iff x's leading dim is the per-rank axis sharded over `axis`
     (any member of it, for a multi-axis tuple)."""
-    sharding = getattr(x, "sharding", None)
-    if not isinstance(sharding, NamedSharding):
+    sharding = _named_sharding(x)
+    if sharding is None:
         return False
     spec = sharding.spec
     if not spec or spec[0] is None:

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import multihost_utils
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from horovod_tpu import basics
 
@@ -37,8 +37,9 @@ from horovod_tpu import basics
 def is_global_array(x) -> bool:
     """True iff x is a jax.Array already placed on the global mesh (the SPMD
     path); host-local numpy/scalars and single-device arrays are 'mine'."""
-    sharding = getattr(x, "sharding", None)
-    return isinstance(sharding, NamedSharding)
+    from horovod_tpu.ops import collective as C
+
+    return C._named_sharding(x) is not None
 
 
 def _stack_local(x, ax: str):

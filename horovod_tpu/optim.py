@@ -2085,7 +2085,7 @@ def is_sharded_state_leaf(x, *, axis=None) -> bool:
     root's value over them would blow each rank's 1/N moment shard back up
     to root's copy and destroy the sharding."""
     ax = _C._axis(axis)
-    return hasattr(x, "sharding") and _C._is_stacked(x, ax)
+    return _C._is_stacked(x, ax)
 
 
 def broadcast_optimizer_state(opt_state: Any, root_rank: int = 0, *, axis=None):

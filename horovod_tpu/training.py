@@ -37,7 +37,7 @@ from horovod_tpu.observability import regression as _regression
 from horovod_tpu.observability import slo as _slo
 from horovod_tpu.observability import straggler as _straggler
 from horovod_tpu.ops.collective import (
-    Average, allreduce, _mesh_axis_size, _smap, _sync_scope,
+    Average, allreduce, _mesh_axis_size, _named_sharding, _smap, _sync_scope,
 )
 from horovod_tpu.ops import overlap as _overlap
 from horovod_tpu.compression import Compression
@@ -832,12 +832,8 @@ def _shard_dim0_tree(tree, axis: Optional[str]):
 
     def place(path, x):
         shape = getattr(x, "shape", ())
-        existing = getattr(x, "sharding", None)
-        spec = (
-            list(existing.spec)
-            if isinstance(existing, NamedSharding) and existing.spec
-            else []
-        )
+        existing = _named_sharding(x)
+        spec = list(existing.spec) if existing is not None else []
         spec += [None] * (len(shape) - len(spec))
         ax_parts = set(ax) if isinstance(ax, tuple) else {ax}
         ax_used = any(ax_parts & set(_axes_in(e)) for e in spec)
