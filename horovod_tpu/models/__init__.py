@@ -21,6 +21,7 @@ from horovod_tpu.models.mnist import MnistCNN  # noqa: F401
 from horovod_tpu.models.mlp import MLP  # noqa: F401
 from horovod_tpu.models.transformer import (  # noqa: F401
     Experts,
+    GatedDelta,
     Layer,
     SwiGLU,
     TransformerLM,

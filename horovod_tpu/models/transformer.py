@@ -16,6 +16,7 @@ ring attention inside a ``shard_map`` over the ``seq`` axis.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Optional, Tuple, Union
 
@@ -71,8 +72,9 @@ class Experts:
     scores; the weights stay the router's. ``scale`` multiplies the routed
     sum (a model's routed scaling factor). ``shared`` is the
     width of a SwiGLU expert every token passes through, added to the
-    routed sum unweighted: it is computed whole wherever the layer is, so
-    across the holders of a layer's experts it counts once."""
+    routed sum unweighted, or with ``shared_gate`` times ``sigmoid(h
+    w_sg)``, ``w_sg`` ``[dim, 1]``: it is computed whole wherever the layer
+    is, so across the holders of a layer's experts it counts once."""
 
     routed: int
     top_k: int
@@ -82,6 +84,23 @@ class Experts:
     select: Optional[Callable] = None
     scale: float = 1.0
     shared: Optional[int] = None
+    shared_gate: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class GatedDelta:
+    """A Gated DeltaNet token mixer (:mod:`horovod_tpu.ops.gated_delta`) in
+    place of a block's attention: ``key_heads`` heads of ``key_dim`` for q
+    and k, each serving ``value_heads / key_heads`` value heads of
+    ``value_dim``, a causal depthwise convolution of ``conv`` taps over
+    ``[q | k | v]``; the projections laid out as the published checkpoints
+    lay them (``in_proj_qkvz``, ``in_proj_ba``, ``out_proj``)."""
+
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    conv: int = 4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,23 +108,83 @@ class Layer:
     """One block of a :class:`TransformerLM` built from a per-layer
     description (``TransformerLM(layers=...)``): its attention (``heads``
     query heads on ``kv_heads`` K/V heads of ``head_dim``, separate
-    bias-free q/k/v projections; rotary with ``rope_base`` and optional
-    ``yarn`` over the first ``rotary_dim`` features of a head, default all;
-    causal, within ``window`` positions where set; with ``gate`` each
-    head's output times ``sigmoid(h W_g)``, ``W_g`` ``[dim, heads]``, before
-    the output projection: the head-wise gate of arXiv:2505.06708) and its
-    FFN (a GELU MLP ``mlp_ratio`` x dim wide, a :class:`SwiGLU` MLP, or
+    bias-free q/k/v projections; with ``qk_norm`` q and k each normalised
+    over the head by the model's ``norm``; rotary with ``rope_base`` and
+    optional ``yarn`` over the first ``rotary_dim`` features of a head,
+    default all; causal, within ``window`` positions where set; a ``gate``
+    of arXiv:2505.06708 on the attention's output before the output
+    projection: ``"head"`` each head's output times
+    ``sigmoid(h W_g)``, ``W_g`` ``[dim, heads]``; ``"element"`` each
+    feature times the sigmoid of a second half of the head's query
+    projection, ``[q | gate]`` a head) or, where ``mixer`` is given, a
+    :class:`GatedDelta` in its place; and its FFN (a GELU MLP
+    ``mlp_ratio`` x dim wide, a :class:`SwiGLU` MLP, or
     :class:`Experts`)."""
 
-    heads: int
-    head_dim: int
+    heads: int = 0
+    head_dim: int = 0
     kv_heads: Optional[int] = None
     rope_base: float = 10000.0
     yarn: Optional[Yarn] = None
     window: Optional[int] = None
     ffn: Union[int, SwiGLU, Experts] = 4
     rotary_dim: Optional[int] = None
-    gate: bool = False
+    gate: Optional[str] = None
+    qk_norm: bool = False
+    mixer: Optional[GatedDelta] = None
+
+    def __post_init__(self):
+        # a configuration's boolean key (Laguna's ``gating``) passes
+        # ``True`` / ``False``: the head-wise gate, or none
+        if isinstance(self.gate, bool):
+            object.__setattr__(self, "gate", "head" if self.gate else None)
+        if self.gate not in _GATES:
+            raise ValueError(
+                f"gate must be one of {_GATES}, got {self.gate!r}")
+
+
+_GATES = (None, "head", "element")
+
+#: a norm of the ``1 + w`` form (``w`` zeros at start):
+#: ``x rsqrt(mean(x^2) + eps) (1 + w)``
+ZERO_CENTRED = "zero_centred_rmsnorm"
+
+
+def refuse_training_only(model, what: str):
+    """Raise where ``what`` (an entry point other than training) is handed
+    a :class:`TransformerLM` with parts only the training path computes."""
+    layers = getattr(model, "layers", None) or ()
+    _refuse_forms(what, getattr(model, "norm", None), [
+        part for i, layer in enumerate(layers) for part in _training_only(
+            f"block{i}", layer.mixer, layer.qk_norm, layer.gate, layer.ffn)])
+
+
+def _training_only(name, mixer, qk_norm, gate, ffn):
+    """A block's parts that only the training path computes, named: a
+    :class:`GatedDelta` mixer, q/k norms, the element-wise gate, a gated
+    shared expert."""
+    found = []
+    if mixer is not None:
+        found.append(f"{name}: the gated-delta layer {mixer}")
+    if qk_norm:
+        found.append(f"{name}: qk_norm=True")
+    if gate == "element":
+        found.append(f"{name}: gate='element'")
+    if isinstance(ffn, Experts) and ffn.shared_gate:
+        found.append(f"{name}: Experts(shared_gate=True)")
+    return found
+
+
+def _refuse_forms(what, norm, found):
+    """Raise, naming each, where ``found`` (:func:`_training_only`'s parts)
+    is not empty or ``norm`` is the zero-centred one."""
+    if norm == ZERO_CENTRED:
+        found = [f"norm={ZERO_CENTRED!r}"] + found
+    if found:
+        raise ValueError(
+            f"{what} has no path for {'; '.join(found)}: a gated-delta "
+            "layer keeps a recurrent state, not K/V, and these forms are "
+            "computed by TransformerLM's training-shape call only")
 
 
 def apply_rope(x, positions, *, base: float = 10000.0,
@@ -209,16 +288,24 @@ class TransformerBlock(nn.Module):
     window: Optional[int] = None
     experts: Optional[Experts] = None
     rotary_dim: Optional[int] = None
-    gate: bool = False
+    gate: Optional[str] = None  # Layer's: "head" or "element"
     swiglu: Optional[SwiGLU] = None
-    norm: str = "layernorm"  # or "rmsnorm"
+    norm: str = "layernorm"  # or "rmsnorm", or ZERO_CENTRED
     norm_eps: float = 1e-6
+    qk_norm: bool = False
+    mixer: Optional[GatedDelta] = None
 
     def _norm(self, name):
         return make_norm(self.norm, self.norm_eps, self.dtype, name)
 
     @nn.compact
     def __call__(self, x, positions=None, page_table=None):
+        if self.decode:
+            _refuse_forms("kv-cache decoding", self.norm, _training_only(
+                self.name, self.mixer, self.qk_norm, self.gate, self.experts))
+        if self.mixer is not None:
+            x = x + self._gated_delta(self._norm("ln1")(x))
+            return x + self._ffn(self._norm("ln2")(x))
         if self.decode and (self.window is not None
                             or self.experts is not None
                             or self.rotary_dim is not None or self.gate
@@ -236,7 +323,8 @@ class TransformerBlock(nn.Module):
             q, k, v = (
                 nn.Dense(n * head_dim, use_bias=False, dtype=self.dtype,
                          name=name)(h)
-                for name, n in (("q_proj", self.heads), ("k_proj", h_kv),
+                for name, n in (("q_proj", self.heads * (
+                    2 if self.gate == "element" else 1)), ("k_proj", h_kv),
                                 ("v_proj", h_kv)))
         elif h_kv == self.heads:
             qkv = nn.Dense(3 * self.dim, use_bias=False, dtype=self.dtype,
@@ -253,7 +341,13 @@ class TransformerBlock(nn.Module):
             k, v = jnp.split(kv, 2, axis=-1)
         split_q = lambda t: t.reshape(*t.shape[:2], self.heads, head_dim)
         split_kv = lambda t: t.reshape(*t.shape[:2], h_kv, head_dim)
+        if self.gate == "element":
+            # a head's projection is its query, then its gate
+            q, gate = jnp.split(q.reshape(*q.shape[:2], self.heads,
+                                          2 * head_dim), 2, axis=-1)
         q, k, v = split_q(q), split_kv(k), split_kv(v)
+        if self.qk_norm:
+            q, k = self._norm("q_norm")(q), self._norm("k_norm")(k)
         if self.use_rope:
             if positions is None:
                 # a silent local-arange fallback would be wrong under SP
@@ -332,7 +426,9 @@ class TransformerBlock(nn.Module):
             att = self.attention_fn(q, k, v, causal=True, window=self.window)
         else:
             att = self.attention_fn(q, k, v, causal=True)
-        if self.gate:
+        if self.gate == "element":
+            att = att * jax.nn.sigmoid(gate).astype(att.dtype)
+        elif self.gate == "head":
             # one sigmoid a head from the block's normalised input
             g = nn.Dense(self.heads, use_bias=False, dtype=self.dtype,
                          name="gate_proj")(h)
@@ -341,16 +437,49 @@ class TransformerBlock(nn.Module):
         x = x + nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
                          name="proj")(att)
 
-        h = self._norm("ln2")(x)
+        return x + self._ffn(self._norm("ln2")(x))
+
+    def _ffn(self, h):
+        """What the block's MLP, SwiGLU MLP or routed experts add."""
         if self.experts is not None:
-            return x + self._routed(h)
+            return self._routed(h)
         if self.swiglu is not None:
-            return x + self._swiglu(h, self.swiglu.width, "mlp")
+            return self._swiglu(h, self.swiglu.width, "mlp")
         h = nn.Dense(self.mlp_ratio * self.dim, dtype=self.dtype,
                      name="mlp_up")(h)
         h = nn.gelu(h)
-        h = nn.Dense(self.dim, dtype=self.dtype, name="mlp_down")(h)
-        return x + h
+        return nn.Dense(self.dim, dtype=self.dtype, name="mlp_down")(h)
+
+    def _gated_delta(self, h):
+        """What the block's :class:`GatedDelta` mixer adds: the in- and
+        out-projections in ``dtype`` around
+        :func:`~horovod_tpu.ops.gated_delta.gated_delta_mixer` (float32,
+        under ``hvd.gdn``). Parameters as the published checkpoints name
+        them; ``conv1d`` ``[channels, taps]`` at normal(0.02), as the
+        published code draws every convolution, ``A_log`` ``log U(0, 16)``,
+        ``dt_bias`` and the output norm's ``norm_scale`` ones."""
+        from horovod_tpu.ops.gated_delta import gated_delta_mixer
+
+        m = self.mixer
+        r = m.value_heads // m.key_heads
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        qkvz = dense(m.key_heads * (2 * m.key_dim + 2 * r * m.value_dim),
+                     name="in_proj_qkvz")(h)
+        ba = dense(2 * m.value_heads, name="in_proj_ba")(h)
+        channels = 2 * m.key_heads * m.key_dim + m.value_heads * m.value_dim
+        conv = self.param("conv1d", nn.initializers.normal(0.02),
+                          (channels, m.conv))
+        a_log = self.param(
+            "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                key, shape, jnp.float32, 0.0, 16.0)), (m.value_heads,))
+        dt_bias = self.param("dt_bias", nn.initializers.ones,
+                             (m.value_heads,))
+        norm = self.param("norm_scale", nn.initializers.ones, (m.value_dim,))
+        with jax.named_scope("hvd.gdn"):
+            y = gated_delta_mixer(
+                qkvz, ba, conv, a_log, dt_bias, norm, key_heads=m.key_heads,
+                key_dim=m.key_dim, value_dim=m.value_dim, eps=self.norm_eps)
+        return dense(self.dim, name="out_proj")(y)
 
     def _swiglu(self, h, width, prefix):
         """``(silu(h W_gate) * (h W_up)) W_down``, bias-free, in ``dtype``:
@@ -390,18 +519,43 @@ class TransformerBlock(nn.Module):
         if e.shared is not None:
             # every token's own expert: it waits for nothing of the routing
             with jax.named_scope("hvd.moe_shared"):
-                y = y + self._swiglu(h, e.shared, "shared")
+                shared = self._swiglu(h, e.shared, "shared")
+                if e.shared_gate:
+                    shared = shared * jax.nn.sigmoid(nn.Dense(
+                        1, use_bias=False, dtype=self.dtype,
+                        name="shared_expert_gate")(h))
+                y = y + shared
         return y
 
 
+class ZeroCentredRMSNorm(nn.Module):
+    """``x rsqrt(mean(x^2) + eps) (1 + scale)`` over the last axis, in
+    float32, ``scale`` zeros at start (Qwen3-Next's norm)."""
+
+    epsilon: float
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.zeros, (x.shape[-1],))
+        x = x.astype(jnp.float32)
+        x = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True)
+                              + self.epsilon)
+        return (x * (1.0 + scale)).astype(self.dtype)
+
+
 def make_norm(kind: str, eps: float, dtype, name: str):
-    """A block's (or the model's last) normalisation: flax's LayerNorm, or
-    RMSNorm (``x rsqrt(mean(x^2) + eps) scale``, no bias, no mean)."""
+    """A block's (or the model's last) normalisation: flax's LayerNorm,
+    RMSNorm (``x rsqrt(mean(x^2) + eps) scale``, no bias, no mean) or
+    :class:`ZeroCentredRMSNorm` (``ZERO_CENTRED``)."""
     if kind == "layernorm":
         return nn.LayerNorm(epsilon=eps, dtype=dtype, name=name)
     if kind == "rmsnorm":
         return nn.RMSNorm(epsilon=eps, dtype=dtype, name=name)
-    raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', got {kind!r}")
+    if kind == ZERO_CENTRED:
+        return ZeroCentredRMSNorm(epsilon=eps, dtype=dtype, name=name)
+    raise ValueError(f"norm must be 'layernorm', 'rmsnorm' or "
+                     f"{ZERO_CENTRED!r}, got {kind!r}")
 
 
 class TransformerLM(nn.Module):
@@ -458,7 +612,8 @@ class TransformerLM(nn.Module):
             head_dim=layer.head_dim, yarn=layer.yarn, window=layer.window,
             experts=layer.ffn if routed else None,
             swiglu=layer.ffn if swiglu else None,
-            rotary_dim=layer.rotary_dim, gate=layer.gate)
+            rotary_dim=layer.rotary_dim, gate=layer.gate,
+            qk_norm=layer.qk_norm, mixer=layer.mixer)
 
     @nn.compact
     def __call__(self, tokens, positions=None, train: bool = True,
@@ -476,6 +631,15 @@ class TransformerLM(nn.Module):
                 f"pos_embedding must be 'learned' or 'rope', "
                 f"got {self.pos_embedding!r}"
             )
+        if self.layers is not None:
+            for i, layer in enumerate(self.layers):
+                if (layer.mixer is None) == (layer.heads < 1
+                                             or layer.head_dim < 1):
+                    raise ValueError(
+                        f"layers[{i}] gives attention's heads and head_dim "
+                        "or a mixer, one of the two: got heads="
+                        f"{layer.heads}, head_dim={layer.head_dim}, "
+                        f"mixer={layer.mixer}")
         head_dims = ([self.dim // self.heads] if self.layers is None
                      else [layer.head_dim for layer in self.layers])
         if self.pos_embedding == "rope" and any(d % 2 for d in head_dims):
@@ -535,6 +699,11 @@ def TransformerSmall(**kw):
     return TransformerLM(**kw)
 
 
+#: a gated-delta block's parameters besides its norms
+_GATED_DELTA_PARAMS = ("in_proj_qkvz", "in_proj_ba", "conv1d", "A_log",
+                       "dt_bias", "norm_scale", "out_proj")
+
+
 def transformer_param_specs(params, model_axis: str = "model"):
     """Tensor-parallel PartitionSpecs for a TransformerLM param tree
     (Megatron-style: qkv/up-proj sharded on the output dim, proj/down-proj on
@@ -545,6 +714,18 @@ def transformer_param_specs(params, model_axis: str = "model"):
     def spec_for(path, leaf):
         names = [getattr(p, "key", str(p)) for p in path]
         name = "/".join(names)
+        if any(n in _GATED_DELTA_PARAMS for n in names):
+            raise ValueError(
+                "transformer_param_specs has no layout for the gated-delta "
+                f"layer ({name}): its heads split over the in- and "
+                "out-projections' grouped columns, which this function does "
+                "not describe")
+        if any(n in ("q_norm", "k_norm", "shared_expert_gate")
+               for n in names):
+            raise ValueError(
+                "transformer_param_specs has no layout for a q/k norm or a "
+                f"gated shared expert ({name}): no model it describes has "
+                "one")
         if "router" in names or any(n.startswith("experts_") for n in names):
             raise ValueError(
                 "transformer_param_specs has no layout for a routed-expert "
@@ -603,6 +784,12 @@ def tp_block_apply(block_params, x, *, heads: int, axis: str = "tp"):
     """
     from horovod_tpu.ops.collective import _axis_size
 
+    found = sorted(set(block_params) & set(
+        _GATED_DELTA_PARAMS + ("q_norm", "k_norm")))
+    if found:
+        raise ValueError(
+            "tp_block_apply handles softmax-attention blocks only: "
+            f"{found} belong to the gated-delta layer or to q/k norms")
     if "qkv" not in block_params:
         raise ValueError(
             "tp_block_apply requires a fused qkv kernel (kv_heads unset "
@@ -700,6 +887,7 @@ def generate(model: TransformerLM, params, prompt, *, max_new_tokens: int,
     """
     import dataclasses
 
+    refuse_training_only(model, "generate()")
     if temperature > 0.0 and rng is None:
         raise ValueError("temperature > 0 needs an rng key")
     if max_new_tokens < 1:

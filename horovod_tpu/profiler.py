@@ -45,8 +45,11 @@ Device scopes, as they read in an instruction's ``op_name``:
   token (a ``pallas_call`` with no name); ``hvd.moe_experts`` — its grouped
   matrix products and the activation between them; ``hvd.moe_shared`` —
   the shared expert every token passes through beside its routed ones
-  (plain matrix products). All cover the forward and, in or under a
-  ``transpose(...)`` component, the backward
+  (plain matrix products); ``hvd.gdn`` — a Gated DeltaNet layer's work
+  between its in- and out-projections (the causal convolution, the q/k
+  normalisations, beta and the decays, the chunked delta rule and its
+  ``while`` over the chunks, the gated output norm). All cover the forward
+  and, in or under a ``transpose(...)`` component, the backward
 - ``hvd_<kernel>`` — one ``pallas_call`` (also the Mosaic ``kernel_name``):
   ``hvd_flash_fwd``; ``hvd_moe_gmm`` (a row tile of the sorted buffer times
   its expert's matrix, or its transpose), ``hvd_moe_mlp_fwd`` (a row tile
@@ -61,7 +64,8 @@ Trace-time gauges say what a trace chose: ``flash_fwd_tile`` /
 routed layer's sorted buffer: the worst case), ``moe_combine_tile`` (the
 tile of the kernel that sums the rows back; absent where the gather ran),
 ``moe_experts_fused`` (1 where the experts' activation and weighting were
-traced inside the grouped products' kernels). ``moe_local_rows`` is a
+traced inside the grouped products' kernels), ``gdn_chunk`` / ``gdn_chunks``
+(tokens a chunk of the gated delta rule, and chunks a row). ``moe_local_rows`` is a
 step's counter (the assignments that landed on the experts held here: what
 the grouped products' time follows), set by ``parallel.moe.record_rows``
 from the step's ``batch_stats``.
@@ -316,11 +320,12 @@ def scope_of(op_name: str, kind: str = ""):
     (``hvd.forward`` in or under a ``transpose(...)`` component: the
     transposed pass, and what ``jax.checkpoint`` recomputes during it),
     ``"forward"`` (any other ``hvd.forward``), else ``None``. ``kernel`` is
-    the innermost ``hvd.flash_*`` / ``hvd.moe_*`` / ``hvd_<kernel>``
-    component, else ``None``."""
+    the innermost ``hvd.flash_*`` / ``hvd.moe_*`` / ``hvd.gdn`` /
+    ``hvd_<kernel>`` component, else ``None``."""
     parts = _components(op_name)
     kernel = next((p for p in reversed(parts)
-                   if p.startswith(("hvd.flash_", "hvd.moe_", "hvd_"))), None)
+                   if p.startswith(("hvd.flash_", "hvd.moe_", "hvd.gdn",
+                                    "hvd_"))), None)
     if kind.startswith(_COLLECTIVE_KINDS) or \
             any("hvd.sync" in p for p in parts):
         return "sync", kernel

@@ -160,6 +160,9 @@ class InferenceEngine:
                  tp_axis: Optional[str] = None):
         import jax
 
+        from horovod_tpu.models.transformer import refuse_training_only
+
+        refuse_training_only(model, "InferenceEngine")
         self._model = model
         self.page_size = int(page_size if page_size is not None
                              else _env_int(PAGE_SIZE_ENV, 16))

@@ -908,6 +908,9 @@ def split_transformer_for_pp(model, params, n_stages: int, *,
     Returns ``{"embed": …, "stages": stacked, "head": …}`` — the input to
     :func:`make_transformer_pp_train_step`.
     """
+    from horovod_tpu.models.transformer import refuse_training_only
+
+    refuse_training_only(model, "split_transformer_for_pp")
     n_total = n_stages * interleaved_v
     if model.depth % n_total != 0:
         raise ValueError(

@@ -12,7 +12,8 @@ import pytest
 pytest.register_assert_rewrite("benchmarks.tests.test_check",
                                "benchmarks.tests.test_correct",
                                "benchmarks.tests.test_mellum",
-                               "benchmarks.tests.test_laguna")
+                               "benchmarks.tests.test_laguna",
+                               "benchmarks.tests.test_qwen3next")
 
 from benchmarks.tests.test_check import (  # noqa: E402,F401
     test_a_second_four_chip_cell_needs_eight_cells,
@@ -36,6 +37,11 @@ from benchmarks.tests.test_mellum import (  # noqa: E402,F401
     test_broken_mellum_timed_path_is_not_correct,
     test_mellum_control_is_not_correct,
     test_unbroken_mellum_run_is_correct,
+)
+from benchmarks.tests.test_qwen3next import (  # noqa: E402,F401
+    test_broken_qwen3next_timed_path_is_not_correct,
+    test_qwen3next_control_is_not_correct,
+    test_unbroken_qwen3next_run_is_correct,
 )
 
 

@@ -292,7 +292,7 @@ def test_decode_refuses_the_new_blocks_by_name():
     for field, layer in (
             ("rotary_dim=8", models.Layer(heads=2, head_dim=16,
                                           rotary_dim=8)),
-            ("gate=True", models.Layer(heads=2, head_dim=16, gate=True)),
+            ("gate=head", models.Layer(heads=2, head_dim=16, gate=True)),
             ("ffn=SwiGLU(width=96)", models.Layer(
                 heads=2, head_dim=16, ffn=models.SwiGLU(96)))):
         block = _block(layer, decode=True)

@@ -157,6 +157,13 @@ def test_scope_table_holds_the_live_step(hvd):
      ("backward", "hvd.flash_bwd")),
     (_BWD + "/hvd.flash_bwd/transpose", "fusion",
      ("backward", "hvd.flash_bwd")),
+    # a Gated DeltaNet layer's work between its projections, and the scan
+    # over its chunks, in both passes (gdn_ms.train keys on it)
+    (_FWD + "/block0/hvd.gdn/while/body/dot_general", "fusion",
+     ("forward", "hvd.gdn")),
+    (_BWD + "/block0/hvd.gdn/while/body/transpose", "fusion",
+     ("backward", "hvd.gdn")),
+    (_FWD + "/block0/hvd.gdn/while", "while", ("forward", "hvd.gdn")),
     ("jit(step)/hvd.optimizer/mul", "fusion", ("optimizer", None)),
     ("jit(step)/hvd.optimizer/hvd_fused_adam/pallas_call", "custom-call",
      ("optimizer", "hvd_fused_adam")),
@@ -185,6 +192,38 @@ def test_scope_table_holds_the_live_step(hvd):
 ])
 def test_scope_of_precedence(op_name, kind, want):
     assert profiler.scope_of(op_name, kind) == want
+
+
+def test_gated_delta_mixer_runs_under_its_scope_in_both_passes():
+    """``hvd.gdn`` is the innermost ``hvd.*`` scope of everything between a
+    Gated DeltaNet layer's in- and out-projections — the convolution, the
+    norms, the decays and the scan over the chunks — forward and in the
+    transposed pass, and of neither projection."""
+    from horovod_tpu import models
+    from horovod_tpu.models.transformer import TransformerBlock
+
+    block = TransformerBlock(**models.TransformerLM(
+        vocab=8, dim=64, depth=1, heads=1, pos_embedding="rope",
+        layers=(models.Layer(mixer=models.GatedDelta(1, 2, 16, 16), ffn=1),),
+        dtype=jnp.float32).block_config(0))
+    x = jnp.zeros((1, 80, 64))
+    pos = jnp.arange(80)[None]
+    params = block.init(jax.random.PRNGKey(0), x, positions=pos)["params"]
+
+    @jax.named_scope("hvd.forward")
+    def loss(p):
+        return block.apply({"params": p}, x, positions=pos).sum()
+
+    text = jax.jit(jax.grad(loss)).lower(params).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]*hvd\.gdn[^"]*)"', text))
+    scopes = {profiler.scope_of(n) for n in names}
+    assert {("forward", "hvd.gdn"), ("backward", "hvd.gdn")} <= scopes
+    assert {k for _, k in scopes} == {"hvd.gdn"}
+    assert any("while" in n and "transpose(" in n for n in names)
+    assert any("while" in n and "transpose(" not in n for n in names)
+    projections = set(re.findall(
+        r'loc\("([^"]*(?:in_proj_qkvz|out_proj)[^"]*dot_general)"', text))
+    assert projections and not any("hvd.gdn" in n for n in projections)
 
 
 @pytest.mark.parametrize("builder,module", [
@@ -284,7 +323,7 @@ def test_benchmark_manifest_check_passes():
         [sys.executable, os.path.join(_ROOT, "benchmarks", "run.py"),
          "--check"], cwd=_ROOT, capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stderr
-    assert "check ok: 5 cell(s)" in out.stdout
+    assert "check ok: 6 cell(s)" in out.stdout
     with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     new = ["phase_forward_ms.train", "phase_backward_ms.train",
