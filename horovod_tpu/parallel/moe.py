@@ -185,15 +185,15 @@ TILE_ROWS = 256
 #: whole and the result tile, double-buffered
 _VMEM_LIMIT = 48 << 20
 #: tokens one grid step of the way back (:func:`_pallas_combine`) sums, and
-#: the rows of one block it reads of the sorted buffer for them: the rows a
-#: token tile has on one held expert follow each other there, 32 on average
-#: at 256 tokens and top-8 of 64, so one window a (tile, expert) as a rule
+#: the most rows of one block it reads of the sorted buffer for them: the
+#: rows a token tile has on one held expert follow each other there, so one
+#: window a (tile, expert) as a rule (:func:`_combine_tile`)
 TOKEN_TILE = 256
 WINDOW_ROWS = 64
-#: windows one product of the way back takes at once: the experts in groups
-#: of so many, a window each, ``[TOKEN_TILE, group x window]`` times
-#: ``[group x window, D]``
-_WINDOW_GROUP = 16
+#: rows one product of the way back takes at once: the held experts in
+#: groups, a window each, ``[TOKEN_TILE, group x window]`` times ``[group x
+#: window, D]``
+_PRODUCT_ROWS = 1024
 
 
 def route_top_k(x, router, top_k: int, select=None):
@@ -298,22 +298,23 @@ def _plan(experts, *, first: int, count: int):
     }
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _to_rows(x, plan, top_k: int, interpret: bool):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _to_rows(x, plan, top_k: int, routed: int, interpret: bool):
     """``x`` ``[T, ...]`` -> ``[rows, ...]``: each buffer row its slot's
     token's row of ``x`` (token 0's for a padding row). ``plan`` is
-    :func:`_plan`'s. Its transpose is no scatter-add (:func:`_to_tokens`):
-    one of as many rows takes ten times a gather's time on the chip."""
+    :func:`_plan`'s over ``routed`` experts. Its transpose is no scatter-add
+    (:func:`_to_tokens`): one of as many rows takes ten times a gather's
+    time on the chip."""
     return x[jnp.minimum(plan["slot_of_row"] // top_k, x.shape[0] - 1)]
 
 
-def _to_rows_fwd(x, plan, top_k, interpret):
-    return _to_rows(x, plan, top_k, interpret), plan
+def _to_rows_fwd(x, plan, top_k, routed, interpret):
+    return _to_rows(x, plan, top_k, routed, interpret), plan
 
 
 @jax.named_scope("hvd.moe_route")
-def _to_rows_bwd(top_k, interpret, plan, g):
-    return _to_tokens(g, plan, top_k, interpret), None
+def _to_rows_bwd(top_k, routed, interpret, plan, g):
+    return _to_tokens(g, plan, top_k, routed, interpret), None
 
 
 _to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
@@ -331,21 +332,40 @@ def _gather_to_tokens(y, row_of_slot, top_k: int):
                    axis=1).astype(y.dtype)
 
 
+def _combine_tile(top_k: int, routed: int, count: int, align: int):
+    """The way back's window rows and the windows one product takes, from
+    the shapes. Where the choice of experts is even, a tile of tokens has
+    ``TOKEN_TILE * top_k / routed`` rows on a held expert, and its first
+    window starts up to ``align - 1`` rows before them: the window is the
+    least power of two from ``align`` that holds both, at most
+    ``WINDOW_ROWS``. A product takes ``_PRODUCT_ROWS`` rows of windows, so
+    at 32 held experts of 5-8 rows a tile the windows are 32 rows and one
+    group holds them all: only the first group's copies are started a tile
+    ahead, a later group's wait in its own round."""
+    segment = -(-TOKEN_TILE * top_k // routed)
+    window = align
+    while window < min(segment + align - 1, WINDOW_ROWS):
+        window *= 2
+    return window, min(count, _PRODUCT_ROWS // window)
+
+
 def _combine_kernel(start_ref, size_ref, slot_rows_ref, y_ref, out_ref, buf,
-                    sem, acc, *, count: int, window: int, align: int):
+                    sem, acc, *, count: int, window: int, windows: int,
+                    align: int):
     """One tile of tokens: the float32 sum of each token's rows of the
     sorted buffer ``y_ref`` (in HBM). The rows the tile has on held expert
     ``e`` are ``size_ref[e]`` rows from ``start_ref[e]`` on; they are read
-    as windows of ``window`` rows from an aligned row, a group of experts'
-    windows at a time, and summed by one 0/1 product, ``P[t, r]`` being
-    "window row ``r`` is one of token ``t``'s rows". A window's rows that
-    are not the segment's may never have been written (or be another
-    tile's): they are zeroed before the product, where ``0 x NaN`` would
-    be NaN. A segment longer than a window takes further rounds. The first
-    windows of the next tile are copied while this one is summed (the grid
-    runs in order), into the other half of ``buf``. The experts are walked
-    by ``fori_loop``: sixteen unrolled bodies a call site, eight sites a
-    step, cost the step's set-up half a minute of tracing."""
+    as windows of ``window`` rows from an aligned row, a group of
+    ``windows`` experts' windows at a time, and summed by one 0/1 product,
+    ``P[t, r]`` being "window row ``r`` is one of token ``t``'s rows". A
+    window's rows that are not the segment's may never have been written
+    (or be another tile's): they are zeroed before the product, where ``0
+    x NaN`` would be NaN. A segment longer than a window takes further
+    rounds. The first group's windows of the next tile are copied while
+    this one is summed (the grid runs in order), into the other half of
+    ``buf``. The experts are walked by ``fori_loop``: sixteen unrolled
+    bodies a call site, eight sites a step, cost the step's set-up half a
+    minute of tracing."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -383,14 +403,13 @@ def _combine_kernel(start_ref, size_ref, slot_rows_ref, y_ref, out_ref, buf,
 
         lax.fori_loop(0, held, one, None)
 
-    first_held = min(_WINDOW_GROUP, count)
-    pl.when(tile == 0)(lambda: start(0, 0, first_held, 0, 0))
+    pl.when(tile == 0)(lambda: start(0, 0, windows, 0, 0))
     pl.when(tile + 1 < pl.num_programs(0))(
-        lambda: start(tile + 1, 0, first_held, 0, 1 - half))
+        lambda: start(tile + 1, 0, windows, 0, 1 - half))
     acc[...] = jnp.zeros_like(acc)
 
-    for group in range(0, count, _WINDOW_GROUP):
-        held = min(_WINDOW_GROUP, count - group)
+    for group in range(0, count, windows):
+        held = min(windows, count - group)
         width = held * window
 
         def one_round(w, carry, group=group, held=held, width=width):
@@ -429,21 +448,24 @@ def _combine_kernel(start_ref, size_ref, slot_rows_ref, y_ref, out_ref, buf,
                 preferred_element_type=jnp.float32)
             return carry
 
-        def windows(k, most, group=group):
+        def rounds(k, most, group=group):
             start_, size, first = segment(tile, group + k)
             return jnp.maximum(most, jnp.where(
                 size > 0, -(-(start_ + size - first) // window), 0))
 
-        lax.fori_loop(0, lax.fori_loop(0, held, windows, 0), one_round, None)
+        lax.fori_loop(0, lax.fori_loop(0, held, rounds, 0), one_round, None)
 
     out_ref[...] = acc[...].astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("top_k", "interpret"))
-def _pallas_combine(y, plan, *, top_k: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("top_k", "window", "windows",
+                                             "interpret"))
+def _pallas_combine(y, plan, *, top_k: int, window: int, windows: int,
+                    interpret: bool):
     """:func:`_to_tokens` on a lane-wide ``[rows, D]`` operand as a Pallas
     kernel (:func:`_combine_kernel`): it reads the rows held here, in
-    windows, and not a row for every slot. The call carries no ``name=``:
+    windows (:func:`_combine_tile`), and not a row for every slot. The call
+    carries no ``name=``:
     its device time is its scope's, ``hvd.moe_route``. Jitted, so that a
     step's call sites (a layer's combine and its dispatch's transpose,
     layer after layer) trace and lower the kernel once between them; each
@@ -458,8 +480,8 @@ def _pallas_combine(y, plan, *, top_k: int, interpret: bool):
     slot_rows = jnp.pad(plan["row_of_slot"].reshape(tokens, top_k),
                         ((0, padded - tokens), (0, 0)), constant_values=rows)
     out = pl.pallas_call(
-        functools.partial(_combine_kernel, count=count, window=WINDOW_ROWS,
-                          align=32 // y.dtype.itemsize),
+        functools.partial(_combine_kernel, count=count, window=window,
+                          windows=windows, align=32 // y.dtype.itemsize),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(token_tiles,),
@@ -469,9 +491,8 @@ def _pallas_combine(y, plan, *, top_k: int, interpret: bool):
             ],
             out_specs=pl.BlockSpec((TOKEN_TILE, d), lambda i, s, n: (i, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, min(count, _WINDOW_GROUP) * WINDOW_ROWS, d),
-                           y.dtype),
-                pltpu.SemaphoreType.DMA((2, min(count, _WINDOW_GROUP))),
+                pltpu.VMEM((2, windows * window, d), y.dtype),
+                pltpu.SemaphoreType.DMA((2, windows)),
                 pltpu.VMEM((TOKEN_TILE, d), jnp.float32),
             ],
         ),
@@ -486,53 +507,61 @@ def _pallas_combine(y, plan, *, top_k: int, interpret: bool):
     return out[:tokens]
 
 
-def _combine_fits(d: int, itemsize: int, count: int) -> bool:
-    """Whether :func:`_combine_kernel` has room at rows of ``d`` elements:
-    both halves of a group's windows, the float32 sum and a product's
-    result, the block of the result twice and the 0/1 matrix."""
-    group = min(count, _WINDOW_GROUP) * WINDOW_ROWS
+def _combine_fits(d: int, itemsize: int, group: int) -> bool:
+    """Whether :func:`_combine_kernel` has room at rows of ``d`` elements
+    and ``group`` rows of windows a product: both halves of a group's
+    windows, the float32 sum and a product's result, the block of the
+    result twice and the 0/1 matrix."""
     return (2 * group * d * itemsize + 2 * TOKEN_TILE * d * 4
             + 2 * TOKEN_TILE * d * itemsize
             + TOKEN_TILE * group * (4 + itemsize)) <= _VMEM_LIMIT * 3 // 4
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _to_tokens(y, plan, top_k: int, interpret: bool):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _to_tokens(y, plan, top_k: int, routed: int, interpret: bool):
     """``y`` ``[rows, ...]`` -> ``[T, ...]``: each token the float32 sum of
     its slots' buffer rows (a slot with no row adds nothing). ``plan`` is
-    :func:`_plan`'s. The operand's shape chooses the form: ``[rows, D]`` in
-    whole lanes (that fit the kernel's VMEM), summed ``top_k`` rows a token
-    as the plan was made, is the kernel's; anything else (the router
-    weights' ``[rows, 1]``) gathers."""
+    :func:`_plan`'s over ``routed`` experts. The operand's shape chooses
+    the form: ``[rows, D]`` in whole lanes (that fit the kernel's VMEM),
+    summed ``top_k`` rows a token as the plan was made, is the kernel's,
+    its windows sized from the shapes (:func:`_combine_tile`); anything
+    else (the router weights' ``[rows, 1]``) gathers."""
     row_of_slot = plan["row_of_slot"]
     token_tiles, count = plan["seg_start"].shape
+    window, windows = _combine_tile(top_k, routed, count,
+                                    32 // y.dtype.itemsize)
     if (y.ndim == 2 and y.shape[1] % 128 == 0
             and token_tiles == -(-(row_of_slot.shape[0] // top_k)
                                  // TOKEN_TILE)
-            and _combine_fits(y.shape[1], y.dtype.itemsize, count)):
+            and _combine_fits(y.shape[1], y.dtype.itemsize,
+                              windows * window)):
         if _metrics.enabled():
-            for dim, n in (("tokens", TOKEN_TILE), ("rows", WINDOW_ROWS)):
+            for dim, n in (("tokens", TOKEN_TILE), ("rows", window),
+                           ("windows", windows)):
                 _metrics.gauge(
                     "moe_combine_tile",
                     help="tokens one grid step of the routed layer's way "
-                         "back to the tokens sums, and rows of one window "
-                         "it reads of the sorted buffer; absent where the "
-                         "gather ran", dim=dim).set(n)
-        return _pallas_combine(y, plan, top_k=top_k, interpret=interpret)
+                         "back to the tokens sums, rows of one window it "
+                         "reads of the sorted buffer, and windows one "
+                         "product takes, chosen from the shapes; absent "
+                         "where the gather ran", dim=dim).set(n)
+        return _pallas_combine(y, plan, top_k=top_k, window=window,
+                               windows=windows, interpret=interpret)
     return _gather_to_tokens(y, row_of_slot, top_k)
 
 
-def _to_tokens_fwd(y, plan, top_k, interpret):
-    return _to_tokens(y, plan, top_k, interpret), plan
+def _to_tokens_fwd(y, plan, top_k, routed, interpret):
+    return _to_tokens(y, plan, top_k, routed, interpret), plan
 
 
 @jax.named_scope("hvd.moe_route")
-def _to_tokens_bwd(top_k, interpret, plan, g):
+def _to_tokens_bwd(top_k, routed, interpret, plan, g):
     # zeros on the padding rows: their slot is ``T * top_k``, which reads
     # the row of zeros put after ``g``, and not a choice over ``[rows,
     # ...]`` behind the gather
     zeros = jnp.zeros((1,) + g.shape[1:], g.dtype)
-    return _to_rows(jnp.concatenate([g, zeros]), plan, top_k, interpret), None
+    return _to_rows(jnp.concatenate([g, zeros]), plan, top_k, routed,
+                    interpret), None
 
 
 _to_tokens.defvjp(_to_tokens_fwd, _to_tokens_bwd)
@@ -922,7 +951,7 @@ def routed_experts(x, router, gate, up, down, *, top_k: int, first: int = 0,
 
     One chip, no exchange: the caller's tokens are all the tokens.
     ``interpret`` defaults to running the kernels interpreted off TPU."""
-    tokens, count = x.shape[0], gate.shape[0]
+    tokens, count, routed = x.shape[0], gate.shape[0], router.shape[1]
     dtype = dtype or x.dtype
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -936,8 +965,8 @@ def routed_experts(x, router, gate, up, down, *, top_k: int, first: int = 0,
     with jax.named_scope("hvd.moe_route"):
         weights, experts = route_top_k(x, router, top_k, select)
         plan = _plan(experts, first=first, count=count)
-        xs = _to_rows(x.astype(dtype), plan, top_k, interpret)
-        w_rows = _to_rows(weights.reshape(-1, 1), plan, 1, interpret)
+        xs = _to_rows(x.astype(dtype), plan, top_k, routed, interpret)
+        w_rows = _to_rows(weights.reshape(-1, 1), plan, 1, routed, interpret)
         real = (plan["slot_of_row"] < tokens * top_k)[:, None]
     groups = (plan["tile_expert"], plan["tiles"], interpret)
     if _experts_fit(*gate.shape[1:], jnp.dtype(dtype).itemsize):
@@ -965,7 +994,7 @@ def routed_experts(x, router, gate, up, down, *, top_k: int, first: int = 0,
             ys = (jnp.where(real, ys.astype(jnp.float32), 0) * w_rows
                   ).astype(dtype)
     with jax.named_scope("hvd.moe_route"):
-        y = _to_tokens(ys, plan, top_k, interpret)
+        y = _to_tokens(ys, plan, top_k, routed, interpret)
     return y, plan["local"].astype(jnp.float32)
 
 

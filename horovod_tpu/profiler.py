@@ -62,7 +62,8 @@ Device scopes, as they read in an instruction's ``op_name``:
 Trace-time gauges say what a trace chose: ``flash_fwd_tile`` /
 ``flash_bwd_tile`` (+ ``*_grid_steps``), ``moe_rows_budget`` (rows of a
 routed layer's sorted buffer: the worst case), ``moe_combine_tile`` (the
-tile of the kernel that sums the rows back; absent where the gather ran),
+tile, window and windows a product of the kernel that sums the rows back,
+chosen from the shapes; absent where the gather ran),
 ``moe_experts_fused`` (1 where the experts' activation and weighting were
 traced inside the grouped products' kernels), ``gdn_chunk`` / ``gdn_chunks``
 (tokens a chunk of the gated delta rule, and chunks a row). ``moe_local_rows`` is a

@@ -535,16 +535,26 @@ def test_what_still_runs_over_the_whole_buffer():
 
 
 def _way_back(tokens, first, count, routing="uniform", routed=8, top_k=2,
-              dim=128, dtype=jnp.float32):
+              dim=128, dtype=jnp.float32, offset=0):
     """A plan and a sorted buffer whose rows past the tiles in use hold
-    NaN, as the grouped products leave them interpreted."""
+    NaN, as the grouped products leave them interpreted. ``"five rows"``:
+    token tile 1 has 5 rows on expert ``first``, ``offset`` rows into its
+    run (tile 0's), and every 64th token one on the next expert."""
     keys = jax.random.split(jax.random.PRNGKey(tokens + 7 * first), 2)
+    away = [e for e in range(routed) if not first <= e < first + count]
     if routing == "one expert":
         chosen = jnp.tile(jnp.array([[first, first + 1]], jnp.int32),
                           (tokens, 1))
     elif routing == "none here":
-        away = [e for e in range(routed) if not first <= e < first + count]
         chosen = jnp.tile(jnp.array([away[:top_k]], jnp.int32), (tokens, 1))
+    elif routing == "five rows":
+        t = jnp.arange(tokens)[:, None]
+        chosen = jnp.tile(jnp.array([away[:top_k]], jnp.int32), (tokens, 1))
+        mine = (t < offset) | ((t >= moe.TOKEN_TILE)
+                               & (t < moe.TOKEN_TILE + 5))
+        chosen = jnp.where(mine & (jnp.arange(top_k) == 0), first, chosen)
+        chosen = jnp.where((t % 64 == 1) & (jnp.arange(top_k) == 1),
+                           first + 1, chosen)
     else:
         chosen = jax.lax.top_k(jax.random.uniform(keys[0], (tokens, routed)),
                                top_k)[1].astype(jnp.int32)
@@ -552,7 +562,21 @@ def _way_back(tokens, first, count, routing="uniform", routed=8, top_k=2,
     y = jax.random.normal(keys[1], (moe.buffer_rows(tokens, top_k, count),
                                     dim))
     y = y.at[int(plan["tiles"][0]) * moe.TILE_ROWS:].set(jnp.nan)
-    return y.astype(dtype), plan, top_k
+    return y.astype(dtype), plan, top_k, routed
+
+
+def _matches_the_gather(y, plan, top_k, routed):
+    """The kernel (interpreted) against the gather of every slot's row:
+    the same float32 sums, and nothing of the rows no product wrote."""
+    jaxpr = str(jax.make_jaxpr(
+        lambda a, w: moe._to_tokens(a, w, top_k, routed, True))(y, plan))
+    assert "pallas_call" in jaxpr and "gather" not in jaxpr
+    got = np.asarray(moe._to_tokens(y, plan, top_k, routed, True), np.float32)
+    want = np.asarray(moe._gather_to_tokens(y, plan["row_of_slot"], top_k),
+                      np.float32)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    return got
 
 
 @pytest.mark.parametrize("tokens,first,count,routing,dtype", [
@@ -573,39 +597,81 @@ def test_the_way_back_reads_the_rows_held_here(tokens, first, count, routing,
                                                dtype):
     """The kernel (interpreted) against the gather of every slot's row: the
     same float32 sums, and nothing of the rows no product wrote."""
-    y, plan, top_k = _way_back(tokens, first, count, routing,
-                               dtype=getattr(jnp, dtype))
+    y, plan, top_k, routed = _way_back(tokens, first, count, routing,
+                                       dtype=getattr(jnp, dtype))
     assert bool(jnp.isnan(y[-1, 0]))
-    jaxpr = str(jax.make_jaxpr(
-        lambda a, w: moe._to_tokens(a, w, top_k, True))(y, plan))
-    assert "pallas_call" in jaxpr and "gather" not in jaxpr
-    got = np.asarray(moe._to_tokens(y, plan, top_k, True), np.float32)
-    want = np.asarray(moe._gather_to_tokens(y, plan["row_of_slot"], top_k),
-                      np.float32)
-    assert got.shape == (tokens, y.shape[1]) and np.isfinite(got).all()
-    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    got = _matches_the_gather(y, plan, top_k, routed)
+    assert got.shape == (tokens, y.shape[1])
     if routing == "none here":
         assert int(plan["local"]) == 0 and not got.any()
     if routing == "one expert":
         assert int(plan["seg_rows"].max()) == min(tokens, moe.TOKEN_TILE)
 
 
-def test_the_way_back_takes_the_held_experts_in_groups():
-    """More held experts than one product takes windows of (24 of 32, in
-    groups of 16 and 8): the groups' sums add up."""
-    assert moe._WINDOW_GROUP < 24
-    y, plan, top_k = _way_back(300, 4, 24, routed=32, top_k=4)
-    got = moe._to_tokens(y, plan, top_k, True)
-    assert bool(jnp.isfinite(got).all()) and int(plan["local"]) > 600
-    np.testing.assert_allclose(
-        got, moe._gather_to_tokens(y, plan["row_of_slot"], top_k),
-        rtol=1e-6, atol=1e-6)
+@pytest.mark.parametrize("top_k,routed,count,dtype,window,groups", [
+    (4, 32, 24, "float32", 64, (16, 8)),
+    (8, 256, 40, "bfloat16", 32, (32, 8)),   # Laguna's windows
+    (2, 1024, 130, "float32", 8, (128, 2)),
+])
+def test_the_way_back_takes_the_held_experts_in_groups(top_k, routed, count,
+                                                       dtype, window, groups):
+    """More held experts than one product takes windows of (the rule's
+    window, ``_PRODUCT_ROWS`` rows of them a product): the groups' sums add
+    up."""
+    align = 32 // jnp.dtype(dtype).itemsize
+    assert moe._combine_tile(top_k, routed, count, align) == (window,
+                                                             groups[0])
+    assert sum(groups) == count
+    y, plan, top_k, routed = _way_back(300, 4, count, routed=routed,
+                                       top_k=top_k, dtype=getattr(jnp, dtype))
+    _matches_the_gather(y, plan, top_k, routed)
+    assert int(plan["local"]) > 300 * top_k * count // routed // 2
+
+
+@pytest.mark.parametrize("first,count,routed,dtype,window", [
+    (0, 32, 64, "bfloat16", 32),     # 32 held, windows of 32: eight rounds
+    (0, 4, 512, "float32", 8),       # windows of 8: 32 rounds
+    (2, 3, 8, "bfloat16", 64),
+])
+def test_a_segment_longer_than_the_window_takes_rounds(first, count, routed,
+                                                       dtype, window):
+    """Every token on two experts: a token tile's 256 rows on each are read
+    in as many rounds of the window the rule chose from even routing."""
+    assert moe._combine_tile(2, routed, count,
+                             32 // jnp.dtype(dtype).itemsize)[0] == window
+    y, plan, top_k, routed = _way_back(600, first, count, "one expert",
+                                       routed=routed,
+                                       dtype=getattr(jnp, dtype))
+    assert int(plan["seg_rows"].max()) == moe.TOKEN_TILE
+    _matches_the_gather(y, plan, top_k, routed)
+
+
+@pytest.mark.parametrize("dtype,offset,crosses", [
+    ("bfloat16", 11, False), ("bfloat16", 12, True), ("bfloat16", 15, True),
+    ("float32", 3, False), ("float32", 4, True), ("float32", 7, True),
+])
+def test_a_segment_across_a_window_end(dtype, offset, crosses):
+    """Top-2 of 512 puts one row of a token tile on an expert as a rule:
+    windows of the dtype's alignment (16 rows, 8 in float32). Five rows
+    that start ``offset`` rows into an aligned row fit one window up to
+    ``window - 5`` and cross its end past that, into a second round."""
+    align = 32 // jnp.dtype(dtype).itemsize
+    window, _ = moe._combine_tile(2, 512, 4, align)
+    assert window == align
+    y, plan, top_k, routed = _way_back(512, 0, 4, "five rows", routed=512,
+                                       dtype=getattr(jnp, dtype),
+                                       offset=offset)
+    start, size = int(plan["seg_start"][1, 0]), int(plan["seg_rows"][1, 0])
+    assert size == 5 and start % align == offset
+    assert (offset + size > window) == crosses
+    got = _matches_the_gather(y, plan, top_k, routed)
+    assert got[moe.TOKEN_TILE:moe.TOKEN_TILE + 5].any()
 
 
 def test_segments_are_the_rows_of_a_token_tile_on_an_expert():
     """``seg_start`` / ``seg_rows``: the sort is stable, so the rows that a
     tile of tokens has on one held expert follow each other."""
-    _, plan, top_k = _way_back(600, 2, 3)
+    _, plan, top_k, _ = _way_back(600, 2, 3)
     rows = np.asarray(plan["row_of_slot"]).reshape(-1, top_k)
     expert_of_row = np.repeat(np.asarray(plan["tile_expert"]), moe.TILE_ROWS)
     for tile in range(-(-600 // moe.TOKEN_TILE)):
@@ -623,22 +689,29 @@ def test_an_operand_one_lane_wide_keeps_the_gather():
     """The router weights' ``f32[rows, 1]`` (and any operand that is not
     ``[rows, D]`` in whole lanes) gathers, chosen from its shape; the gauge
     ``moe_combine_tile`` says which form a trace took."""
-    y, plan, top_k = _way_back(600, 4, 4)
+    y, plan, top_k, routed = _way_back(600, 4, 4)
     slots = jax.random.normal(jax.random.PRNGKey(2), (y.shape[0], 1))
     # ... and rows too wide for the kernel's windows to fit its VMEM
     wide = jax.ShapeDtypeStruct((y.shape[0], 1 << 14), jnp.bfloat16)
-    assert moe._combine_fits(2304, 2, 16) and not moe._combine_fits(
-        1 << 14, 2, 4)
+    assert moe._combine_fits(2304, 2, 16 * 64) and moe._combine_fits(
+        2048, 2, 32 * 32) and not moe._combine_fits(1 << 14, 2, 4 * 64)
     for operand, k in ((slots, 1), (y[:, :64], top_k), (y[:, :128], 1),
                        (wide, top_k)):
         metrics.REGISTRY.reset()
         jaxpr = str(jax.make_jaxpr(
-            lambda a, w: moe._to_tokens(a, w, k, True))(operand, plan))
+            lambda a, w: moe._to_tokens(a, w, k, routed, True))(operand,
+                                                               plan))
         assert "pallas_call" not in jaxpr and "gather" in jaxpr
         assert metrics.value("moe_combine_tile", dim="tokens") is None
-    jax.make_jaxpr(lambda a, w: moe._to_tokens(a, w, top_k, True))(y, plan)
+    jax.make_jaxpr(lambda a, w: moe._to_tokens(a, w, top_k, routed, True))(
+        y, plan)
+    # top-2 of 8 in float32: 64 rows a tile on an expert, windows of 64,
+    # the four held experts' windows in one product
+    window, windows = moe._combine_tile(top_k, routed, 4, 8)
+    assert (window, windows) == (64, 4)
     assert metrics.value("moe_combine_tile", dim="tokens") == moe.TOKEN_TILE
-    assert metrics.value("moe_combine_tile", dim="rows") == moe.WINDOW_ROWS
+    assert metrics.value("moe_combine_tile", dim="rows") == window
+    assert metrics.value("moe_combine_tile", dim="windows") == windows
 
 
 @pytest.mark.parametrize("first,count", [(0, 8), (4, 4), (2, 3)])

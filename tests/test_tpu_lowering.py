@@ -407,18 +407,32 @@ def test_token_xent_keeps_no_float32_copy_of_the_logits(one_v5e_chip, dtype,
 
 
 @pytest.mark.parametrize("tokens,top_k,count,dim,dtype", [
-    (8192, 8, 16, 2304, "bfloat16"),   # the benchmark's routed cell
-    (8192, 8, 32, 2048, "bfloat16"),   # its other one: two groups of windows
+    (8192, 8, 16, 2304, "bfloat16"),   # mellum2_train_1chip
+    (8192, 8, 32, 2048, "bfloat16"),   # laguna_train_1chip: 8 rows a window
     (2048, 8, 16, 2304, "bfloat16"),   # the ladder's other shape
     (600, 2, 3, 256, "float32"),       # a count no tile divides, exact f32
+    (8192, 10, 32, 2048, "bfloat16"),  # qwen3next_train_1chip: 5 rows
 ])
 def test_moe_way_back_compiles_for_v5e(one_v5e_chip, tokens, top_k, count,
                                        dim, dtype):
     """Mosaic takes the kernel that sums a token's rows of the sorted
     buffer: its window copies start on the dtype's tiling, its scratch
-    fits the VMEM it asks for, and no gather is left beside it."""
+    fits the VMEM it asks for, and no gather is left beside it. The
+    windows are the rule's: 64 rows, 16 a product, at Mellum2's 32 rows a
+    token tile on an expert; 32 rows, all 32 held experts' in one product,
+    at Laguna's 8 and Qwen3-Next's 5."""
+    from horovod_tpu.observability import metrics
     from horovod_tpu.parallel import moe
 
+    # the router's width, and the window and windows a product the rule
+    # gives there
+    routed, window, windows = {(8, 16): (64, 64, 16), (8, 32): (256, 32, 32),
+                               (10, 32): (512, 32, 32), (2, 3): (8, 64, 3)
+                               }[top_k, count]
+    assert moe._combine_tile(top_k, routed, count,
+                             32 // jnp.dtype(dtype).itemsize) == (window,
+                                                                  windows)
+    metrics.REGISTRY.reset()
     rows = moe.buffer_rows(tokens, top_k, count)
     tiles = -(-tokens // moe.TOKEN_TILE)
     ints = functools.partial(S, dtype=jnp.int32, sharding=one_v5e_chip)
@@ -428,10 +442,12 @@ def test_moe_way_back_compiles_for_v5e(one_v5e_chip, tokens, top_k, count,
              "seg_rows": ints((tiles, count))}
     y = S((rows, dim), dtype, sharding=one_v5e_chip)
     text = jax.jit(
-        lambda y, plan: moe._to_tokens(y, plan, top_k, False)
+        lambda y, plan: moe._to_tokens(y, plan, top_k, routed, False)
     ).lower(y, plan).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert " gather(" not in text and " while(" not in text
+    assert metrics.value("moe_combine_tile", dim="rows") == window
+    assert metrics.value("moe_combine_tile", dim="windows") == windows
 
 
 @pytest.mark.parametrize("tokens,top_k,count,dim,width", [
