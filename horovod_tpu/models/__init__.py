@@ -23,6 +23,7 @@ from horovod_tpu.models.transformer import (  # noqa: F401
     Experts,
     GatedDelta,
     Layer,
+    Mamba2,
     SwiGLU,
     TransformerLM,
     TransformerTiny,

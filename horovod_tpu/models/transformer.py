@@ -65,16 +65,29 @@ class SwiGLU:
 class Experts:
     """A routed-expert FFN (:func:`horovod_tpu.parallel.moe.routed_experts`)
     in place of a block's MLP: a float32 router ``routed`` wide, ``top_k``
-    experts a token, SwiGLU experts ``width`` wide; of them this model holds
+    experts a token, experts ``width`` wide; of them this model holds
     ``count`` from ``first`` (default: all) and computes their part of the
     layer. ``select`` (``probabilities [tokens, routed] -> scores``) chooses
     a token's experts in the router's place, by the ``top_k`` of its
     scores; the weights stay the router's. ``scale`` multiplies the routed
     sum (a model's routed scaling factor). ``shared`` is the
-    width of a SwiGLU expert every token passes through, added to the
+    width of an expert every token passes through, added to the
     routed sum unweighted, or with ``shared_gate`` times ``sigmoid(h
     w_sg)``, ``w_sg`` ``[dim, 1]``: it is computed whole wherever the layer
-    is, so across the holders of a layer's experts it counts once."""
+    is, so across the holders of a layer's experts it counts once.
+
+    ``activation`` is every expert's, the shared one's too: ``"swiglu"``,
+    ``(silu(h W_gate) * (h W_up)) W_down``, or ``"relu2"``, ``relu(h
+    W_up)^2 W_down`` (no gate matrix). ``router`` ``"softmax"`` weighs a
+    token's chosen experts by their probabilities over their sum;
+    ``"sigmoid"`` chooses by ``top_k(s + b)`` of ``s = sigmoid(h W_r)``,
+    ``b`` a selection bias that no gradient trains (the block's
+    ``batch_stats`` ``router_bias``, zeros where it holds none), and weighs
+    by ``s`` over the chosen ``s``' sum. With ``latent`` the routed experts
+    work in a space of that width: ``fc1_latent_proj`` takes ``h`` down to
+    it, the routed sum comes back through ``fc2_latent_proj`` (both under
+    ``hvd.moe_latent``); the router and the shared expert read ``h``
+    itself."""
 
     routed: int
     top_k: int
@@ -85,6 +98,17 @@ class Experts:
     scale: float = 1.0
     shared: Optional[int] = None
     shared_gate: bool = False
+    activation: str = "swiglu"
+    router: str = "softmax"
+    latent: Optional[int] = None
+
+    def __post_init__(self):
+        if self.activation not in ("swiglu", "relu2"):
+            raise ValueError("activation must be 'swiglu' or 'relu2', got "
+                             f"{self.activation!r}")
+        if self.router not in ("softmax", "sigmoid"):
+            raise ValueError("router must be 'softmax' or 'sigmoid', got "
+                             f"{self.router!r}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,6 +128,24 @@ class GatedDelta:
 
 
 @dataclasses.dataclass(frozen=True)
+class Mamba2:
+    """A Mamba-2 token mixer (:mod:`horovod_tpu.ops.mamba2`) in place of a
+    block's attention: ``heads`` heads of ``head_dim`` channels, their
+    ``B`` and ``C`` in ``groups`` groups of ``state`` (head ``h`` reads group
+    ``h // (heads / groups)``), a causal depthwise convolution of ``conv``
+    taps with a bias over ``[x | B | C]``, the recurrence in chunks of
+    ``chunk`` tokens; the projections laid out as the published checkpoints
+    lay them (``in_proj`` ``[z | x | B | C | dt]``, ``out_proj``)."""
+
+    heads: int
+    head_dim: int
+    groups: int
+    state: int
+    conv: int = 4
+    chunk: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
 class Layer:
     """One block of a :class:`TransformerLM` built from a per-layer
     description (``TransformerLM(layers=...)``): its attention (``heads``
@@ -117,9 +159,13 @@ class Layer:
     ``sigmoid(h W_g)``, ``W_g`` ``[dim, heads]``; ``"element"`` each
     feature times the sigmoid of a second half of the head's query
     projection, ``[q | gate]`` a head) or, where ``mixer`` is given, a
-    :class:`GatedDelta` in its place; and its FFN (a GELU MLP
-    ``mlp_ratio`` x dim wide, a :class:`SwiGLU` MLP, or
-    :class:`Experts`)."""
+    :class:`GatedDelta` or :class:`Mamba2` in its place; and its FFN (a
+    GELU MLP ``mlp_ratio`` x dim wide, a :class:`SwiGLU` MLP, or
+    :class:`Experts`).
+
+    A block may be one part alone, ``x + part(norm(x))`` (Nemotron-H's
+    blocks): ``ffn=None`` is its mixer alone, no heads and no mixer its FFN
+    alone."""
 
     heads: int = 0
     head_dim: int = 0
@@ -127,11 +173,11 @@ class Layer:
     rope_base: float = 10000.0
     yarn: Optional[Yarn] = None
     window: Optional[int] = None
-    ffn: Union[int, SwiGLU, Experts] = 4
+    ffn: Union[int, SwiGLU, Experts, None] = 4
     rotary_dim: Optional[int] = None
     gate: Optional[str] = None
     qk_norm: bool = False
-    mixer: Optional[GatedDelta] = None
+    mixer: Union[GatedDelta, Mamba2, None] = None
 
     def __post_init__(self):
         # a configuration's boolean key (Laguna's ``gating``) passes
@@ -154,24 +200,42 @@ def refuse_training_only(model, what: str):
     """Raise where ``what`` (an entry point other than training) is handed
     a :class:`TransformerLM` with parts only the training path computes."""
     layers = getattr(model, "layers", None) or ()
-    _refuse_forms(what, getattr(model, "norm", None), [
-        part for i, layer in enumerate(layers) for part in _training_only(
-            f"block{i}", layer.mixer, layer.qk_norm, layer.gate, layer.ffn)])
+    found = [part for i, layer in enumerate(layers)
+             for part in _training_only(
+                 f"block{i}", layer.mixer, layer.qk_norm, layer.gate,
+                 layer.ffn, heads=layer.heads, has_ffn=layer.ffn is not None)]
+    if getattr(model, "pos_embedding", None) == "none":
+        found = ["pos_embedding='none'"] + found
+    _refuse_forms(what, getattr(model, "norm", None), found)
 
 
-def _training_only(name, mixer, qk_norm, gate, ffn):
+def _training_only(name, mixer, qk_norm, gate, ffn, *, heads=1,
+                   has_ffn=True):
     """A block's parts that only the training path computes, named: a
-    :class:`GatedDelta` mixer, q/k norms, the element-wise gate, a gated
-    shared expert."""
+    :class:`GatedDelta` or :class:`Mamba2` mixer, q/k norms, the
+    element-wise gate, a gated shared expert, experts of the ``relu2``
+    activation, the ``sigmoid`` router or a ``latent`` width, a block of
+    one part."""
     found = []
-    if mixer is not None:
+    if isinstance(mixer, Mamba2):
+        found.append(f"{name}: the Mamba-2 layer {mixer}")
+    elif mixer is not None:
         found.append(f"{name}: the gated-delta layer {mixer}")
     if qk_norm:
         found.append(f"{name}: qk_norm=True")
     if gate == "element":
         found.append(f"{name}: gate='element'")
-    if isinstance(ffn, Experts) and ffn.shared_gate:
-        found.append(f"{name}: Experts(shared_gate=True)")
+    if isinstance(ffn, Experts):
+        found += [f"{name}: Experts({field})" for field, on in (
+            ("shared_gate=True", ffn.shared_gate),
+            ("activation='relu2'", ffn.activation == "relu2"),
+            ("router='sigmoid'", ffn.router == "sigmoid"),
+            (f"latent={ffn.latent}", ffn.latent is not None)) if on]
+    if mixer is None and not heads:
+        found.append(f"{name}: no heads and no mixer, a block of its FFN "
+                     "alone")
+    if not has_ffn:
+        found.append(f"{name}: ffn=None, a block of its mixer alone")
     return found
 
 
@@ -182,9 +246,9 @@ def _refuse_forms(what, norm, found):
         found = [f"norm={ZERO_CENTRED!r}"] + found
     if found:
         raise ValueError(
-            f"{what} has no path for {'; '.join(found)}: a gated-delta "
-            "layer keeps a recurrent state, not K/V, and these forms are "
-            "computed by TransformerLM's training-shape call only")
+            f"{what} has no path for {'; '.join(found)}: a gated-delta or "
+            "Mamba-2 layer keeps a recurrent state, not K/V, and these forms "
+            "are computed by TransformerLM's training-shape call only")
 
 
 def apply_rope(x, positions, *, base: float = 10000.0,
@@ -293,7 +357,10 @@ class TransformerBlock(nn.Module):
     norm: str = "layernorm"  # or "rmsnorm", or ZERO_CENTRED
     norm_eps: float = 1e-6
     qk_norm: bool = False
-    mixer: Optional[GatedDelta] = None
+    mixer: Union[GatedDelta, Mamba2, None] = None
+    # a block of its mixer alone (Layer's ``ffn=None``); one of its FFN
+    # alone has no heads and no mixer
+    has_ffn: bool = True
 
     def _norm(self, name):
         return make_norm(self.norm, self.norm_eps, self.dtype, name)
@@ -302,9 +369,16 @@ class TransformerBlock(nn.Module):
     def __call__(self, x, positions=None, page_table=None):
         if self.decode:
             _refuse_forms("kv-cache decoding", self.norm, _training_only(
-                self.name, self.mixer, self.qk_norm, self.gate, self.experts))
+                self.name, self.mixer, self.qk_norm, self.gate, self.experts,
+                heads=self.heads, has_ffn=self.has_ffn))
+        if self.mixer is None and not self.heads:
+            return x + self._ffn(self._norm("ln1")(x))
         if self.mixer is not None:
-            x = x + self._gated_delta(self._norm("ln1")(x))
+            mix = (self._mamba2 if isinstance(self.mixer, Mamba2)
+                   else self._gated_delta)
+            x = x + mix(self._norm("ln1")(x))
+            if not self.has_ffn:
+                return x
             return x + self._ffn(self._norm("ln2")(x))
         if self.decode and (self.window is not None
                             or self.experts is not None
@@ -436,7 +510,8 @@ class TransformerBlock(nn.Module):
         att = att.reshape(*att.shape[:2], self.heads * head_dim)
         x = x + nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
                          name="proj")(att)
-
+        if not self.has_ffn:
+            return x
         return x + self._ffn(self._norm("ln2")(x))
 
     def _ffn(self, h):
@@ -481,6 +556,55 @@ class TransformerBlock(nn.Module):
                 key_dim=m.key_dim, value_dim=m.value_dim, eps=self.norm_eps)
         return dense(self.dim, name="out_proj")(y)
 
+    def _mamba2(self, h):
+        """What the block's :class:`Mamba2` mixer adds: the in- and
+        out-projections in ``dtype`` around
+        :func:`~horovod_tpu.ops.mamba2.mamba2_mixer` (float32, under
+        ``hvd.ssm``). Parameters as the published checkpoints name them;
+        ``conv1d`` ``[channels, taps]`` and ``conv1d_bias`` as torch draws a
+        ``Conv1d``'s (uniform within ``1 / sqrt(taps)``), ``A_log`` ``log
+        U(1, 16)``, ``D`` and the gated norm's ``norm_scale`` ones,
+        ``dt_bias`` the inverse softplus of a step size log-uniform in
+        ``[0.001, 0.1]``, at least ``1e-4`` (the published code's)."""
+        from horovod_tpu.ops.mamba2 import mamba2_mixer
+
+        m = self.mixer
+        inner, bc = m.heads * m.head_dim, 2 * m.groups * m.state
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        zxbcdt = dense(2 * inner + bc + m.heads, name="in_proj")(h)
+        bound = m.conv ** -0.5
+        conv_init = functools.partial(jax.random.uniform, minval=-bound,
+                                      maxval=bound)
+        conv = self.param("conv1d", conv_init, (inner + bc, m.conv))
+        conv_bias = self.param("conv1d_bias", conv_init, (inner + bc,))
+
+        def dt_init(key, shape):
+            dt = jnp.maximum(jnp.exp(jax.random.uniform(
+                key, shape, jnp.float32, math.log(1e-3), math.log(0.1))),
+                1e-4)
+            return dt + jnp.log(-jnp.expm1(-dt))
+
+        dt_bias = self.param("dt_bias", dt_init, (m.heads,))
+        a_log = self.param(
+            "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                key, shape, jnp.float32, 1.0, 16.0)), (m.heads,))
+        d = self.param("D", nn.initializers.ones, (m.heads,))
+        norm = self.param("norm_scale", nn.initializers.ones, (inner,))
+        with jax.named_scope("hvd.ssm"):
+            y = mamba2_mixer(
+                zxbcdt, conv, conv_bias, dt_bias, a_log, d, norm,
+                heads=m.heads, head_dim=m.head_dim, groups=m.groups,
+                state=m.state, chunk=m.chunk, eps=self.norm_eps)
+        return dense(self.dim, name="out_proj")(y)
+
+    def _relu2(self, h, width, prefix):
+        """``relu(h W_up)^2 W_down``, bias-free, in ``dtype``: parameters
+        ``{prefix}_up``, ``{prefix}_down``."""
+        up = nn.Dense(width, use_bias=False, dtype=self.dtype,
+                      name=f"{prefix}_up")(h)
+        return nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
+                        name=f"{prefix}_down")(jnp.square(nn.relu(up)))
+
     def _swiglu(self, h, width, prefix):
         """``(silu(h W_gate) * (h W_up)) W_down``, bias-free, in ``dtype``:
         parameters ``{prefix}_gate``, ``{prefix}_up``, ``{prefix}_down``."""
@@ -500,26 +624,50 @@ class TransformerBlock(nn.Module):
         count = e.routed if e.count is None else e.count
         init = nn.initializers.normal(0.02)
         router = self.param("router", init, (self.dim, e.routed))
-        gate = self.param("experts_gate", init, (count, self.dim, e.width))
-        up = self.param("experts_up", init, (count, self.dim, e.width))
-        down = self.param("experts_down", init, (count, e.width, self.dim))
+        inner = e.latent or self.dim
+        gate = None
+        if e.activation == "swiglu":
+            gate = self.param("experts_gate", init, (count, inner, e.width))
+        up = self.param("experts_up", init, (count, inner, e.width))
+        down = self.param("experts_down", init, (count, e.width, inner))
         if e.scale != 1.0:
             # the routed sum is linear in the down projections: the factor
             # rides in their cast to ``dtype`` and costs no pass of its own
             down = down * e.scale
+        x = h.reshape(-1, self.dim)
+        routing = {}
+        if e.router == "sigmoid":
+            # the selection bias is a buffer, not a parameter: no gradient
+            # trains it (a balancing rule would set it between steps)
+            bias = jnp.zeros((e.routed,), jnp.float32)
+            if self.has_variable("batch_stats", "router_bias") \
+                    or self.is_mutable_collection("batch_stats"):
+                bias = self.variable("batch_stats", "router_bias", jnp.zeros,
+                                     (e.routed,), jnp.float32).value
+            routing = dict(router_kind="sigmoid", bias=bias)
+        if e.latent is not None:
+            routing["route_from"] = x
+            with jax.named_scope("hvd.moe_latent"):
+                x = nn.Dense(e.latent, use_bias=False, dtype=self.dtype,
+                             name="fc1_latent_proj")(x)
         y, rows = routed_experts(
-            h.reshape(-1, self.dim), router, gate, up, down, top_k=e.top_k,
-            first=e.first, select=e.select, dtype=self.dtype)
+            x, router, gate, up, down, top_k=e.top_k, first=e.first,
+            select=e.select, dtype=self.dtype, **routing)
         # the step's counter rides where BatchNorm's statistics do: a step
         # builder hands it on, ``moe.record_rows`` reads it
         if self.is_mutable_collection("batch_stats"):
             self.variable("batch_stats", "moe_rows", jnp.zeros, (),
                           jnp.float32).value = rows
+        if e.latent is not None:
+            with jax.named_scope("hvd.moe_latent"):
+                y = nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
+                             name="fc2_latent_proj")(y)
         y = y.reshape(h.shape)
         if e.shared is not None:
             # every token's own expert: it waits for nothing of the routing
             with jax.named_scope("hvd.moe_shared"):
-                shared = self._swiglu(h, e.shared, "shared")
+                shared = (self._relu2 if e.activation == "relu2"
+                          else self._swiglu)(h, e.shared, "shared")
                 if e.shared_gate:
                     shared = shared * jax.nn.sigmoid(nn.Dense(
                         1, use_bias=False, dtype=self.dtype,
@@ -607,34 +755,43 @@ class TransformerLM(nn.Module):
         swiglu = isinstance(layer.ffn, SwiGLU)
         return dict(
             common, heads=layer.heads,
-            mlp_ratio=0 if routed or swiglu else layer.ffn,
+            mlp_ratio=0 if routed or swiglu or layer.ffn is None
+            else layer.ffn,
             kv_heads=layer.kv_heads, rope_base=layer.rope_base,
             head_dim=layer.head_dim, yarn=layer.yarn, window=layer.window,
             experts=layer.ffn if routed else None,
             swiglu=layer.ffn if swiglu else None,
             rotary_dim=layer.rotary_dim, gate=layer.gate,
-            qk_norm=layer.qk_norm, mixer=layer.mixer)
+            qk_norm=layer.qk_norm, mixer=layer.mixer,
+            has_ffn=layer.ffn is not None)
 
     @nn.compact
     def __call__(self, tokens, positions=None, train: bool = True,
                  page_table=None):
         if self.layers is not None and (
                 len(self.layers) != self.depth
-                or self.pos_embedding != "rope"):
+                or self.pos_embedding not in ("rope", "none")):
             raise ValueError(
                 f"layers describes {len(self.layers)} blocks with rotary "
-                f"positions: pass depth={len(self.layers)} and "
-                f"pos_embedding='rope' (got depth={self.depth}, "
+                f"positions or none: pass depth={len(self.layers)} and "
+                f"pos_embedding='rope' or 'none' (got depth={self.depth}, "
                 f"pos_embedding={self.pos_embedding!r})")
-        if self.pos_embedding not in ("learned", "rope"):
+        if self.pos_embedding not in ("learned", "rope", "none"):
             raise ValueError(
-                f"pos_embedding must be 'learned' or 'rope', "
+                f"pos_embedding must be 'learned', 'rope' or 'none', "
                 f"got {self.pos_embedding!r}"
             )
         if self.layers is not None:
             for i, layer in enumerate(self.layers):
-                if (layer.mixer is None) == (layer.heads < 1
-                                             or layer.head_dim < 1):
+                if layer.mixer is None and not (layer.heads
+                                                or layer.head_dim):
+                    if layer.ffn is None:
+                        raise ValueError(
+                            f"layers[{i}] has no heads, no mixer and no FFN: "
+                            "a block takes attention or a mixer, an FFN, or "
+                            "both")
+                elif (layer.mixer is None) == (layer.heads < 1
+                                               or layer.head_dim < 1):
                     raise ValueError(
                         f"layers[{i}] gives attention's heads and head_dim "
                         "or a mixer, one of the two: got heads="
@@ -657,7 +814,7 @@ class TransformerLM(nn.Module):
         x = nn.Embed(self.vocab, self.dim, dtype=self.dtype,
                      name="tok_embed")(tokens)
         use_rope = self.pos_embedding == "rope"
-        if not use_rope:
+        if self.pos_embedding == "learned":
             pos_table = self.param(
                 "pos_embed",
                 nn.initializers.normal(0.02),
@@ -702,6 +859,9 @@ def TransformerSmall(**kw):
 #: a gated-delta block's parameters besides its norms
 _GATED_DELTA_PARAMS = ("in_proj_qkvz", "in_proj_ba", "conv1d", "A_log",
                        "dt_bias", "norm_scale", "out_proj")
+#: a Mamba-2 block's
+_MAMBA2_PARAMS = ("in_proj", "conv1d", "conv1d_bias", "A_log", "D",
+                  "dt_bias", "norm_scale", "out_proj")
 
 
 def transformer_param_specs(params, model_axis: str = "model"):
@@ -714,12 +874,12 @@ def transformer_param_specs(params, model_axis: str = "model"):
     def spec_for(path, leaf):
         names = [getattr(p, "key", str(p)) for p in path]
         name = "/".join(names)
-        if any(n in _GATED_DELTA_PARAMS for n in names):
+        if any(n in _GATED_DELTA_PARAMS + _MAMBA2_PARAMS for n in names):
             raise ValueError(
                 "transformer_param_specs has no layout for the gated-delta "
-                f"layer ({name}): its heads split over the in- and "
-                "out-projections' grouped columns, which this function does "
-                "not describe")
+                f"layer or the Mamba-2 layer ({name}): its heads split over "
+                "the in- and out-projections' grouped columns, which this "
+                "function does not describe")
         if any(n in ("q_norm", "k_norm", "shared_expert_gate")
                for n in names):
             raise ValueError(
@@ -785,12 +945,17 @@ def tp_block_apply(block_params, x, *, heads: int, axis: str = "tp"):
     from horovod_tpu.ops.collective import _axis_size
 
     found = sorted(set(block_params) & set(
-        _GATED_DELTA_PARAMS + ("q_norm", "k_norm")))
+        _GATED_DELTA_PARAMS + _MAMBA2_PARAMS + ("q_norm", "k_norm")))
     if found:
         raise ValueError(
             "tp_block_apply handles softmax-attention blocks only: "
-            f"{found} belong to the gated-delta layer or to q/k norms")
+            f"{found} belong to a gated-delta or Mamba-2 layer or to q/k "
+            "norms")
     if "qkv" not in block_params:
+        if "ln1" in block_params and "ln2" not in block_params:
+            raise ValueError(
+                "tp_block_apply handles blocks of attention and an MLP: this "
+                f"one is of one part alone (params: {sorted(block_params)})")
         raise ValueError(
             "tp_block_apply requires a fused qkv kernel (kv_heads unset "
             "or == heads); GQA blocks need the GSPMD path "

@@ -196,15 +196,26 @@ WINDOW_ROWS = 64
 _PRODUCT_ROWS = 1024
 
 
-def route_top_k(x, router, top_k: int, select=None):
+def route_top_k(x, router, top_k: int, select=None, *,
+                kind: str = "softmax", bias=None):
     """The router in float32: probabilities over every routed expert, the
     ``top_k`` largest and their weights normalised to sum to one. ``x``
     ``[T, D]``, ``router`` ``[D, E]`` -> weights ``[T, k]`` f32, experts
     ``[T, k]`` int32. Where ``select`` is given, a token's experts are the
     ``top_k`` of ``select(probabilities)`` ``[T, E]`` instead; their
-    weights are the router's all the same."""
+    weights are the router's all the same.
+
+    ``kind`` ``"sigmoid"`` (DeepSeek-V3's, Nemotron-H's): ``s =
+    sigmoid(x router)``, the experts the ``top_k`` of ``s + bias`` (a
+    selection bias ``[E]`` that no gradient reaches; zeros where none is
+    given), or of ``select(s)``, weighed by their ``s`` over the chosen
+    ``s``' sum."""
     logits = jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
+    if kind == "sigmoid":
+        return _route_sigmoid(logits, top_k, select, bias)
+    if kind != "softmax":
+        raise ValueError(f"kind must be 'softmax' or 'sigmoid', got {kind!r}")
     probs = jax.nn.softmax(logits, axis=-1)
     _, experts = lax.top_k(probs if select is None else select(probs), top_k)
     # the chosen logits by a one-hot product: its gradient is a product
@@ -216,19 +227,38 @@ def route_top_k(x, router, top_k: int, select=None):
                           axis=-1), experts
 
 
+def _route_sigmoid(logits, top_k: int, select, bias):
+    """:func:`route_top_k`'s ``sigmoid`` form from the float32 logits."""
+    scores = jax.nn.sigmoid(logits)
+    if select is not None:
+        choice = select(scores)
+    elif bias is not None:
+        choice = scores + lax.stop_gradient(bias.astype(jnp.float32))
+    else:
+        choice = scores
+    _, experts = lax.top_k(choice, top_k)
+    # the chosen scores by a one-hot product, as the softmax form takes its
+    # logits: its gradient is a product too
+    chosen = jax.nn.one_hot(experts, logits.shape[-1], dtype=logits.dtype)
+    picked = jnp.sum(scores[:, None, :] * chosen, axis=-1)
+    return picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20), experts
+
+
 def buffer_rows(tokens: int, top_k: int, count: int) -> int:
     """Rows of the sorted buffer: the worst case, every assignment held
     here, and a tile of padding an expert, so no assignment is ever without
-    a row. Nothing holds a router near balance (untrained, the chip read
-    0 to 30,639 of 65,536 assignments on 16 of 64 experts, layer by layer
-    and step by step, where balance sends 16,384). The grouped products,
-    with the activation and the weighting inside them
+    a row. A token's ``top_k`` experts are distinct, so at most ``min(top_k,
+    count)`` of its slots land on the ``count`` held here: with 22 chosen of
+    512 and 8 held, 8 a token, not 22. Nothing holds a router near balance
+    (untrained, the chip read 0 to 30,639 of 65,536 assignments on 16 of 64
+    experts, layer by layer and step by step, where balance sends 16,384).
+    The grouped products, with the activation and the weighting inside them
     (:func:`expert_mlp`), pass over the tiles no row fills, and the way
     back to the tokens reads the rows held here (:func:`_pallas_combine`);
     the gather into the buffer (:func:`_to_rows`, and the like of it that
     is the way back's transpose) and the plan's tables of a number a row
     still run over the whole of it."""
-    return (-(-tokens * top_k // TILE_ROWS) + count) * TILE_ROWS
+    return (-(-tokens * min(top_k, count) // TILE_ROWS) + count) * TILE_ROWS
 
 
 def _plan(experts, *, first: int, count: int):
@@ -915,13 +945,134 @@ def _expert_mlp_bwd(interpret, res, dys):
 expert_mlp.defvjp(_expert_mlp_fwd, _expert_mlp_bwd)
 
 
+def hvd_moe_relu2_fwd(tile_expert_ref, tiles_ref, x_ref, w_ref, up_ref,
+                      down_ref, u_ref, act_ref, y_ref):
+    """One row tile through its expert's relu² MLP: times the up matrix,
+    the weighted activation ``relu(u)^2 w`` from the float32 accumulator,
+    rounded once, times the down matrix (the combine's weighting rides
+    there, as in :func:`hvd_moe_mlp_fwd`). ``u`` is kept, rounded, for the
+    backward, and the weighted activation for the down matrix's
+    gradient."""
+    from jax.experimental import pallas as pl
+
+    del tile_expert_ref                    # read by the index maps
+
+    @pl.when(pl.program_id(0) < tiles_ref[0])
+    def _multiply():
+        u = _dot(x_ref[...], up_ref[0])
+        u_ref[...] = u.astype(u_ref.dtype)
+        act = (jnp.square(jnp.maximum(u, 0.0))
+               * w_ref[...].reshape(-1, 1)).astype(act_ref.dtype)
+        act_ref[...] = act
+        y_ref[...] = _dot(act, down_ref[0]).astype(y_ref.dtype)
+
+
+def hvd_moe_relu2_bwd(tile_expert_ref, tiles_ref, dy_ref, w_ref, u_ref,
+                      up_ref, down_ref, du_ref, dw_ref, dx_ref):
+    """The backward of :func:`hvd_moe_relu2_fwd` for a tile of ``d ys``, in
+    float32 on the accumulator ``t = d ys . down^T``: ``d w = rowsum(t
+    relu(u)^2)``, written as a row; ``d u = 2 t w relu(u)`` from the kept
+    ``u``, rounded for the up matrix's gradient; ``d xs = d u . up^T``."""
+    from jax.experimental import pallas as pl
+
+    del tile_expert_ref
+
+    @pl.when(pl.program_id(0) < tiles_ref[0])
+    def _multiply():
+        t = _dot(dy_ref[...], down_ref[0], transpose_rhs=True)
+        relu = jnp.maximum(u_ref[...].astype(jnp.float32), 0.0)
+        dw_ref[...] = jnp.sum(t * relu * relu, axis=1)[None, :]
+        du = (2.0 * t * w_ref[...].reshape(-1, 1) * relu).astype(
+            du_ref.dtype)
+        du_ref[...] = du
+        dx_ref[...] = _dot(du, up_ref[0], transpose_rhs=True).astype(
+            dx_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _pallas_relu2_fwd(xs, w, up, down, tile_expert, tiles, *,
+                      interpret: bool):
+    d, f = up.shape[1:]
+    return _grouped_call(
+        hvd_moe_relu2_fwd, (tile_expert, tiles, xs, w, up, down),
+        [_row_tiles(d), _row_scalars(), _matrix_of_tile(up),
+         _matrix_of_tile(down)],
+        [(f, xs.dtype)] * 2 + [(d, xs.dtype)], interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _pallas_relu2_bwd(dys, w, u, up, down, tile_expert, tiles, *,
+                      interpret: bool):
+    d, f = up.shape[1:]
+    return _grouped_call(
+        hvd_moe_relu2_bwd, (tile_expert, tiles, dys, w, u, up, down),
+        [_row_tiles(d), _row_scalars(), _row_tiles(f), _matrix_of_tile(up),
+         _matrix_of_tile(down)],
+        [(f, dys.dtype), (None, jnp.float32), (d, dys.dtype)],
+        interpret=interpret)
+
+
+def _relu2_fit(d: int, f: int, itemsize: int) -> bool:
+    """Whether the relu² calls have room at experts of ``[d, f]``: an
+    expert's two matrices, double-buffered, beside (the backward holds
+    most) two tiles of ``d`` and two of ``f`` elements a row, twice each,
+    and the float32 accumulators: one of ``d`` and three of ``f`` a row."""
+    return (4 * d * f * itemsize
+            + TILE_ROWS * (2 * (2 * d + 2 * f) * itemsize + (d + 3 * f) * 4)
+            ) <= _VMEM_LIMIT * 7 // 8
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def expert_relu2_mlp(xs, w_rows, up, down, tile_expert, tiles,
+                     interpret: bool = False):
+    """:func:`expert_mlp` for relu² experts (Nemotron-H's): ``w_rows *
+    (relu(xs up[e])^2 down[e])`` tile by tile, ``up`` ``[E, D, F]`` and
+    ``down`` ``[E, F, D]``, one matrix before the activation where SwiGLU
+    has two. One call forward (:func:`hvd_moe_relu2_fwd`), three backward
+    (:func:`hvd_moe_relu2_bwd`, and :func:`hvd_moe_tgmm` for the up's
+    gradient and for the down's). The rows past the tiles in use are
+    neither written nor read, in any result."""
+    return _expert_relu2_fwd(xs, w_rows, up, down, tile_expert, tiles,
+                             interpret)[0]
+
+
+def _expert_relu2_fwd(xs, w_rows, up, down, tile_expert, tiles, interpret):
+    w = w_rows.reshape(1, -1)
+    u, act, ys = _pallas_relu2_fwd(
+        xs, w, up.astype(xs.dtype), down.astype(xs.dtype), tile_expert,
+        tiles, interpret=interpret)
+    return ys, (xs, w, u, act, up, down, tile_expert, tiles)
+
+
+@jax.named_scope("hvd.moe_experts")
+def _expert_relu2_bwd(interpret, res, dys):
+    xs, w, u, act, up, down, tile_expert, tiles = res
+    dys = dys.astype(xs.dtype)
+    du, dw, dxs = _pallas_relu2_bwd(
+        dys, w, u, up.astype(xs.dtype), down.astype(xs.dtype), tile_expert,
+        tiles, interpret=interpret)
+    # padding rows add zeros: ``act`` is weighted by 0 there, and so is
+    # ``d u``
+    dup, = _pallas_tgmm(xs, (du,), tile_expert, tiles, up.shape[0],
+                        interpret=interpret)
+    ddown, = _pallas_tgmm(act, (dys,), tile_expert, tiles, up.shape[0],
+                          interpret=interpret)
+    return (dxs, dw.reshape(-1), dup.astype(up.dtype),
+            ddown.astype(down.dtype), None, None)
+
+
+expert_relu2_mlp.defvjp(_expert_relu2_fwd, _expert_relu2_bwd)
+
+
 def routed_experts(x, router, gate, up, down, *, top_k: int, first: int = 0,
                    select=None, dtype=None,
-                   interpret: Optional[bool] = None):
+                   interpret: Optional[bool] = None,
+                   router_kind: str = "softmax", bias=None, route_from=None):
     """One routed-expert layer without dropped tokens, for the experts held
     here: ``x`` ``[T, D]``; ``router`` ``[D, E]`` over all ``E`` routed
     experts; ``gate``, ``up`` ``[count, D, F]`` and ``down`` ``[count, F,
-    D]``, the SwiGLU experts ``first … first + count - 1``. Returns ``(y,
+    D]``, the SwiGLU experts ``first … first + count - 1`` (``gate`` None:
+    relu² experts, ``relu(x up)^2 down``). Returns ``(y,
     local)``: ``y`` ``[T, D]``, each token's ``sum_j w_j expert_j(x)`` over
     its ``top_k`` experts that are held here (weights normalised over all
     ``top_k``; what the others would add is another holder's to compute),
@@ -930,7 +1081,10 @@ def routed_experts(x, router, gate, up, down, *, top_k: int, first: int = 0,
     choice of experts in the router's place, their weights still the
     router's (:func:`route_top_k`): a measurement hands in scores that
     spread the tokens evenly where the router is untrained, as a block is
-    handed its ``attention_fn``.
+    handed its ``attention_fn``. ``router_kind`` and ``bias`` are
+    :func:`route_top_k`'s ``kind`` and ``bias``; the router reads
+    ``route_from`` ``[T, D_r]`` where it is given (a latent layer's experts
+    work on a narrower ``x`` than the router reads), else ``x``.
 
     The router runs in float32 (``highest`` precision); the assignments to
     held experts are sorted by expert into a buffer of static shape that
@@ -951,7 +1105,7 @@ def routed_experts(x, router, gate, up, down, *, top_k: int, first: int = 0,
 
     One chip, no exchange: the caller's tokens are all the tokens.
     ``interpret`` defaults to running the kernels interpreted off TPU."""
-    tokens, count, routed = x.shape[0], gate.shape[0], router.shape[1]
+    tokens, count, routed = x.shape[0], up.shape[0], router.shape[1]
     dtype = dtype or x.dtype
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -963,13 +1117,17 @@ def routed_experts(x, router, gate, up, down, *, top_k: int, first: int = 0,
                  "an expert").set(buffer_rows(tokens, top_k, count))
 
     with jax.named_scope("hvd.moe_route"):
-        weights, experts = route_top_k(x, router, top_k, select)
+        weights, experts = route_top_k(
+            x if route_from is None else route_from, router, top_k, select,
+            kind=router_kind, bias=bias)
         plan = _plan(experts, first=first, count=count)
         xs = _to_rows(x.astype(dtype), plan, top_k, routed, interpret)
         w_rows = _to_rows(weights.reshape(-1, 1), plan, 1, routed, interpret)
         real = (plan["slot_of_row"] < tokens * top_k)[:, None]
     groups = (plan["tile_expert"], plan["tiles"], interpret)
-    if _experts_fit(*gate.shape[1:], jnp.dtype(dtype).itemsize):
+    relu2 = gate is None
+    fit = _relu2_fit if relu2 else _experts_fit
+    if fit(*up.shape[1:], jnp.dtype(dtype).itemsize):
         if _metrics.enabled():
             _metrics.gauge(
                 "moe_experts_fused",
@@ -983,11 +1141,15 @@ def routed_experts(x, router, gate, up, down, *, top_k: int, first: int = 0,
             # by nothing (``_to_tokens`` reads the rows that have a slot)
             w_rows = jnp.where(real, w_rows, 0).reshape(-1)
         with jax.named_scope("hvd.moe_experts"):
-            ys = expert_mlp(xs, w_rows, gate, up, down, *groups)
+            ys = (expert_relu2_mlp(xs, w_rows, up, down, *groups) if relu2
+                  else expert_mlp(xs, w_rows, gate, up, down, *groups))
     else:
         with jax.named_scope("hvd.moe_experts"):
-            act = (jax.nn.silu(grouped_matmul(xs, gate, *groups))
-                   * grouped_matmul(xs, up, *groups))
+            if relu2:
+                act = jnp.square(jax.nn.relu(grouped_matmul(xs, up, *groups)))
+            else:
+                act = (jax.nn.silu(grouped_matmul(xs, gate, *groups))
+                       * grouped_matmul(xs, up, *groups))
             ys = grouped_matmul(act, down, *groups)
         with jax.named_scope("hvd.moe_route"):
             # chosen away, never multiplied: ``0 x NaN``

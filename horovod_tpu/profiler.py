@@ -48,13 +48,18 @@ Device scopes, as they read in an instruction's ``op_name``:
   (plain matrix products); ``hvd.gdn`` — a Gated DeltaNet layer's work
   between its in- and out-projections (the causal convolution, the q/k
   normalisations, beta and the decays, the chunked delta rule and its
-  ``while`` over the chunks, the gated output norm). All cover the forward
-  and, in or under a ``transpose(...)`` component, the backward
+  ``while`` over the chunks, the gated output norm); ``hvd.ssm`` — a
+  Mamba-2 layer's work between its in- and out-projections (the causal
+  convolution, the step sizes, the chunked recurrence, the gated group
+  norm); ``hvd.moe_latent`` — a latent routed layer's projections down to
+  its experts' width and back. All cover the forward and, in or under a
+  ``transpose(...)`` component, the backward
 - ``hvd_<kernel>`` — one ``pallas_call`` (also the Mosaic ``kernel_name``):
   ``hvd_flash_fwd``; ``hvd_moe_gmm`` (a row tile of the sorted buffer times
   its expert's matrix, or its transpose), ``hvd_moe_mlp_fwd`` (a row tile
   through its expert's SwiGLU, weighted), ``hvd_moe_mlp_bwd`` (its
-  backward) and ``hvd_moe_tgmm`` (an expert's weight gradients) inside
+  backward), ``hvd_moe_relu2_fwd`` / ``hvd_moe_relu2_bwd`` (the same of a
+  relu² expert) and ``hvd_moe_tgmm`` (an expert's weight gradients) inside
   ``hvd.moe_experts``, keyed apart from it by
   :func:`scope_of`; the quantisation and optimizer kernels of
   ``ops/pallas_kernels.py``
@@ -66,7 +71,8 @@ tile, window and windows a product of the kernel that sums the rows back,
 chosen from the shapes; absent where the gather ran),
 ``moe_experts_fused`` (1 where the experts' activation and weighting were
 traced inside the grouped products' kernels), ``gdn_chunk`` / ``gdn_chunks``
-(tokens a chunk of the gated delta rule, and chunks a row). ``moe_local_rows`` is a
+(tokens a chunk of the gated delta rule, and chunks a row), ``ssm_chunk`` /
+``ssm_chunks`` (the same of the Mamba-2 recurrence). ``moe_local_rows`` is a
 step's counter (the assignments that landed on the experts held here: what
 the grouped products' time follows), set by ``parallel.moe.record_rows``
 from the step's ``batch_stats``.
@@ -322,11 +328,11 @@ def scope_of(op_name: str, kind: str = ""):
     transposed pass, and what ``jax.checkpoint`` recomputes during it),
     ``"forward"`` (any other ``hvd.forward``), else ``None``. ``kernel`` is
     the innermost ``hvd.flash_*`` / ``hvd.moe_*`` / ``hvd.gdn`` /
-    ``hvd_<kernel>`` component, else ``None``."""
+    ``hvd.ssm`` / ``hvd_<kernel>`` component, else ``None``."""
     parts = _components(op_name)
     kernel = next((p for p in reversed(parts)
                    if p.startswith(("hvd.flash_", "hvd.moe_", "hvd.gdn",
-                                    "hvd_"))), None)
+                                    "hvd.ssm", "hvd_"))), None)
     if kind.startswith(_COLLECTIVE_KINDS) or \
             any("hvd.sync" in p for p in parts):
         return "sync", kernel

@@ -13,7 +13,8 @@ pytest.register_assert_rewrite("benchmarks.tests.test_check",
                                "benchmarks.tests.test_correct",
                                "benchmarks.tests.test_mellum",
                                "benchmarks.tests.test_laguna",
-                               "benchmarks.tests.test_qwen3next")
+                               "benchmarks.tests.test_qwen3next",
+                               "benchmarks.tests.test_nemotron_h")
 
 from benchmarks.tests.test_check import (  # noqa: E402,F401
     test_a_second_four_chip_cell_needs_eight_cells,
@@ -37,6 +38,11 @@ from benchmarks.tests.test_mellum import (  # noqa: E402,F401
     test_broken_mellum_timed_path_is_not_correct,
     test_mellum_control_is_not_correct,
     test_unbroken_mellum_run_is_correct,
+)
+from benchmarks.tests.test_nemotron_h import (  # noqa: E402,F401
+    test_broken_nemotron_h_timed_path_is_not_correct,
+    test_nemotron_h_control_is_not_correct,
+    test_unbroken_nemotron_h_run_is_correct,
 )
 from benchmarks.tests.test_qwen3next import (  # noqa: E402,F401
     test_broken_qwen3next_timed_path_is_not_correct,

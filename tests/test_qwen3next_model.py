@@ -564,7 +564,9 @@ def test_tp_block_apply_refuses_the_new_layers(layer):
 
 
 def test_a_layer_gives_attention_or_a_mixer():
-    for layer in (models.Layer(ffn=1), models.Layer(
+    # heads without a head size, or attention beside a mixer (no heads and
+    # no mixer is a block of its FFN alone)
+    for layer in (models.Layer(heads=2, ffn=1), models.Layer(
             heads=2, head_dim=16, mixer=models.GatedDelta(1, 2, 16, 16))):
         model = models.TransformerLM(vocab=8, dim=64, depth=1, heads=1,
                                      layers=(layer,), pos_embedding="rope")

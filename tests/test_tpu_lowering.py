@@ -412,6 +412,7 @@ def test_token_xent_keeps_no_float32_copy_of_the_logits(one_v5e_chip, dtype,
     (2048, 8, 16, 2304, "bfloat16"),   # the ladder's other shape
     (600, 2, 3, 256, "float32"),       # a count no tile divides, exact f32
     (8192, 10, 32, 2048, "bfloat16"),  # qwen3next_train_1chip: 5 rows
+    (4096, 22, 8, 1024, "bfloat16"),   # nemotron3super_train_1chip: 11
 ])
 def test_moe_way_back_compiles_for_v5e(one_v5e_chip, tokens, top_k, count,
                                        dim, dtype):
@@ -427,8 +428,8 @@ def test_moe_way_back_compiles_for_v5e(one_v5e_chip, tokens, top_k, count,
     # the router's width, and the window and windows a product the rule
     # gives there
     routed, window, windows = {(8, 16): (64, 64, 16), (8, 32): (256, 32, 32),
-                               (10, 32): (512, 32, 32), (2, 3): (8, 64, 3)
-                               }[top_k, count]
+                               (10, 32): (512, 32, 32), (2, 3): (8, 64, 3),
+                               (22, 8): (512, 32, 8)}[top_k, count]
     assert moe._combine_tile(top_k, routed, count,
                              32 // jnp.dtype(dtype).itemsize) == (window,
                                                                   windows)
@@ -490,6 +491,67 @@ def test_moe_expert_mlp_compiles_for_v5e(one_v5e_chip, tokens, top_k, count,
             and "custom-call" not in l and "parameter(" not in l
             and "get-tuple-element" not in l and " tuple(" not in l]
     assert not wide, wide
+
+
+def test_moe_relu2_mlp_compiles_for_v5e(one_v5e_chip):
+    """Mosaic takes the relu² experts' calls at
+    ``nemotron3super_train_1chip``'s shapes (experts of ``[1024, 2688]`` in
+    the latent, 8 held, 22 of 512 chosen over 4,096 tokens: the buffer
+    bounded at 8 rows a token), forward and backward: both matrices beside
+    the tiles fit the VMEM asked for, and no XLA operation is left over a
+    ``[rows, D]`` or ``[rows, F]`` array but the casts of the cotangent
+    this test hands in."""
+    from horovod_tpu.parallel import moe
+
+    tokens, top_k, count, dim, width = 4096, 22, 8, 1024, 2688
+    assert moe._relu2_fit(dim, width, 2)
+    rows = moe.buffer_rows(tokens, top_k, count)
+    assert rows == 34816
+    on_chip = functools.partial(S, sharding=one_v5e_chip)
+    args = (on_chip((rows, dim), jnp.bfloat16), on_chip((rows,), jnp.float32),
+            on_chip((count, dim, width), jnp.float32),
+            on_chip((count, width, dim), jnp.float32),
+            on_chip((rows // moe.TILE_ROWS,), jnp.int32),
+            on_chip((1,), jnp.int32))
+
+    def both(*a):
+        ys, back = jax.vjp(
+            lambda *d: moe.expert_relu2_mlp(*d, *a[4:], False), *a[:4])
+        return ys, back(ys)
+
+    text = jax.jit(both).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert " while(" not in text
+    entry = text[text.index("\nENTRY "):]
+    # the copies the compiler starts between memory spaces move the
+    # forward's result that this test hands back as its cotangent
+    wide = [l for l in entry.splitlines()
+            if re.search(r"= \(?bf16\[%d,(%d|%d)\]" % (rows, dim, width), l)
+            and "custom-call" not in l and "parameter(" not in l
+            and "get-tuple-element" not in l and " tuple(" not in l
+            and " copy-start(" not in l and " copy-done(" not in l]
+    assert not wide, wide
+
+
+def test_mamba2_recurrence_compiles_for_v5e(one_v5e_chip):
+    """The chunked recurrence at ``nemotron3super_train_1chip``'s shapes
+    (one row of 4,096 tokens, 16 heads of 64 on one group of state 128,
+    chunks of 128), forward and every gradient: batched products over the
+    32 chunks, no ``while``, under 0.5 GB of temporaries."""
+    from horovod_tpu.ops import mamba2
+
+    on_chip = functools.partial(S, dtype=jnp.float32, sharding=one_v5e_chip)
+    args = (on_chip((1, 4096, 16, 64)), on_chip((1, 4096, 16)),
+            on_chip((16,)), on_chip((1, 4096, 1, 128)),
+            on_chip((1, 4096, 1, 128)))
+
+    def both(*a):
+        y, back = jax.vjp(functools.partial(mamba2.ssd, chunk=128), *a)
+        return y, back(y)
+
+    compiled = jax.jit(both).lower(*args).compile()
+    assert " while(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 5e8
 
 
 # ------------------------------- the gated delta rule, on the chip

@@ -164,6 +164,19 @@ def test_scope_table_holds_the_live_step(hvd):
     (_BWD + "/block0/hvd.gdn/while/body/transpose", "fusion",
      ("backward", "hvd.gdn")),
     (_FWD + "/block0/hvd.gdn/while", "while", ("forward", "hvd.gdn")),
+    # a Mamba-2 layer's work between its projections, in both passes and,
+    # under jax.checkpoint, recomputed in the backward (ssm_ms.train keys
+    # on it)
+    (_FWD + "/block1/hvd.ssm/dot_general", "fusion", ("forward", "hvd.ssm")),
+    (_BWD + "/block1/hvd.ssm/transpose", "fusion", ("backward", "hvd.ssm")),
+    (_BWD + "/checkpoint/rematted_computation/block1/hvd.ssm/exp", "fusion",
+     ("backward", "hvd.ssm")),
+    # a latent routed layer's projections (moe_latent_ms.train), and a
+    # relu² expert's call keyed apart from the scope around it
+    (_FWD + "/block0/hvd.moe_latent/dot_general", "fusion",
+     ("forward", "hvd.moe_latent")),
+    (_BWD + "/block0/hvd.moe_experts/hvd_moe_relu2_bwd/pallas_call",
+     "custom-call", ("backward", "hvd_moe_relu2_bwd")),
     ("jit(step)/hvd.optimizer/mul", "fusion", ("optimizer", None)),
     ("jit(step)/hvd.optimizer/hvd_fused_adam/pallas_call", "custom-call",
      ("optimizer", "hvd_fused_adam")),
@@ -224,6 +237,36 @@ def test_gated_delta_mixer_runs_under_its_scope_in_both_passes():
     projections = set(re.findall(
         r'loc\("([^"]*(?:in_proj_qkvz|out_proj)[^"]*dot_general)"', text))
     assert projections and not any("hvd.gdn" in n for n in projections)
+
+
+def test_mamba2_mixer_runs_under_its_scope_in_both_passes():
+    """``hvd.ssm`` is the innermost ``hvd.*`` scope of everything between a
+    Mamba-2 layer's in- and out-projections — the convolution, the step
+    sizes, the chunked recurrence and the gated norm — forward and in the
+    transposed pass, and of neither projection."""
+    from horovod_tpu import models
+    from horovod_tpu.models.transformer import TransformerBlock
+
+    block = TransformerBlock(**models.TransformerLM(
+        vocab=8, dim=64, depth=1, heads=1, pos_embedding="none",
+        layers=(models.Layer(mixer=models.Mamba2(4, 8, 2, 16, chunk=16),
+                             ffn=None),),
+        dtype=jnp.float32).block_config(0))
+    x = jnp.zeros((1, 80, 64))
+    params = block.init(jax.random.PRNGKey(0), x)["params"]
+
+    @jax.named_scope("hvd.forward")
+    def loss(p):
+        return block.apply({"params": p}, x).sum()
+
+    text = jax.jit(jax.grad(loss)).lower(params).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]*hvd\.ssm[^"]*)"', text))
+    scopes = {profiler.scope_of(n) for n in names}
+    assert {("forward", "hvd.ssm"), ("backward", "hvd.ssm")} <= scopes
+    assert {k for _, k in scopes} == {"hvd.ssm"}
+    projections = set(re.findall(
+        r'loc\("([^"]*(?:in_proj|out_proj)[^"]*dot_general)"', text))
+    assert projections and not any("hvd.ssm" in n for n in projections)
 
 
 @pytest.mark.parametrize("builder,module", [
@@ -323,7 +366,7 @@ def test_benchmark_manifest_check_passes():
         [sys.executable, os.path.join(_ROOT, "benchmarks", "run.py"),
          "--check"], cwd=_ROOT, capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stderr
-    assert "check ok: 6 cell(s)" in out.stdout
+    assert "check ok: 7 cell(s)" in out.stdout
     with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     new = ["phase_forward_ms.train", "phase_backward_ms.train",
